@@ -1,6 +1,6 @@
 # Parity with the reference's Makefile targets (install/test/lint/format/docs/release).
 
-.PHONY: test test-fast lint lint-fed audit-smoke bench bench-smoke chaos-smoke hostchaos-smoke federation-smoke trace-smoke profile-smoke loadtest-smoke autotune-smoke retune-smoke warm-cache adapter-smoke adapter-evidence fleet-smoke fleet-evidence multihost-smoke multihost-bench tenants-smoke tenants-bench example dryrun dryrun-multichip-2d api-docs notebook accuracy metrics-summary clean
+.PHONY: test test-fast lint lint-fed audit-smoke bench chip-smoke bench-smoke chaos-smoke hostchaos-smoke federation-smoke trace-smoke profile-smoke loadtest-smoke autotune-smoke retune-smoke warm-cache adapter-smoke adapter-evidence fleet-smoke fleet-evidence multihost-smoke multihost-bench tenants-smoke tenants-bench example dryrun dryrun-multichip-2d api-docs notebook accuracy metrics-summary clean
 
 test:
 	python -m pytest tests/ -q
@@ -26,8 +26,12 @@ lint-fed:
 audit-smoke:
 	python -m nanofed_tpu.analysis --programs --mutants nanofed_tpu/
 
+# On a machine with a TPU only: both exit non-zero when JAX finds none.
 bench:
 	python bench.py
+
+chip-smoke:
+	python chip_smoke.py
 
 # Tiny fused-vs-single-round timing sanity on CPU (seconds, not minutes): catches
 # perf-plumbing regressions (fused engine, dispatch/host_sync spans) in tier-1.
@@ -136,11 +140,12 @@ retune-smoke:
 	  -q -p no:cacheprovider
 
 # Warm the shippable persistent compilation cache (tuning.compile_cache.warm):
-# pre-compile the candidate program set into .jax_cache/ with a toolchain
-# manifest, ready to tar to the accel host.  Verify a shipped cache with
-# `python scripts/warm_cache.py --verify-only --cache-dir <dir>`.
+# pre-compile the candidate program set into the compile cache
+# ($$JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache) with a toolchain
+# manifest, ready to tar to the accelerator host.  Verify a shipped cache with
+# `python scripts/warm_cache.py --verify-only`.
 warm-cache:
-	python scripts/warm_cache.py --cache-dir .jax_cache
+	python scripts/warm_cache.py
 
 # Adapter smoke (nanofed_tpu.adapters): the compile-heavy transformer/adapter
 # integration legs — strict 2-D frozen-base federation with a descending loss,

@@ -53,6 +53,7 @@ from typing import Any, Callable, Iterable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 
 from nanofed_tpu.parallel.mesh import CLIENT_AXIS, HOST_AXIS
 
@@ -81,10 +82,11 @@ AUDIT_CHECKS = (
 # to psum + divide, so schedules are psum-normal; axis names live in the
 # ``axes`` param for the reduce family and ``axis_name`` for the gather family.
 _COLLECTIVE_PRIMS = frozenset({
-    # psum2 is psum after shard_map's replication-checker rewrite (the form
-    # 1-D check_rep=True bodies carry); pbroadcast is deliberately absent —
-    # it adjusts replication bookkeeping, it moves no bytes.
-    "psum", "psum2", "pmax", "pmin", "all_gather", "all_to_all", "ppermute",
+    # psum_invariant is psum under shard_map's replication checker (the form
+    # 1-D check_vma=True bodies carry); pvary is deliberately absent — it
+    # adjusts replication bookkeeping, it moves no bytes.
+    "psum", "psum_invariant", "pmax", "pmin", "all_gather", "all_to_all",
+    "ppermute",
     "reduce_scatter", "psum_scatter", "pgather",
 })
 
@@ -166,9 +168,9 @@ def _inner_jaxprs(params: dict[str, Any]) -> Iterator[Any]:
 
 
 def _jaxprs_in(val: Any) -> Iterator[Any]:
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex_core.Jaxpr):
         yield val
     elif isinstance(val, (tuple, list)):
         for item in val:
@@ -278,7 +280,7 @@ def _walk_dtype_drift(
         prim = eqn.primitive.name
         if prim == "convert_element_type":
             var = eqn.invars[0]
-            if not isinstance(var, jax.core.Literal) and var in tracked:
+            if not isinstance(var, jex_core.Literal) and var in tracked:
                 old = np.dtype(var.aval.dtype)
                 new = np.dtype(eqn.params["new_dtype"])
                 if old == np.dtype(jnp.bfloat16) and new in (
@@ -308,7 +310,7 @@ def _walk_dtype_drift(
                 for sub in _jaxprs_in(br):
                     inner = set()
                     for outer_v, inner_v in zip(operands, sub.invars):
-                        if not isinstance(outer_v, jax.core.Literal) \
+                        if not isinstance(outer_v, jex_core.Literal) \
                                 and outer_v in tracked:
                             inner.add(inner_v)
                     _walk_dtype_drift(sub, inner, program, findings)
@@ -318,7 +320,7 @@ def _walk_dtype_drift(
                 operands = eqn.invars[-n:] if n else []
                 inner = set()
                 for outer_v, inner_v in zip(operands, sub.invars):
-                    if not isinstance(outer_v, jax.core.Literal) \
+                    if not isinstance(outer_v, jex_core.Literal) \
                             and outer_v in tracked:
                         inner.add(inner_v)
                 _walk_dtype_drift(sub, inner, program, findings)

@@ -1413,6 +1413,12 @@ def main(argv: list[str] | None = None) -> int:
     bench.add_argument("--out-dir", default="runs/bench")
 
     args = parser.parse_args(argv)
+    if args.cmd in ("run", "bench", "loadtest"):
+        # The commands that compile round programs keep them in the one
+        # persistent cache (utils.platform.compilation_cache_dir).
+        from nanofed_tpu.utils.platform import enable_compilation_cache
+
+        enable_compilation_cache()
     if args.cmd == "info":
         return _cmd_info(args)
     if args.cmd == "bench":

@@ -44,7 +44,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -54,6 +53,7 @@ from nanofed_tpu.parallel.mesh import (
     hierarchical_psum,
     multi_axis_shard_map_kwargs,
     replicated_sharding,
+    shard_map,
 )
 
 __all__ = [

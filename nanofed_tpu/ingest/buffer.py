@@ -158,6 +158,11 @@ class DeviceIngestBuffer:
     def device_bytes(self) -> int:
         return self.capacity * self.flat_size * 4
 
+    @property
+    def devices(self) -> set[jax.Device]:
+        """The devices the slot array is committed to."""
+        return self._buf.devices()
+
     def occupied(self) -> list[SlotMeta]:
         """Occupied slots in arrival order."""
         return sorted(self._meta.values(), key=lambda m: m.seq)

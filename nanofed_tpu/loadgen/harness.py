@@ -227,6 +227,7 @@ def run_loadtest(
                 ingest_block = {
                     "capacity": ingest_capacity,
                     "device_bytes": pipeline.buffer.device_bytes,
+                    "devices": sorted(str(d) for d in pipeline.buffer.devices),
                     "drains": _counter_total(
                         snapshot, "nanofed_ingest_drains_total"
                     ),

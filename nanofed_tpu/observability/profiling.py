@@ -100,20 +100,15 @@ def peaks_for_device_kind(device_kind: str, platform: str) -> PlatformPeaks | No
 
 
 def extract_cost_analysis(compiled: Any) -> dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax/jaxlib versions.
-
-    Older jaxlibs return a one-element list of dicts, newer ones a plain dict;
-    keys of interest are ``flops``, ``transcendentals`` and ``bytes accessed``
-    (the aggregate — per-operand ``bytes accessedN{}`` breakdowns are dropped).
-    Missing analysis (some backends return nothing) yields zeros, never a raise:
+    """The aggregate rows of ``compiled.cost_analysis()``: ``flops``,
+    ``transcendentals`` and ``bytes accessed`` (per-operand ``bytes accessedN{}``
+    breakdowns are dropped).  Missing analysis (some backends return nothing) yields zeros, never a raise:
     a missing cost must degrade a report, not kill the run that asked for it.
     """
     try:
         raw = compiled.cost_analysis()
     except Exception:
         raw = None
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else None
     if not isinstance(raw, dict):
         return {"flops": 0.0, "transcendentals": 0.0, "bytes_accessed": 0.0}
     return {

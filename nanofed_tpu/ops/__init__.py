@@ -6,9 +6,6 @@ hardware PRNG buys something XLA's pattern library doesn't express:
 
 * ``ops.reduce``    — the FedAvg weighted reduce over the stacked client axis as one
                       MXU contraction per tile ([C, P] x [C] -> [P]).
-* ``ops.dp_reduce`` — the central-DP clip+mean fused into two read passes: per-row
-                      norms, then clip coefficients folded into the reduce WEIGHTS so
-                      the clipped [C, P] intermediate never exists.
 * ``ops.quantize``  — fixed-point uint32 quantize / dequantize and seeded additive
                       masking (the SecAgg inner loop) with the on-core PRNG, so masking
                       never round-trips to the host; plus the fused q8/topk aggregation
@@ -23,11 +20,6 @@ Every op takes ``interpret=None`` (auto: real kernels on TPU, interpreter elsewh
 the same code paths are exercised by the CPU-mesh test suite.
 """
 
-from nanofed_tpu.ops.dp_reduce import (
-    central_dp_reduce_stacked,
-    dp_clipped_mean_flat,
-    row_sq_norms,
-)
 from nanofed_tpu.ops.quantize import (
     add_mask,
     dequant_accumulate_flat,
@@ -42,13 +34,10 @@ from nanofed_tpu.ops.reduce import (
 
 __all__ = [
     "add_mask",
-    "central_dp_reduce_stacked",
     "dequant_accumulate_flat",
     "dequantize_u32",
-    "dp_clipped_mean_flat",
     "masked_weighted_mean_flat",
     "quantize_u32",
-    "row_sq_norms",
     "weighted_mean_flat",
     "weighted_mean_tree",
 ]

@@ -100,9 +100,8 @@ def dequantize_u32(
 # program and reduces them in another (codec ``decode_delta_q8`` then the weighted
 # mean): the [C, P] float32 intermediate is written to and re-read from memory just
 # to be summed — at int8 payload q, that is 1 byte read + 4 written + 4 re-read per
-# element where 1 read suffices.  The fusion is algebraic, the same trick
-# ``ops.dp_reduce`` plays with clip coefficients: the per-client dequant scale is a
-# per-ROW multiplier, so it folds into the reduce weights exactly —
+# element where 1 read suffices.  The fusion is algebraic: the per-client dequant
+# scale is a per-ROW multiplier, so it folds into the reduce weights exactly —
 #
 #     out[p] = base[p] + sum_c (w_c / denom) * s_c * q[c, p]
 #            = base[p] + coefs @ q,      coefs_c = w_c * s_c / denom  (an O(C) vector)
@@ -183,12 +182,18 @@ def dequant_accumulate_flat(
 
 
 def _mask_kernel(seed_ref, sign_ref, q_ref, out_ref):
-    # Per-block stream: seed with (128-bit caller seed, block index) so every block
-    # draws an independent deterministic stream — identical for both parties of a pair.
-    pltpu.prng_seed(
-        seed_ref[0], seed_ref[1], seed_ref[2], seed_ref[3], pl.program_id(0)
-    )
-    bits = pltpu.bitcast(pltpu.prng_random_bits(q_ref.shape), jnp.uint32)
+    # Per-block stream from (128-bit caller seed, block index) — identical for both
+    # parties of a pair.  Mosaic seeds the core PRNG with at most TWO words, so the
+    # four seed words feed two streams that are XORed: every seed bit and the block
+    # index (spread by an odd multiplier so neighbouring blocks do not get
+    # neighbouring seeds) reaches the mask.
+    mix = pl.program_id(0) * jnp.int32(-1640531527)  # 0x9E3779B9
+
+    def stream(a, b):
+        pltpu.prng_seed(a, b)
+        return pltpu.bitcast(pltpu.prng_random_bits(q_ref.shape), jnp.uint32)
+
+    bits = stream(seed_ref[0] ^ mix, seed_ref[1]) ^ stream(seed_ref[2], seed_ref[3] ^ mix)
     # sign +1: add mask; sign -1: subtract (uint32 wraps mod 2^32 either way).
     out_ref[:] = jnp.where(sign_ref[0] > 0, q_ref[:] + bits, q_ref[:] - bits)
 

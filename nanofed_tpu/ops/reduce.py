@@ -50,8 +50,8 @@ def weighted_mean_flat(
     denom: jax.Array | None = None,
 ) -> jax.Array:
     """``[C, P] x [C] -> [P]`` weighted mean (weights normalized by their sum, or by an
-    explicit ``denom`` — the central-DP reduce divides by the PARTICIPANT sum even when
-    clip coefficients are folded into the weights, see ``ops.dp_reduce``)."""
+    explicit ``denom`` — for callers that fold per-row coefficients into the weights
+    but still divide by the participant sum)."""
     c, p = x.shape
     pad = (-p) % _TILE
     xp = jnp.pad(x, ((0, 0), (0, pad)))

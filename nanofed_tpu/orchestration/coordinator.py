@@ -2021,6 +2021,12 @@ class Coordinator:
         )
 
     @property
+    def client_data(self) -> ClientData:
+        """The training data as placed on the mesh: padded to the client shard
+        count, client axis sharded."""
+        return self._data
+
+    @property
     def cohort_size(self) -> int:
         """Clients sampled per round (see ``orchestration.types.cohort_size``).
 

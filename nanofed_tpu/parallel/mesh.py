@@ -35,13 +35,12 @@ cluster make the same program span real hosts.
 
 from __future__ import annotations
 
-import inspect
 import math
 import os
-import warnings
 
 import jax
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from nanofed_tpu.core.types import ClientData
@@ -50,31 +49,20 @@ CLIENT_AXIS = "clients"
 MODEL_AXIS = "model"
 HOST_AXIS = "hosts"
 
-# shard_map graduated from jax.experimental into the jax namespace; support both so
-# the round-step builders run on every JAX the image may carry (same call signature).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on the installed jax version
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
+#: The one shard_map every round-program builder uses.
+shard_map = jax.shard_map
 
 
 def pcast_varying(tree, axis_name: str | tuple[str, ...]):
     """Mark a replicated pytree as device-varying inside a ``shard_map`` body.
 
-    Newer JAX's replication checker requires the explicit ``lax.pcast(...,
-    to="varying")`` before replicated inputs feed per-device compute; older JAX has
-    no pcast (and no varying/unvarying distinction at the type level), where the
-    identity is exactly equivalent.  ``axis_name`` may be a tuple (the hierarchical
-    ``(hosts, clients)`` client axes) — the cast covers every named axis.
+    The replication checker requires the explicit ``lax.pcast(..., to="varying")``
+    before replicated inputs feed per-device compute.  ``axis_name`` may be a tuple
+    (the hierarchical ``(hosts, clients)`` client axes) — the cast covers every
+    named axis.
     """
-    from jax import lax
-
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    if hasattr(lax, "pcast"):
-        return jax.tree.map(
-            lambda x: lax.pcast(x, axes, to="varying"), tree
-        )
-    return tree
+    return jax.tree.map(lambda x: lax.pcast(x, axes, to="varying"), tree)
 
 
 def hierarchical_psum(x, axes: str | tuple[str, ...]):
@@ -85,8 +73,6 @@ def hierarchical_psum(x, axes: str | tuple[str, ...]):
     shard.  Mathematically identical to the flat ``psum`` over all axes (same
     sum, different association order — float parity to rounding); structurally it
     is the client → host/edge → global aggregation hierarchy."""
-    from jax import lax
-
     if isinstance(axes, str):
         return lax.psum(x, axes)
     for ax in reversed(tuple(axes)):
@@ -97,8 +83,6 @@ def hierarchical_psum(x, axes: str | tuple[str, ...]):
 def hierarchical_pmean(x, axes: str | tuple[str, ...]):
     """Mean companion of :func:`hierarchical_psum` (per-stage ``pmean`` composes
     to the global mean because every stage averages over a fixed axis size)."""
-    from jax import lax
-
     if isinstance(axes, str):
         return lax.pmean(x, axes)
     for ax in reversed(tuple(axes)):
@@ -112,8 +96,6 @@ def hierarchical_all_gather(x, axes: str | tuple[str, ...], axis: int = 0):
     client's value on every device; a sort cannot stream through a psum).  The
     concatenation order interleaves host blocks, which is irrelevant to every
     consumer here (trimmed mean / median / Krum are permutation-invariant)."""
-    from jax import lax
-
     if isinstance(axes, str):
         return lax.all_gather(x, axes, axis=axis, tiled=True)
     for ax in reversed(tuple(axes)):
@@ -173,7 +155,6 @@ def initialize_distributed(
         # Single-process: nothing to coordinate.
         return {"process_index": 0, "process_count": 1}
 
-    _enable_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -183,47 +164,6 @@ def initialize_distributed(
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),
     }
-
-
-def _enable_cpu_collectives() -> None:
-    """On a CPU-only platform, multi-process XLA computations need a cross-process
-    collectives backend; the default ("none") makes every multi-device program die
-    with "Multiprocess computations aren't implemented on the CPU backend".  Gloo
-    ships in jaxlib and only needs selecting BEFORE the backend client is created
-    — which is exactly when :func:`initialize_distributed` runs.  A no-op when the
-    flag is already set (operator override wins), when ``JAX_PLATFORMS`` names a
-    non-CPU platform, or on GKE-style TPU pods (``TPU_WORKER_HOSTNAMES``) — TPU/GPU
-    carry their own collectives.  With ``JAX_PLATFORMS`` unset and no pod marker
-    the CPU intent is assumed; at worst this configures the secondary CPU
-    backend's collectives on an accelerator host, which its data plane ignores."""
-    plat = os.environ.get("JAX_PLATFORMS", "").strip().lower()
-    if plat not in ("cpu", ""):
-        return
-    if plat == "" and os.environ.get("TPU_WORKER_HOSTNAMES", "").strip():
-        # JAX_PLATFORMS unset on a TPU pod (the normal GKE bring-up): the TPU
-        # backend carries its own collectives — leave the secondary CPU
-        # backend's config untouched rather than flipping a global on every
-        # pod start (and warning spuriously on gloo-less jaxlib builds).
-        return
-    try:
-        from jax._src.xla_bridge import CPU_COLLECTIVES_IMPLEMENTATION
-
-        current = CPU_COLLECTIVES_IMPLEMENTATION.value
-    except Exception:  # pragma: no cover - jax._src has no stability contract
-        # The private holder moved: fall back to the operator's env override
-        # (the config's own source of truth at startup) and otherwise still
-        # select gloo below — silently returning here would resurrect the
-        # exact multi-process failure this helper exists to prevent.
-        current = os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION")
-    if current in (None, "none"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception as e:  # pragma: no cover - option absent/renamed
-            warnings.warn(
-                f"could not select gloo CPU collectives ({e}); multi-process "
-                "CPU programs will fail at the first cross-process collective",
-                RuntimeWarning,
-            )
 
 
 def make_mesh(
@@ -387,16 +327,10 @@ def multi_axis_shard_map_kwargs(mesh: Mesh) -> dict:
     replicated over the model axis (every model column computes them from
     identical gathered params and identical client data), but that equality is
     structural, not something the checker can prove from the collectives (the
-    psum runs over ``clients`` only).  The checker keyword has been renamed
-    across JAX versions (check_rep -> check_vma); disable whichever this JAX
-    carries."""
+    psum runs over ``clients`` only)."""
     if len(mesh.axis_names) == 1:
         return {}
-    sig_params = inspect.signature(shard_map).parameters
-    for flag in ("check_rep", "check_vma"):
-        if flag in sig_params:
-            return {flag: False}
-    return {}
+    return {"check_vma": False}
 
 
 def model_spec_dim(spec: P, model_axis: str = MODEL_AXIS) -> int | None:
@@ -502,7 +436,6 @@ class MeshLayout:
     def gather_full(self, tree, specs):
         if not self.multi_axis:
             return tree
-        from jax import lax
 
         return jax.tree.map(
             lambda x, spec: (
@@ -518,7 +451,6 @@ class MeshLayout:
     def slice_shard(self, tree):
         if not self.multi_axis:
             return tree
-        from jax import lax
 
         def s(x):
             dim = model_spec_dim(self._leaf_spec(x.shape), self.model_axis)

@@ -308,6 +308,7 @@ def build_round_block(
     # Lowered-program access for the cost profiler (observability.profiling):
     # round_block is a plain wrapper, so expose the inner jit — its signature is
     # (params, sos, data, num_samples, base_keys, lr_scales, cohort_idx,
-    # cohort_mask), with None for idx/mask selecting on-device resampling.
+    # cohort_mask, base_params), with None for idx/mask selecting on-device
+    # resampling and None for base_params unless built with frozen_base=.
     round_block.jit_program = _block
     return round_block

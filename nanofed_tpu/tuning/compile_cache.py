@@ -1,13 +1,11 @@
 """The persistent compilation cache as a managed subsystem, not an ambient
 side effect.
 
-``utils.platform.enable_compilation_cache`` points JAX's persistent cache at a
-directory and walks away; until now nothing owned what lands there, whether a
-run actually hit it, or how a cache built on one host could be trusted on
-another.  Both failed accel windows (r05, r14) burned their whole slot inside
-XLA compiles that a pre-warmed, shipped cache would have skipped — FedJAX
-(arXiv:2108.02117) amortizes jit compilation across rounds, but amortization
-starts at zero every time the cache is cold.  This module closes that gap:
+``utils.platform.enable_compilation_cache`` turns JAX's persistent cache on at
+the one directory the location rule names; this module owns what lands there,
+whether a run actually hit it, and how a cache built on one host can be trusted
+on another.  FedJAX (arXiv:2108.02117) amortizes jit compilation across rounds,
+but amortization starts at zero every time the cache is cold:
 
 * :func:`install_compile_cache_metrics` — bridges JAX's compilation-cache
   ``jax.monitoring`` events into ``nanofed_compile_cache_hits_total`` /
@@ -23,8 +21,8 @@ starts at zero every time the cache is cold.  This module closes that gap:
   a cache built under a different jaxlib is DEAD WEIGHT (XLA keys miss), and
   the manifest says so before the accel window finds out the slow way.
 
-The cache directory is shippable: ``tar`` it, move it to the accel host, point
-``NANOFED_CACHE_DIR`` (or ``--cache-dir``) at it, and verify the manifest.
+The cache directory is shippable: ``tar`` it, move it to the accelerator host,
+point ``JAX_COMPILATION_CACHE_DIR`` at it, and verify the manifest.
 """
 
 from __future__ import annotations
@@ -254,7 +252,6 @@ def warm(
     eval_every: int = 0,
     space: Any = None,
     adapter: Any = None,
-    cache_dir: str | os.PathLike | None = None,
     telemetry: Any = None,
     force: bool = False,
     compile_budget_s: float | None = None,
@@ -264,7 +261,8 @@ def warm(
     cache, off the critical path.
 
     Runs the full :func:`~nanofed_tpu.tuning.autotuner.autotune` sweep with
-    the persistent compilation cache enabled at ``cache_dir`` — every
+    the persistent compilation cache enabled (at
+    ``utils.platform.compilation_cache_dir()``) — every
     candidate round program the coordinator could dispatch gets lowered,
     compiled, and serialized into the cache (the sweep result itself lands as
     an ``autotune_*.json`` table beside the XLA entries).  One ``compile``
@@ -276,7 +274,7 @@ def warm(
     from nanofed_tpu.tuning.autotuner import autotune
     from nanofed_tpu.utils.platform import enable_compilation_cache
 
-    path = enable_compilation_cache(cache_dir)
+    path = enable_compilation_cache()
     install_compile_cache_metrics()
     t0 = time.perf_counter()
     result = autotune(

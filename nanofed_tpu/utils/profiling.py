@@ -32,14 +32,9 @@ def trace(log_dir: str | Path, host_tracer_level: int = 2) -> Iterator[None]:
     """
     log_dir = str(log_dir)
     Logger().info("profiler trace -> %s", log_dir)
-    if hasattr(jax.profiler, "ProfileOptions"):
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(log_dir, profiler_options=options)
-    else:
-        # Older JAX has no ProfileOptions; the default host tracer level still
-        # records host annotations, so the capture stays useful.
-        jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

@@ -197,8 +197,8 @@ same update budget than the barrier, at higher accuracy.""",
 
 - **Scale**: `client_chunk` trains 1000 clients on 8 chips in sequential chunks
   (`nanofed-tpu bench mnist_1000`); `compute_dtype="bfloat16"` engages the MXU.
-  Measured on ONE real v5e chip: **0.74 s** for a 1000-client round of the current
-  code — 271× the reference-extrapolated CPU baseline (`runs/bench_tpu_r05.json`).
+  `python chip_smoke.py` runs that configuration end to end on a TPU; its speed on
+  the current code is in `PERF_LEDGER.jsonl` once measured (`PERF.md`).
 - **Real networks**: `nanofed_tpu.communication` has a binary-payload HTTP server/client
   with RSA-PSS-signed updates and optional q8-delta compression;
   `examples/secure_federation/run_secure.py` is the full secure-aggregation protocol as

@@ -84,7 +84,7 @@ MODULES = [
     ("analysis", ["nanofed_tpu.analysis.fedlint",
                   "nanofed_tpu.analysis.program_audit",
                   "nanofed_tpu.analysis.contracts"]),
-    ("ops", ["nanofed_tpu.ops.reduce", "nanofed_tpu.ops.dp_reduce",
+    ("ops", ["nanofed_tpu.ops.reduce",
              "nanofed_tpu.ops.quantize"]),
     ("utils", ["nanofed_tpu.utils.logger", "nanofed_tpu.utils.profiling",
                "nanofed_tpu.utils.trees", "nanofed_tpu.utils.platform",

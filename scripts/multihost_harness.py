@@ -1638,7 +1638,12 @@ def run_federate(args: argparse.Namespace) -> int:
 
     # Canned payload base = the same deterministic init the workers publish,
     # so the servers' delta reconstruction lands on base + noise exactly.
+    # The supervisor is pinned to the CPU backend like the workers it spawns
+    # (_worker_env): on a machine with a chip, a parent that initialized JAX on
+    # it would hold it for one model.init — a chip belongs to one process.
     import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
     from nanofed_tpu.models import get_model
 

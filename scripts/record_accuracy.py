@@ -62,11 +62,7 @@ def main() -> int:
     ap.add_argument("--lr-min-factor", type=float, default=0.0)
     args = ap.parse_args()
 
-    from nanofed_tpu.utils.platform import (
-        force_cpu_mesh,
-        init_devices_or_die,
-        log_stage,
-    )
+    from nanofed_tpu.utils.platform import force_cpu_mesh, log_stage
 
     if args.platform == "cpu":
         force_cpu_mesh(args.n_devices)
@@ -78,7 +74,7 @@ def main() -> int:
     from nanofed_tpu.orchestration import Coordinator, CoordinatorConfig
     from nanofed_tpu.trainer import TrainingConfig
 
-    devices = init_devices_or_die(150.0)
+    devices = jax.devices()
     log_stage(f"devices: {len(devices)}x {devices[0].platform}")
 
     mnist_available = False

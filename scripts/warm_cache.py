@@ -5,12 +5,16 @@ The warm-ship workflow (tuning.compile_cache): on the BUILD host, pre-compile
 the full candidate program set into a cache directory off the critical path
 and stamp a toolchain manifest::
 
-    python scripts/warm_cache.py --model digits_mlp --cache-dir .jax_cache
+    python scripts/warm_cache.py --model digits_mlp
 
-then ``tar`` the directory, move it to the accel host, and on the RECEIVING
+then ``tar`` the directory, move it to the accelerator host, and on the RECEIVING
 host check the manifest before trusting a single entry::
 
-    python scripts/warm_cache.py --verify-only --cache-dir .jax_cache
+    python scripts/warm_cache.py --verify-only
+
+The directory is ``$JAX_COMPILATION_CACHE_DIR`` when set and ``<checkout>/.jax_cache``
+otherwise (``utils.platform.compilation_cache_dir``) — the one place every entry
+point keeps its compile cache.
 
 ``--verify-only`` exits 1 on an incompatible cache (foreign jax/jaxlib/
 platform — XLA would silently key-miss and recompile everything; the manifest
@@ -34,9 +38,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--capacity", type=int, default=32)
     ap.add_argument("--rounds", type=int, default=8)
     ap.add_argument("--batch-size", type=int, default=16)
-    ap.add_argument("--cache-dir", default=None,
-                    help="cache directory (default: $NANOFED_CACHE_DIR or "
-                    "./.jax_cache)")
     ap.add_argument("--compile-budget", type=float, default=None,
                     help="cap the sweep's total compile seconds (remaining "
                     "candidates are skipped, stated in the table)")
@@ -54,15 +55,9 @@ def main(argv: list[str] | None = None) -> int:
     from nanofed_tpu.tuning import verify_manifest
 
     if args.verify_only:
-        import os
+        from nanofed_tpu.utils.platform import compilation_cache_dir
 
-        # Same default resolution as utils.platform.enable_compilation_cache.
-        cache_dir = (
-            args.cache_dir
-            or os.environ.get("NANOFED_CACHE_DIR")
-            or os.path.join(os.getcwd(), ".jax_cache")
-        )
-        verdict = verify_manifest(cache_dir)
+        verdict = verify_manifest(compilation_cache_dir())
         print(json.dumps(verdict, indent=2, default=str))
         return 0 if verdict["compatible"] else 1
 
@@ -83,7 +78,6 @@ def main(argv: list[str] | None = None) -> int:
             client_chunks=(None,), rounds_per_blocks=(1, args.rounds),
             model_shards=(1,), batch_sizes=(args.batch_size,),
         ),
-        cache_dir=args.cache_dir,
         force=args.force,
         compile_budget_s=args.compile_budget,
         candidate_deadline_s=args.candidate_deadline,

@@ -22,6 +22,22 @@ def test_info(capsys):
     assert payload["devices"]
 
 
+def test_compiling_commands_enable_the_compile_cache(tmp_path, capsys, monkeypatch):
+    """run/bench/loadtest keep their programs in the one persistent cache; info, which
+    compiles nothing, does not touch the setting."""
+    from nanofed_tpu.utils import platform
+
+    calls = []
+    monkeypatch.setattr(
+        platform, "enable_compilation_cache", lambda: calls.append(1) or "unused"
+    )
+    assert main(["info"]) == 0
+    assert calls == []
+    assert main(["bench", "--list"]) == 0
+    assert calls == [1]
+    capsys.readouterr()
+
+
 def test_run_with_calibrated_dp(tmp_path, capsys):
     rc = main([
         "run", "--model", "digits_mlp", "--clients", "8", "--rounds", "2",

@@ -56,6 +56,11 @@ def test_flatten_matches_tree_ravel_layout():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_buffer_reports_where_its_slots_live():
+    buf = DeviceIngestBuffer(_params(), capacity=4)
+    assert buf.devices == {jax.devices()[0]}  # a bare jnp.zeros: the default device
+
+
 def test_offer_drain_fedavg_weighted_mean():
     params = _params()
     base = flatten_params(params)

@@ -106,12 +106,12 @@ def test_peaks_table_matches_device_kinds():
     assert peaks_for_device_kind("TPU v99", "tpu") is None
 
 
-def test_extractors_tolerate_version_shapes_and_absence():
-    class ListStyle:  # older jaxlib: one-element list of dicts
+def test_extractors_tolerate_absence():
+    class Full:
         def cost_analysis(self):
-            return [{"flops": 10.0, "bytes accessed": 4.0, "transcendentals": 1.0}]
+            return {"flops": 10.0, "bytes accessed": 4.0, "transcendentals": 1.0}
 
-    class DictStyle:  # newer jax: plain dict
+    class Partial:
         def cost_analysis(self):
             return {"flops": 7.0, "bytes accessed": 2.0}
 
@@ -122,11 +122,11 @@ def test_extractors_tolerate_version_shapes_and_absence():
         def memory_analysis(self):
             return None
 
-    assert extract_cost_analysis(ListStyle()) == {
+    assert extract_cost_analysis(Full()) == {
         "flops": 10.0, "transcendentals": 1.0, "bytes_accessed": 4.0
     }
-    assert extract_cost_analysis(DictStyle())["flops"] == 7.0
-    assert extract_cost_analysis(DictStyle())["transcendentals"] == 0.0
+    assert extract_cost_analysis(Partial())["flops"] == 7.0
+    assert extract_cost_analysis(Partial())["transcendentals"] == 0.0
     # A missing analysis degrades to zeros — it must never raise.
     assert extract_cost_analysis(Broken())["flops"] == 0.0
     assert extract_memory_analysis(Broken())["peak_bytes"] == 0

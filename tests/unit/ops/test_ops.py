@@ -1,10 +1,14 @@
 """Pallas ops: parity against the XLA/numpy reference implementations (interpret mode on
 the CPU mesh; the same code runs as real kernels on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from nanofed_tpu import ops
 from nanofed_tpu.ops import (
     add_mask,
     dequantize_u32,
@@ -85,70 +89,37 @@ class TestMask:
         assert np.mean(np.asarray(m1) == np.asarray(m2)) < 0.01
 
 
-class TestDPReduce:
-    """Fused clip+mean (ops.dp_reduce) vs the straightforward clip-then-mean."""
 
-    def _reference(self, x, w, clip):
-        norms = np.linalg.norm(x, axis=1)
-        coef = np.minimum(1.0, clip / np.maximum(norms, 1e-12))
-        clipped = x * coef[:, None]
-        return (w[:, None] * clipped).sum(axis=0) / max(w.sum(), 1e-12)
 
-    def test_row_sq_norms(self):
-        from nanofed_tpu.ops import row_sq_norms
+# Every other test here runs the kernels through the Pallas interpreter; this one takes
+# each through the real Pallas -> Mosaic lowering for the TPU platform, which needs no
+# TPU (the Mosaic compile itself does — chip_smoke.py covers that with interpret=False).
+_X = jnp.zeros((40, 1300), jnp.float32)
+_W = jnp.ones((40,), jnp.float32)
+_TPU_LOWERINGS = {
+    "quantize_u32": (ops.quantize_u32, (_X[0],)),
+    "dequantize_u32": (ops.dequantize_u32, (jnp.zeros((1300,), jnp.uint32),)),
+    "add_mask": (
+        ops.add_mask,
+        (jnp.zeros((1300,), jnp.uint32), jnp.arange(4, dtype=jnp.int32), jnp.int32(1)),
+    ),
+    "weighted_mean_flat": (ops.weighted_mean_flat, (_X, _W)),
+    "masked_weighted_mean_flat": (ops.masked_weighted_mean_flat, (_X, _W, _W)),
+    "dequant_accumulate_flat": (
+        ops.dequant_accumulate_flat, (_X.astype(jnp.int8), _W, _W, _X[0]),
+    ),
+    "weighted_mean_tree": (ops.weighted_mean_tree, ({"w": _X.reshape(40, 13, 100)}, _W)),
+}
 
-        rng = np.random.default_rng(0)
-        x = rng.normal(size=(7, 1300)).astype(np.float32)  # P not a tile multiple
-        got = np.asarray(row_sq_norms(jnp.asarray(x)))
-        np.testing.assert_allclose(got, (x.astype(np.float64) ** 2).sum(1), rtol=1e-5)
 
-    def test_fused_matches_clip_then_mean(self):
-        from nanofed_tpu.ops import dp_clipped_mean_flat
+@pytest.mark.parametrize("name", sorted(_TPU_LOWERINGS))
+def test_kernel_lowers_for_tpu(name):
+    fn, args = _TPU_LOWERINGS[name]
+    lowered = jax.jit(functools.partial(fn, interpret=False)).trace(*args).lower(
+        lowering_platforms=("tpu",)
+    )
+    assert "tpu_custom_call" in lowered.as_text()
 
-        rng = np.random.default_rng(1)
-        x = rng.normal(size=(9, 700)).astype(np.float32) * 3.0
-        w = np.ones(9, np.float32)
-        got = np.asarray(dp_clipped_mean_flat(jnp.asarray(x), jnp.asarray(w), 1.0))
-        np.testing.assert_allclose(got, self._reference(x, w, 1.0), rtol=2e-5, atol=1e-6)
 
-    def test_fused_denominator_is_participant_sum(self):
-        # All rows over the clip bound: result must be mean of clip-scaled rows over
-        # sum(w), NOT over sum(w * coef) — the sensitivity-C/K contract.
-        from nanofed_tpu.ops import dp_clipped_mean_flat
-
-        x = np.full((4, 600), 10.0, np.float32)  # every norm >> clip
-        w = np.ones(4, np.float32)
-        got = np.asarray(dp_clipped_mean_flat(jnp.asarray(x), jnp.asarray(w), 1.0))
-        np.testing.assert_allclose(got, self._reference(x, w, 1.0), rtol=2e-5)
-        # Sanity: each row scaled to norm 1 -> mean row has norm ~1 (not ~4).
-        assert abs(np.linalg.norm(got) - 1.0) < 1e-3
-
-    def test_dropout_weight_zero_excluded(self):
-        from nanofed_tpu.ops import dp_clipped_mean_flat
-
-        rng = np.random.default_rng(2)
-        x = rng.normal(size=(5, 640)).astype(np.float32)
-        w = np.array([1, 0, 1, 1, 0], np.float32)
-        got = np.asarray(dp_clipped_mean_flat(jnp.asarray(x), jnp.asarray(w), 0.5))
-        np.testing.assert_allclose(got, self._reference(x, w, 0.5), rtol=2e-5, atol=1e-6)
-
-    def test_tree_wrapper_matches_round_step_math(self):
-        # central_dp_reduce_stacked == the materializing round-step DP reduce
-        # (clip_deltas + psum_weighted_mean with uniform weights) on one device.
-        from nanofed_tpu.ops import central_dp_reduce_stacked
-        from nanofed_tpu.utils.trees import tree_clip_by_global_norm
-
-        rng = np.random.default_rng(3)
-        stacked = {
-            "w": jnp.asarray(rng.normal(size=(6, 20, 10)).astype(np.float32)),
-            "b": jnp.asarray(rng.normal(size=(6, 10)).astype(np.float32) * 5),
-        }
-        w = jnp.ones(6)
-        clip = 0.7
-        got = central_dp_reduce_stacked(stacked, w, clip)
-        clipped = jax.vmap(lambda d: tree_clip_by_global_norm(d, clip)[0])(stacked)
-        want = jax.tree.map(lambda leaf: (leaf * w[:, None, None] if leaf.ndim == 3
-                                          else leaf * w[:, None]).sum(0) / w.sum(),
-                            clipped)
-        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=1e-6)
+def test_every_exported_kernel_has_a_tpu_lowering_case():
+    assert set(_TPU_LOWERINGS) == set(ops.__all__)

@@ -118,10 +118,7 @@ def test_hierarchical_psum_matches_flat(devices):
         return lax.psum(v.sum(), (HOST_AXIS, CLIENT_AXIS))
 
     kw = dict(mesh=mesh, in_specs=P((HOST_AXIS, CLIENT_AXIS)), out_specs=P())
-    import inspect
-
-    sig = inspect.signature(shard_map).parameters
-    flag = {f: False for f in ("check_rep", "check_vma") if f in sig}
+    flag = {"check_vma": False}
     got_h = jax.jit(shard_map(hier, **kw, **flag))(x)
     got_f = jax.jit(shard_map(flat, **kw, **flag))(x)
     assert float(got_h) == pytest.approx(float(got_f))
@@ -137,10 +134,7 @@ def test_hierarchical_helpers_single_axis_degenerate(devices):
         g = hierarchical_all_gather(v, CLIENT_AXIS)
         return s, m, g
 
-    import inspect
-
-    sig = inspect.signature(shard_map).parameters
-    flag = {f: False for f in ("check_rep", "check_vma") if f in sig}
+    flag = {"check_vma": False}
     s, m, g = jax.jit(
         shard_map(body, mesh=mesh, in_specs=P(CLIENT_AXIS),
                   out_specs=(P(), P(), P(CLIENT_AXIS)), **flag)
@@ -156,10 +150,7 @@ def test_hierarchical_all_gather_collects_every_row(devices):
     def body(v):
         return hierarchical_all_gather(v, (HOST_AXIS, CLIENT_AXIS))
 
-    import inspect
-
-    sig = inspect.signature(shard_map).parameters
-    flag = {f: False for f in ("check_rep", "check_vma") if f in sig}
+    flag = {"check_vma": False}
     out = jax.jit(
         shard_map(body, mesh=mesh, in_specs=P((HOST_AXIS, CLIENT_AXIS)),
                   out_specs=P((HOST_AXIS, CLIENT_AXIS)), **flag)

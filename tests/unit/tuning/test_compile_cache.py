@@ -1,11 +1,14 @@
-"""Compile-cache lifecycle tests: the hit/miss counter bridge, the manifest
-round-trip + toolchain verification, and the ``warm()`` pre-compile pass."""
+"""Compile-cache lifecycle tests: the location rule, the hit/miss counter bridge,
+the manifest round-trip + toolchain verification, and the ``warm()`` pre-compile
+pass."""
 
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.compilation_cache import compilation_cache as jax_cache
 
 from nanofed_tpu.models import get_model
 from nanofed_tpu.observability.registry import MetricsRegistry
@@ -19,7 +22,10 @@ from nanofed_tpu.tuning import (
     write_manifest,
 )
 from nanofed_tpu.tuning import compile_cache
-from nanofed_tpu.utils.platform import enable_compilation_cache
+from nanofed_tpu.utils.platform import (
+    compilation_cache_dir,
+    enable_compilation_cache,
+)
 from nanofed_tpu.tuning.compile_cache import (
     COMPILE_CACHE_HITS,
     COMPILE_CACHE_MISSES,
@@ -33,6 +39,53 @@ ONE_CAND_SPACE = TuningSpace(
     client_chunks=(None,), rounds_per_blocks=(1,), model_shards=(1,),
     batch_sizes=(16,),
 )
+
+REPO = Path(__file__).resolve().parents[3]
+
+
+@pytest.fixture
+def cache_env(tmp_path, monkeypatch):
+    """A private cache directory placed the way an operator places one: through
+    ``JAX_COMPILATION_CACHE_DIR``.  jax binds that variable at import, so the
+    fixture sets the config value it would have produced, and un-latches the
+    cache object around the test (earlier tests compiled under another dir)."""
+    cache = tmp_path / "cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(cache))
+    jax.config.update("jax_enable_compilation_cache", True)  # conftest turns it off
+    jax_cache.reset_cache()
+    yield cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_compilation_cache_dir", before)
+    jax_cache.reset_cache()
+
+
+class TestLocationRule:
+    def test_env_dir_wins_and_config_is_left_alone(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+        updates = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda *a, **k: updates.append(a)
+        )
+        assert enable_compilation_cache() == str(tmp_path / "outside")
+        assert updates == []
+        assert not (tmp_path / "outside").exists()
+
+    def test_default_is_the_checkout_whatever_the_cwd(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        updates = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda *a, **k: updates.append(a)
+        )
+        seen = []
+        for cwd in (tmp_path, REPO / "tests"):
+            monkeypatch.chdir(cwd)
+            assert compilation_cache_dir() == str(REPO / ".jax_cache")
+            seen.append(enable_compilation_cache())
+        assert seen == [str(REPO / ".jax_cache")] * 2
+        assert ("jax_compilation_cache_dir", str(REPO / ".jax_cache")) in updates
+
 
 # jax.monitoring keeps listeners forever, so the FIRST install in the process
 # wins the registry (another test in the same pytest run — e.g. warm() — may
@@ -54,12 +107,9 @@ class TestCounterBridge:
         # live in the first caller's registry and nowhere else.
         assert COMPILE_CACHE_HITS in reg.snapshot()
 
-    def test_miss_then_hit_counted(self, tmp_path):
+    def test_miss_then_hit_counted(self, cache_env):
         REGISTRY = adopted_registry()
-        # Route through enable_compilation_cache: it resets jax's latched
-        # cache object, so this works even after earlier tests compiled with
-        # a different (or no) cache dir in this process.
-        enable_compilation_cache(tmp_path)
+        min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         try:
             def misses():
@@ -82,7 +132,9 @@ class TestCounterBridge:
             jax.jit(lambda a: jnp.tanh(a) @ a.T)(x).block_until_ready()
             assert hits() > h1 and misses() == m1
         finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", min_secs
+            )
 
 
 class TestManifest:
@@ -122,12 +174,9 @@ class TestManifest:
 
 
 class TestWarm:
-    def test_warm_compiles_and_stamps_manifest(self, tmp_path):
-        cache = tmp_path / "cache"
-        result = warm(
-            MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE,
-            cache_dir=cache,
-        )
+    def test_warm_compiles_and_stamps_manifest(self, cache_env):
+        cache = cache_env
+        result = warm(MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE)
         assert result.autotune.compiles == 1
         assert result.programs[0]["program"].startswith("cand_")
         assert result.programs[0]["compile_seconds"] > 0
@@ -139,19 +188,17 @@ class TestWarm:
         assert d["autotune_entries"]
         assert verify_manifest(cache)["compatible"] is True
 
-    def test_rewarm_hits_the_autotune_cache(self, tmp_path):
-        cache = tmp_path / "cache"
-        warm(MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE,
-             cache_dir=cache)
-        again = warm(MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE,
-                     cache_dir=cache)
+    def test_rewarm_hits_the_autotune_cache(self, cache_env):
+        cache = cache_env
+        warm(MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE)
+        again = warm(MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE)
         assert again.autotune.cache_hit is True
         assert again.autotune.compiles == 0
         assert again.programs == []
         manifest = json.loads((cache / "manifest.json").read_text())
         assert manifest["warmed"]["cache_hit"] is True
 
-    def test_warm_emits_compile_records(self, tmp_path):
+    def test_warm_emits_compile_records(self, cache_env):
         class FakeTelemetry:
             def __init__(self):
                 self.records = []
@@ -162,6 +209,6 @@ class TestWarm:
         tel = FakeTelemetry()
         warm(
             MODEL, POP, TRAINING, num_rounds=2, space=ONE_CAND_SPACE,
-            cache_dir=tmp_path / "cache", telemetry=tel, force=True,
+            telemetry=tel, force=True,
         )
         assert [r for r in tel.records if r["type"] == "compile"]
