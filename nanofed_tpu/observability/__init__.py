@@ -35,6 +35,7 @@ scrape ``/metrics`` or read ``telemetry.jsonl``.
 from nanofed_tpu.observability.critical_path import (
     CRITICAL_PATH_HISTOGRAM,
     CRITICAL_PATH_SEGMENTS,
+    SYNC_LOOP_SEGMENTS,
     clock_offsets,
     critical_path_rounds,
     federation_timeline,
@@ -60,7 +61,12 @@ from nanofed_tpu.observability.registry import (
     MetricsRegistry,
     get_registry,
 )
-from nanofed_tpu.observability.spans import SPAN_HISTOGRAM, SpanRecord, SpanTracer
+from nanofed_tpu.observability.spans import (
+    SPAN_HISTOGRAM,
+    SpanRecord,
+    SpanTiming,
+    SpanTracer,
+)
 from nanofed_tpu.observability.telemetry import (
     TELEMETRY_FILENAME,
     RunTelemetry,
@@ -94,7 +100,9 @@ __all__ = [
     "ProgramCostReport",
     "RunTelemetry",
     "SPAN_HISTOGRAM",
+    "SYNC_LOOP_SEGMENTS",
     "SpanRecord",
+    "SpanTiming",
     "SpanTracer",
     "TELEMETRY_FILENAME",
     "TRACE_VERSION",

@@ -34,6 +34,12 @@ pool-busy seconds attributed to the round and ``wire_wait`` as the measured
 beat wait MINUS that overlap — the six segments then partition the loop body,
 and their sum tracks the measured round walltime (the residue is heartbeat
 and bookkeeping slivers).
+
+The synchronous loop (``orchestration.Coordinator``, single-step or fused) feeds
+the same ledger with its own five, :data:`SYNC_LOOP_SEGMENTS`: ``prepare``,
+``dispatch``, ``device_wait``, ``readback`` and ``publish`` are cut at the
+loop's own spans' clock readings and tile one step of ``start_training()``
+exactly, so there the charged walltime IS their sum and the coverage reads 1.0.
 """
 
 from __future__ import annotations
@@ -48,6 +54,7 @@ from nanofed_tpu.observability.telemetry import TELEMETRY_FILENAME
 __all__ = [
     "CRITICAL_PATH_HISTOGRAM",
     "CRITICAL_PATH_SEGMENTS",
+    "SYNC_LOOP_SEGMENTS",
     "clock_offsets",
     "critical_path_rounds",
     "federation_timeline",
@@ -57,9 +64,17 @@ __all__ = [
     "segment_digest",
 ]
 
-#: The per-round decomposition, in critical-path order.
+#: The synchronous loop's decomposition (``orchestration.Coordinator``): five
+#: sequential stretches that tile one step of ``start_training()``, fused or not.
+SYNC_LOOP_SEGMENTS = (
+    "prepare", "dispatch", "device_wait", "readback", "publish",
+)
+
+#: Every segment a ``round`` record may carry: the federate worker's six and the
+#: synchronous loop's five, each in critical-path order (``publish`` closes both).
+#: One record carries one loop's names, never a mix.
 CRITICAL_PATH_SEGMENTS = (
-    "wire_wait", "decode", "drain", "collective", "apply", "publish",
+    "wire_wait", "decode", "drain", "collective", "apply", *SYNC_LOOP_SEGMENTS,
 )
 
 #: Registry histogram the RoundLedger publishes the segments under.

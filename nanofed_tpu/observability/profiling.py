@@ -41,13 +41,13 @@ disk hit.  That is why ``Coordinator`` profiling is opt-in
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterable, NamedTuple
+from typing import Any, Callable, Iterable, Mapping, NamedTuple
 
 from nanofed_tpu.observability.registry import MetricsRegistry, get_registry
-from nanofed_tpu.observability.spans import SPAN_HISTOGRAM
 
 #: Gauge/histogram names (the metric inventory in docs/observability.md).
 PROGRAM_FLOPS_GAUGE = "nanofed_program_flops_total"
@@ -479,44 +479,33 @@ class ProgramCatalog:
         ).observe(report.compile_seconds, program=report.program)
 
 
-def update_device_occupancy(registry: MetricsRegistry | None = None) -> float | None:
-    """Derive ``nanofed_device_occupancy_ratio`` from the span histogram and set
-    the gauge; returns the ratio (or None when no spans have been recorded).
+def update_device_occupancy(
+    segments: Mapping[str, float], registry: MetricsRegistry | None = None
+) -> float | None:
+    """Set ``nanofed_device_occupancy_ratio`` from one round's (or one fused block's)
+    critical-path segments and return it; None, the gauge untouched, when the
+    segments hold no ``device_wait`` (a round that FAILED before any dispatch).
 
-    Occupancy here is the fraction of orchestration walltime the host spent
-    blocked ON the device rather than doing host work around it — a LOWER bound
-    on true device busy-fraction (the device also computes while the fused
-    dispatch enqueues), but one derivable from the spans the loop already emits:
-
-    * fused blocks: ``host_sync`` (the one device barrier per block) over
-      ``dispatch + host_sync + publish``;
-    * single rounds: the ``local-train`` span (which blocks until the device
-      round completes, so its duration IS device time) over ``round + publish``.
-
-    ``publish`` (checkpoint + metrics JSON + versioned model, recorded OUTSIDE
-    the round/dispatch spans in both loops) belongs in the denominator: it is
-    host orchestration time the device spends idle, and omitting it would let
-    a publish-heavy run report occupancy ABOVE the truth — the opposite of a
-    lower bound.  The fused split wins when both exist — a run that mixes
-    fused blocks with ragged single-round tails is dominated by its blocks.
+    Occupancy here is the fraction of the loop's walltime the host spent blocked ON
+    the device rather than doing host work around it: ``device_wait`` over the sum of
+    the five segments the Coordinator tiles a generator step into (``prepare``,
+    ``dispatch``, ``device_wait``, ``readback``, ``publish``).  It is a LOWER bound
+    on the device's true busy fraction — the device also computes while ``dispatch``
+    is still enqueueing — and, being of the last round alone, it forgets the
+    compile-and-warm first round as soon as that is over.  ``publish`` (checkpoint,
+    metrics JSON, versioned model) is in the denominator: it is host time the device
+    spends idle, and leaving it out would let a publish-heavy run read ABOVE the
+    truth, the opposite of a lower bound.
     """
-    reg = registry or get_registry()
-    hist = reg.histogram(SPAN_HISTOGRAM, labels=("span",))
-    sync = hist.sample_sum(span="host_sync")
-    dispatch = hist.sample_sum(span="dispatch")
-    publish = hist.sample_sum(span="publish")
-    if sync + dispatch > 0:
-        busy, total = sync, sync + dispatch + publish
-    else:
-        busy = hist.sample_sum(span="local-train")
-        total = hist.sample_sum(span="round") + publish
-    if total <= 0:
+    wait = segments.get("device_wait")
+    total = math.fsum(segments.values())
+    if wait is None or total <= 0:
         return None
-    ratio = min(1.0, busy / total)
-    reg.gauge(
+    ratio = min(1.0, wait / total)
+    (registry or get_registry()).gauge(
         DEVICE_OCCUPANCY_GAUGE,
-        "Host-blocked-on-device fraction of orchestration walltime (lower "
-        "bound on device occupancy), derived from dispatch/host_sync spans",
+        "Host-blocked-on-device fraction of the round loop's walltime (lower "
+        "bound on device occupancy), from the last round's critical-path segments",
     ).set(ratio)
     return ratio
 

@@ -10,7 +10,7 @@ as named slices inside a device capture taken with ``utils.profiling.trace``.
 Exports:
 
 * **JSONL** — one record per closed span; ``observability.telemetry.RunTelemetry``
-  streams these into the per-run ``telemetry.jsonl`` as they close.
+  writes these into the per-run ``telemetry.jsonl``, a round's at a time.
 * **Chrome trace** (``trace_event`` format) — loadable in ``chrome://tracing`` or
   Perfetto, mergeable with the device captures TensorBoard's profiler writes.
 * A metrics bridge — each closed span observes into a
@@ -62,13 +62,31 @@ class SpanRecord:
         return out
 
 
+class SpanTiming:
+    """What ``SpanTracer.span`` yields: the span's own two ``perf_counter`` readings,
+    ``t_start`` as it opens and ``t_end`` as it closes.  A caller that tiles a stretch
+    of time out of its spans — the Coordinator's round segments — takes its
+    boundaries from here, so the tiling and the spans are one set of clock readings,
+    not two side by side."""
+
+    __slots__ = ("t_start", "t_end")
+
+    def __init__(self) -> None:
+        self.t_start = 0.0
+        self.t_end = 0.0
+
+    @property
+    def duration_s(self) -> float:
+        return self.t_end - self.t_start
+
+
 class SpanTracer:
     """Collects nested spans; thread-safe (each thread nests independently via a
     thread-local stack, closed spans land in one shared list).
 
     ``on_close`` (if given) is called with each ``SpanRecord`` as it closes —
-    ``RunTelemetry`` uses this to stream spans into ``telemetry.jsonl`` so a crashed
-    run still has every completed phase on disk.
+    ``RunTelemetry`` uses this to put spans into ``telemetry.jsonl``, so a crashed
+    run still has every completed round's phases on disk.
 
     ``keep_records`` controls in-memory retention (what ``records`` /
     ``phase_summary`` / the exports read).  Default: retain only when there is NO
@@ -109,8 +127,10 @@ class SpanTracer:
         return stack
 
     @contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[None]:
-        """Time the enclosed block as a span named ``name``; nests freely."""
+    def span(self, name: str, **attrs: Any) -> Iterator[SpanTiming]:
+        """Time the enclosed block as a span named ``name``; nests freely.  Yields the
+        span's :class:`SpanTiming`, complete once the block has exited."""
+        timing = SpanTiming()
         stack = self._stack()
         with self._lock:
             span_id = self._next_id
@@ -129,11 +149,12 @@ class SpanTracer:
                 annotation = None
         # fedlint: disable=FED010 (forensics-only: start_unix aligns spans across PROCESSES — durations use perf_counter below; a per-process virtual clock cannot provide a cross-process common timeline)
         start_unix = time.time()
-        t0 = time.perf_counter()
+        timing.t_start = time.perf_counter()
         try:
-            yield
+            yield timing
         finally:
-            duration = time.perf_counter() - t0
+            timing.t_end = time.perf_counter()
+            duration = timing.duration_s
             if annotation is not None:
                 try:
                     annotation.__exit__(None, None, None)

@@ -1,12 +1,12 @@
 """Per-run telemetry artifact and the JAX-event bridge.
 
 ``RunTelemetry`` owns one run's ``telemetry.jsonl``: an append-only stream of typed
-JSON records — ``span`` records streamed from a :class:`~nanofed_tpu.observability.
-spans.SpanTracer` as each phase closes, ``round`` records appended by the coordinator
-after each round, and a final ``metrics_snapshot`` of the whole registry on ``close()``.
-Append-per-record (with a flush) means a crashed run still has every completed round
-and phase on disk — the failure mode the reference's end-of-run metrics JSON cannot
-cover.
+JSON records — ``span`` records from a :class:`~nanofed_tpu.observability.spans.
+SpanTracer`, ``round`` records appended by the coordinator after each round (a round's
+spans go to disk with its record, in one append), and a final ``metrics_snapshot`` of
+the whole registry on ``close()``.  An append per round means a crashed run still has
+every completed round and its phases on disk — the failure mode the reference's
+end-of-run metrics JSON cannot cover.
 
 ``install_jax_event_bridge`` forwards ``jax.monitoring`` events (compilation-cache
 hits/misses, backend init, compile durations) into the metrics registry, which is how
@@ -30,10 +30,21 @@ from nanofed_tpu.observability.spans import SpanRecord, SpanTracer
 
 TELEMETRY_FILENAME = "telemetry.jsonl"
 
+#: Most span lines a ``RunTelemetry`` holds back before it writes them anyway (a
+#: tracer with no round loop behind it — a server's — never sees another record).
+SPAN_BATCH = 64
+
 
 class RunTelemetry:
     """One run's telemetry sink: a tracer wired to stream spans into
     ``<run_dir>/telemetry.jsonl``, plus typed record appends for round results.
+
+    Span lines ride the next record's write: on the chip's host one ``os.write``
+    costs several times the rest of a span (PERF.md, PR 24), and a round loop closes
+    eleven spans and then charges one ``round`` record.  So a closed span is held —
+    at most ``SPAN_BATCH`` of them — until any other record, or ``close()``, puts
+    them all on disk in one append, in the order they closed.  A crash loses the
+    spans closed since the last record: less than one round's.
 
     Usage (what both coordinators do)::
 
@@ -66,6 +77,7 @@ class RunTelemetry:
             str(self.path), os.O_APPEND | os.O_CREAT | os.O_WRONLY, 0o644
         )
         self._closed = False
+        self._held_spans: list[str] = []
         self.tracer = SpanTracer(
             registry=self.registry,
             on_close=self._on_span_close,
@@ -79,14 +91,21 @@ class RunTelemetry:
         self.record("span", **record.to_dict())
 
     def record(self, record_type: str, **fields: Any) -> None:
-        """Append one typed JSON line; silently a no-op after ``close()`` (a late
+        """Append one typed JSON line — a ``span`` line is held for the next write
+        (see the class docstring); silently a no-op after ``close()`` (a late
         straggler span must not raise inside a finally block)."""
         # fedlint: disable=FED010 (forensics-only: the `t` stamp exists to line telemetry.jsonl up against external logs/dashboards by real wall time — a virtual clock here would date every record 1970 and break cross-artifact correlation)
         line = json.dumps({"type": record_type, "t": round(time.time(), 3), **fields})
         with self._lock:
             if self._closed:
                 return
-            os.write(self._fd, (line + "\n").encode("utf-8"))
+            self._held_spans.append(line)
+            if record_type != "span" or len(self._held_spans) >= SPAN_BATCH:
+                self._append(self._held_spans)
+                self._held_spans.clear()
+
+    def _append(self, lines: list[str]) -> None:
+        os.write(self._fd, ("\n".join(lines) + "\n").encode("utf-8"))
 
     def close(self) -> None:
         """Append the final registry snapshot and release the file handle.
@@ -99,7 +118,8 @@ class RunTelemetry:
                 {"type": "metrics_snapshot", "t": round(time.time(), 3),
                  "metrics": self.registry.snapshot()}
             )
-            os.write(self._fd, (snapshot + "\n").encode("utf-8"))
+            self._append([*self._held_spans, snapshot])
+            self._held_spans.clear()
             self._closed = True
             os.close(self._fd)
 
@@ -186,6 +206,7 @@ def summarize_telemetry(path: str | Path) -> dict[str, Any]:
     rounds: dict[str, int] = {}
     round_durations: list[float] = []
     segment_durations: dict[str, list[float]] = {}
+    segment_coverage: list[float] = []
     clock_syncs: list[dict[str, Any]] = []
     snapshot: dict[str, Any] | None = None
     program_profiles: dict[str, dict[str, Any]] = {}
@@ -226,8 +247,14 @@ def summarize_telemetry(path: str | Path) -> dict[str, Any]:
                 # Critical-path decomposition (observability.critical_path):
                 # federate workers attach per-round segment timings that tile
                 # the round walltime — accumulate per segment for the digest.
-                for seg, v in (rec.get("segments") or {}).items():
+                segments = rec.get("segments") or {}
+                for seg, v in segments.items():
                     segment_durations.setdefault(str(seg), []).append(float(v))
+                if segments and float(rec.get("duration_s", 0.0)) > 0:
+                    segment_coverage.append(
+                        math.fsum(map(float, segments.values()))
+                        / float(rec["duration_s"])
+                    )
             elif rtype == "metrics_snapshot":
                 snapshot = rec.get("metrics")
             elif rtype == "program_profile":
@@ -457,6 +484,14 @@ def summarize_telemetry(path: str | Path) -> dict[str, Any]:
         # apply / publish, digested per segment across all rounds seen.
         out["critical_path"] = {
             seg: _digest(d) for seg, d in sorted(segment_durations.items())
+        }
+    if segment_coverage:
+        # How much of each round's charged walltime its segments account for
+        # (the federate worker's bar is 0.95; the synchronous loop tiles exactly).
+        out["critical_path_coverage"] = {
+            "rounds": len(segment_coverage),
+            "min": round(min(segment_coverage), 4),
+            "mean": round(math.fsum(segment_coverage) / len(segment_coverage), 4),
         }
     if clock_syncs:
         walls = sorted(

@@ -27,7 +27,7 @@ import contextlib
 import json
 import math
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from pathlib import Path
 from typing import Any, Callable, Iterator
 
@@ -46,7 +46,7 @@ from nanofed_tpu.observability.profiling import (
     update_device_occupancy,
 )
 from nanofed_tpu.observability.registry import get_registry
-from nanofed_tpu.observability.spans import SpanTracer
+from nanofed_tpu.observability.spans import SpanTiming, SpanTracer
 from nanofed_tpu.observability.telemetry import RunTelemetry, install_jax_event_bridge
 from nanofed_tpu.orchestration.engine import RoundLedger, completion_required
 from nanofed_tpu.orchestration.types import RoundMetrics, RoundStatus, TrainingProgress
@@ -1313,6 +1313,9 @@ class Coordinator:
         with self._log.context("coordinator"):
             try:
                 while self.current_round < self.config.num_rounds:
+                    # The top of a generator step: the round's ``prepare`` segment
+                    # runs from this reading (see _train_round).
+                    resumed_at = time.perf_counter()
                     # Retune checks run BETWEEN blocks (the swap-safe boundary):
                     # the next dispatch picks up a swapped program, the one in
                     # flight never changes under its own feet.
@@ -1322,16 +1325,23 @@ class Coordinator:
                         # _train_block publishes + advances state for the whole
                         # block before anything is yielded, so abandonment cannot
                         # leave params ahead of the recorded round counter.
-                        for metrics in self._train_block(n):
+                        for metrics in self._train_block(n, resumed_at):
                             yield metrics
                         continue
-                    metrics = self._train_round(self.current_round)
+                    metrics, round_span = self._train_round(
+                        self.current_round, resumed_at
+                    )
                     self.history.append(metrics)
                     with self._tracer.span("publish", round=metrics.round_id):
                         self._publish_round(metrics)
                     if self.on_round_end is not None:
                         self.on_round_end(metrics)
                     self.current_round += 1
+                    metrics = self._close_round(metrics, round_span.t_end)
+                    self._observe_retune(
+                        1, round_span.duration_s,
+                        update_device_occupancy(metrics.segments, self._registry),
+                    )
                     yield metrics
             finally:
                 # Final registry snapshot only when ALL rounds ran: a caller that
@@ -1367,6 +1377,34 @@ class Coordinator:
                             merges=self._merge_count,
                         )
                     self.telemetry.close()
+
+    def _close_round(
+        self, metrics: RoundMetrics, publish_from: float, **record_fields: Any
+    ) -> RoundMetrics:
+        """A round's last act before it is yielded.  One clock reading closes the
+        ``publish`` segment — everything since ``publish_from``: the ``publish``
+        span, ``on_round_end`` — the five segments replace the four on the metrics
+        (and on ``history``), and the ledger is charged with them: the ``round``
+        record and ``nanofed_round_critical_path_seconds{segment}`` get the whole
+        tiling, and the charged ``duration_s`` is its sum, the round's share of the
+        generator's time, publish included (as the federate worker charges its
+        beats).  The charge itself falls after the reading and into no segment."""
+        segments = {
+            **metrics.segments, "publish": time.perf_counter() - publish_from,
+        }
+        metrics = replace(metrics, segments=segments)
+        self.history[-1] = metrics
+        step_s = math.fsum(segments.values())
+        self._ledger.charge(
+            status=metrics.status.name, num_clients=metrics.num_clients,
+            duration_s=step_s, expected=self.cohort_size, segments=segments,
+            telemetry_fields=dict(
+                round=metrics.round_id, status=metrics.status.name,
+                num_clients=metrics.num_clients, duration_s=round(step_s, 6),
+                **record_fields,
+            ),
+        )
+        return metrics
 
     def _publish_round(self, metrics: RoundMetrics, persist_state: bool = True) -> None:
         """Release the round's artifacts — checkpoint, metrics JSON, versioned model.
@@ -1609,7 +1647,7 @@ class Coordinator:
                     - (self.current_round % self.config.eval_every))
         return n if n == rpb else 1
 
-    def _train_block(self, n: int) -> list[RoundMetrics]:
+    def _train_block(self, n: int, resumed_at: float) -> list[RoundMetrics]:
         """Run ``n`` rounds as one fused device block.
 
         Host work splits into exactly two phases, each its own span so phase
@@ -1619,12 +1657,19 @@ class Coordinator:
         ``block_until_ready`` + stacked-metrics fetch at the block boundary).
         Cohorts, keys, and lr scales are the SAME pure host functions of the
         round index the single-round path uses, so a fused run reproduces the
-        unfused trajectory round for round."""
+        unfused trajectory round for round.
+
+        Every round of the block reports the single-step loop's five segments
+        (``_train_round``): the block's ``prepare`` (from ``resumed_at`` to the end of
+        ``round-keys``), ``dispatch`` (the rest of the ``dispatch`` span),
+        ``device_wait`` (the ``device-wait`` span inside ``host_sync``) and
+        ``readback`` (the rest of ``host_sync``) in even shares, plus what the round
+        itself took after the block came back: building its metrics goes to its
+        ``readback``, and ``publish`` is its own."""
         cfg = self.config
         first = self.current_round
         rounds = list(range(first, first + n))
         required = completion_required(self.cohort_size, cfg.min_completion_rate)
-        t0 = time.perf_counter()
 
         with self._tracer.span("dispatch", round=first, rounds=n):
             with self._tracer.span("cohort-sample", round=first, rounds=n):
@@ -1640,17 +1685,18 @@ class Coordinator:
                         idx_rows[i], mask_rows[i] = self._place_cohort(survived)
                     else:
                         mask_rows[i, survived] = 1.0
-            lr_scales = lr_schedule_scales(
-                cfg.lr_schedule, first, n, cfg.num_rounds,
-                min_factor=cfg.lr_min_factor, decay_every=cfg.lr_decay_every,
-                gamma=cfg.lr_decay_gamma,
-            )
-            # Device-ready inputs BEFORE the guarded dispatch: under strict mode
-            # the jit call itself must perform zero implicit h2d transfers.
-            base_keys = stack_round_keys(cfg.seed, rounds)
-            lr_dev = jnp.asarray(lr_scales, jnp.float32)
-            idx_dev = jnp.asarray(idx_rows) if self._cohort_mode else None
-            mask_dev = jnp.asarray(mask_rows)
+            with self._tracer.span("round-keys", round=first, rounds=n) as keys:
+                lr_scales = lr_schedule_scales(
+                    cfg.lr_schedule, first, n, cfg.num_rounds,
+                    min_factor=cfg.lr_min_factor, decay_every=cfg.lr_decay_every,
+                    gamma=cfg.lr_decay_gamma,
+                )
+                # Device-ready inputs BEFORE the guarded dispatch: under strict mode
+                # the jit call itself must perform zero implicit h2d transfers.
+                base_keys = stack_round_keys(cfg.seed, rounds)
+                lr_dev = jnp.asarray(lr_scales, jnp.float32)
+                idx_dev = jnp.asarray(idx_rows) if self._cohort_mode else None
+                mask_dev = jnp.asarray(mask_rows)
             with self._dispatch_guard():
                 result = self._round_block(
                     self.params, self.server_state, self._data,
@@ -1660,9 +1706,10 @@ class Coordinator:
             self.params = result.params
             self.server_state = result.server_opt_state
 
-        with self._tracer.span("host_sync", round=first, rounds=n):
-            # fedlint: disable=FED001 (the ONE deliberate host sync per fused block — the host_sync span exists to measure exactly this barrier)
-            jax.block_until_ready(self.params)
+        with self._tracer.span("host_sync", round=first, rounds=n) as sync:
+            with self._tracer.span("device-wait", round=first, rounds=n) as wait:
+                # fedlint: disable=FED001 (the ONE deliberate host sync per fused block — the host_sync span exists to measure exactly this barrier)
+                jax.block_until_ready(self.params)
             stacked = {k: np.asarray(v) for k, v in result.metrics.items()}
             detail = None
             # Fetch the [R, K] per-client stacks only when some round in this
@@ -1677,13 +1724,15 @@ class Coordinator:
                     "client_accuracy": np.asarray(result.client_metrics.accuracy),
                     "update_sq_norms": np.asarray(result.update_sq_norms),
                 }
-        block_duration = time.perf_counter() - t0
+        block_duration = sync.t_end - resumed_at
         per_round_s = block_duration / n
-        # Derived occupancy: host_sync (host blocked ON the device) over
-        # dispatch + host_sync + publish — updated at every block boundary so
-        # /metrics always carries the current ratio (see observability.profiling).
-        occupancy = update_device_occupancy(self._registry)
-        self._observe_retune(n, block_duration, occupancy)
+        shared = {
+            "prepare": (keys.t_end - resumed_at) / n,
+            "dispatch": (wait.t_start - keys.t_end) / n,
+            "device_wait": wait.duration_s / n,
+            "readback": (sync.t_end - wait.t_end) / n,
+        }
+        boundary = sync.t_end  # where the block's time stops and a round's own starts
 
         out: list[RoundMetrics] = []
         for i, r in enumerate(rounds):
@@ -1698,6 +1747,7 @@ class Coordinator:
                     num_clients=survived_counts[i],
                     duration_s=per_round_s,
                     timestamp=_now_iso(),
+                    segments=shared,
                 )
             else:
                 agg = {k: float(v[i]) for k, v in stacked.items()}
@@ -1736,18 +1786,8 @@ class Coordinator:
                     eval_metrics=eval_metrics,
                     duration_s=per_round_s,
                     timestamp=_now_iso(),
+                    segments=shared,
                 )
-
-            self._ledger.charge(
-                status=metrics.status.name, num_clients=metrics.num_clients,
-                duration_s=per_round_s, expected=self.cohort_size,
-                telemetry_fields=dict(
-                    round=r, status=metrics.status.name,
-                    num_clients=metrics.num_clients,
-                    duration_s=round(per_round_s, 6), fused=True,
-                    rounds_per_block=n,
-                ),
-            )
 
             self._last_client_detail = None
             if (
@@ -1762,7 +1802,7 @@ class Coordinator:
                     self._last_client_detail["client_ids"] = idx_rows[i].tolist()
 
             self.history.append(metrics)
-            with self._tracer.span("publish", round=r):
+            with self._tracer.span("publish", round=r) as publish:
                 # Checkpoint / versioned model only at the block boundary: a
                 # mid-block checkpoint would pair round r's id with the block's
                 # END params and make a resume re-apply rounds r+1..end.
@@ -1770,7 +1810,22 @@ class Coordinator:
             if self.on_round_end is not None:
                 self.on_round_end(metrics)
             self.current_round += 1
+            own_readback = publish.t_start - boundary
+            metrics = self._close_round(
+                replace(metrics, segments={
+                    **shared, "readback": shared["readback"] + own_readback,
+                }),
+                publish.t_start, fused=True, rounds_per_block=n,
+            )
+            boundary = publish.t_start + metrics.segments["publish"]
             out.append(metrics)
+        # Occupancy of the whole block, updated at every block boundary so /metrics
+        # always carries the current ratio (see observability.profiling).
+        occupancy = update_device_occupancy(
+            {seg: math.fsum(m.segments[seg] for m in out) for seg in out[0].segments},
+            self._registry,
+        )
+        self._observe_retune(n, block_duration, occupancy)
         return out
 
     def _client_detail_due(self, round_id: int) -> bool:
@@ -1778,30 +1833,38 @@ class Coordinator:
         return every > 0 and round_id % every == 0
 
     @log_exec
-    def _train_round(self, round_id: int) -> RoundMetrics:
+    def _train_round(
+        self, round_id: int, resumed_at: float
+    ) -> tuple[RoundMetrics, SpanTiming]:
         """One round, instrumented: the round and its phases land as spans (and in
-        the ``nanofed_span_duration_seconds`` histogram), the outcome in
-        ``nanofed_rounds_total`` / ``nanofed_round_duration_seconds``, and — when
-        telemetry is on — as a ``round`` record in ``telemetry.jsonl``."""
-        t0 = time.perf_counter()
-        with self._tracer.span("round", round=round_id):
-            metrics = self._train_round_impl(round_id)
-        duration = time.perf_counter() - t0
-        self._ledger.charge(
-            status=metrics.status.name, num_clients=metrics.num_clients,
-            duration_s=duration, expected=self.cohort_size,
-            telemetry_fields=dict(
-                round=round_id, status=metrics.status.name,
-                num_clients=metrics.num_clients, duration_s=round(duration, 6),
-            ),
-        )
-        # Single-round occupancy basis: the local-train span blocks until the
-        # device round completes, so its share of the round span IS device time.
-        occupancy = update_device_occupancy(self._registry)
-        self._observe_retune(1, duration, occupancy)
-        return metrics
+        the ``nanofed_span_duration_seconds`` histogram), and the metrics come back
+        carrying the round's segments up to the end of the ``round`` span, which is
+        returned with them: ``publish`` runs from its end (``_close_round``).
 
-    def _train_round_impl(self, round_id: int) -> RoundMetrics:
+        The segments tile ``[resumed_at, end of round]`` at the spans' own clock
+        readings: ``prepare`` up to the ``dispatch`` span's start, ``dispatch`` up to
+        ``device-wait``'s start, ``device_wait`` that span, ``readback`` the rest.  A
+        round that FAILED before anything was dispatched was preparation throughout."""
+        with self._tracer.span("round", round=round_id) as round_span:
+            metrics, device = self._train_round_impl(round_id)
+        if device is None:
+            segments = {"prepare": round_span.t_end - resumed_at}
+        else:
+            dispatch, wait = device
+            segments = {
+                "prepare": dispatch.t_start - resumed_at,
+                "dispatch": wait.t_start - dispatch.t_start,
+                "device_wait": wait.duration_s,
+                "readback": round_span.t_end - wait.t_end,
+            }
+        return replace(metrics, segments=segments), round_span
+
+    def _train_round_impl(
+        self, round_id: int
+    ) -> tuple[RoundMetrics, tuple[SpanTiming, SpanTiming] | None]:
+        """The round, and the ``dispatch`` and ``device-wait`` spans' timings (None
+        for a round that FAILED before dispatch): where ``_train_round`` cuts the
+        round into segments."""
         t0 = time.perf_counter()
         cohort = self.cohort_size
         with self._tracer.span("cohort-sample", round=round_id):
@@ -1818,7 +1881,7 @@ class Coordinator:
                 num_clients=len(survived),
                 duration_s=time.perf_counter() - t0,
                 timestamp=_now_iso(),
-            )
+            ), None
 
         with self._tracer.span("cohort-gather", round=round_id,
                                cohort=len(survived)):
@@ -1839,81 +1902,88 @@ class Coordinator:
                 mask[survived] = 1.0
                 weights = compute_weights(self._num_samples, jnp.asarray(mask))
 
-        # Device RNG stack: seed-deterministic without DP.  Under central DP the round
-        # step derives the server NOISE key from this stack (round_step.py
-        # ``noise_rng``) — noise regenerable from a persisted seed could be subtracted
-        # from the released aggregate, voiding DP entirely, so fold in OS entropy
-        # (same secrecy argument as _sample_cohort, but for the noise itself).
-        base = jax.random.fold_in(jax.random.key(self.config.seed), round_id)
-        if self.central_privacy is not None:
-            # Fold in 4 secret words — saturating threefry2x32's 64-bit key state, the
-            # effective bound here (see ops/quantize.py on the keyspace); a single
-            # 31-bit fold would leave the noise key brute-forceable by an adversary
-            # testing candidate draws against the released aggregate.
-            for word in self._secret_sampling_rng.integers(
-                0, 1 << 32, size=4, dtype=np.uint32
-            ):
-                base = jax.random.fold_in(base, word)
-        if self._cohort_mode:
-            # Client-STABLE keys: slot i carries the key of the client it hosts, so
-            # a client's batch shuffling (and any model stochasticity) is identical
-            # whether the round ran gathered or full-N masked — the optimization is
-            # exactly invisible, not just statistically equivalent.
-            rngs = stack_rngs(base, self._padded_clients)[idx_dev]
-        else:
-            rngs = stack_rngs(base, self._step_clients)
-        lr_scale = lr_schedule_scale(
-            self.config.lr_schedule, round_id, self.config.num_rounds,
-            min_factor=self.config.lr_min_factor,
-            decay_every=self.config.lr_decay_every,
-            gamma=self.config.lr_decay_gamma,
-        )
+        # The rest of what the step takes, made device-ready before the dispatch.
+        with self._tracer.span("round-keys", round=round_id):
+            # Device RNG stack: seed-deterministic without DP.  Under central DP the
+            # round step derives the server NOISE key from this stack (round_step.py
+            # ``noise_rng``) — noise regenerable from a persisted seed could be
+            # subtracted from the released aggregate, voiding DP entirely, so fold in
+            # OS entropy (same secrecy argument as _sample_cohort, but for the noise
+            # itself).
+            base = jax.random.fold_in(jax.random.key(self.config.seed), round_id)
+            if self.central_privacy is not None:
+                # Fold in 4 secret words — saturating threefry2x32's 64-bit key state,
+                # the effective bound here (see ops/quantize.py on the keyspace); a
+                # single 31-bit fold would leave the noise key brute-forceable by an
+                # adversary testing candidate draws against the released aggregate.
+                for word in self._secret_sampling_rng.integers(
+                    0, 1 << 32, size=4, dtype=np.uint32
+                ):
+                    base = jax.random.fold_in(base, word)
+            if self._cohort_mode:
+                # Client-STABLE keys: slot i carries the key of the client it hosts,
+                # so a client's batch shuffling (and any model stochasticity) is
+                # identical whether the round ran gathered or full-N masked — the
+                # optimization is exactly invisible, not just statistically equivalent.
+                rngs = stack_rngs(base, self._padded_clients)[idx_dev]
+            else:
+                rngs = stack_rngs(base, self._step_clients)
+            lr_scale = lr_schedule_scale(
+                self.config.lr_schedule, round_id, self.config.num_rounds,
+                min_factor=self.config.lr_min_factor,
+                decay_every=self.config.lr_decay_every,
+                gamma=self.config.lr_decay_gamma,
+            )
+            lr_dev = jnp.float32(lr_scale)  # h2d BEFORE the guarded dispatch
         # The device step fuses local training AND the psum aggregation into one XLA
         # program, so "local-train" covers both (attr says so); "aggregate" below is
         # the host-side post-aggregation work.  block_until_ready inside the span
         # makes its duration the real device time, not dispatch time.
-        lr_dev = jnp.float32(lr_scale)  # h2d BEFORE the guarded dispatch
         with self._tracer.span("local-train", round=round_id,
                                fused="train+aggregate"):
-            if self.scaffold:
-                c_rows = (
-                    self._gather_controls(self.c_stack, idx_dev)
-                    if self._cohort_mode
-                    else self.c_stack
-                )
-                with self._dispatch_guard():
-                    result = self._round_step(
-                        self.params, self.server_state, self.c_global, c_rows,
-                        data, weights, rngs, lr_dev,
+            # ``dispatch``: the jitted call until it returns (the device is then at
+            # work, or about to be); ``device-wait``: the host blocked on it.
+            with self._tracer.span("dispatch", round=round_id) as dispatch:
+                if self.scaffold:
+                    c_rows = (
+                        self._gather_controls(self.c_stack, idx_dev)
+                        if self._cohort_mode
+                        else self.c_stack
                     )
-                self.c_global = result.c_global
-                if self._cohort_mode:
-                    # Participants' control rows move by their delta; padding/dropped
-                    # slots add exact zeros (collision-safe though they alias row 0).
-                    self.c_stack = self._scatter_add_controls(
-                        self.c_stack, idx_dev, result.delta_c
-                    )
+                    with self._dispatch_guard():
+                        result = self._round_step(
+                            self.params, self.server_state, self.c_global, c_rows,
+                            data, weights, rngs, lr_dev,
+                        )
+                    self.c_global = result.c_global
+                    if self._cohort_mode:
+                        # Participants' control rows move by their delta; padding/dropped
+                        # slots add exact zeros (collision-safe though they alias row 0).
+                        self.c_stack = self._scatter_add_controls(
+                            self.c_stack, idx_dev, result.delta_c
+                        )
+                    else:
+                        # Rows already align with the stack — a fused elementwise add,
+                        # not a scatter (which GSPMD may lower with cross-device index
+                        # traffic).
+                        self.c_stack = self._add_controls(self.c_stack, result.delta_c)
+                elif self.adapter is not None:
+                    with self._dispatch_guard():
+                        result = self._round_step(
+                            self.params, self.server_state, self.base_params,
+                            data, weights, rngs, lr_dev,
+                        )
                 else:
-                    # Rows already align with the stack — a fused elementwise add,
-                    # not a scatter (which GSPMD may lower with cross-device index
-                    # traffic).
-                    self.c_stack = self._add_controls(self.c_stack, result.delta_c)
-            elif self.adapter is not None:
-                with self._dispatch_guard():
-                    result = self._round_step(
-                        self.params, self.server_state, self.base_params,
-                        data, weights, rngs, lr_dev,
-                    )
-            else:
-                with self._dispatch_guard():
-                    result = self._round_step(
-                        self.params, self.server_state, data, weights, rngs,
-                        lr_dev,
-                    )
-            self.params = result.params
-            self.server_state = result.server_opt_state
-            # fedlint: disable=FED001 (deliberate: blocks INSIDE the local-train span so its duration is device time, not dispatch time)
-            jax.block_until_ready(self.params)
+                    with self._dispatch_guard():
+                        result = self._round_step(
+                            self.params, self.server_state, data, weights, rngs,
+                            lr_dev,
+                        )
+                self.params = result.params
+                self.server_state = result.server_opt_state
+            with self._tracer.span("device-wait", round=round_id) as wait:
+                # fedlint: disable=FED001 (deliberate: blocks INSIDE the local-train span so its duration is device time, not dispatch time)
+                jax.block_until_ready(self.params)
 
         with self._tracer.span("aggregate", round=round_id):
             agg = {k: float(v) for k, v in result.metrics.items()}
@@ -1964,16 +2034,19 @@ class Coordinator:
             and self.central_privacy is None
             and self._client_detail_due(round_id)
         ):
-            self._last_client_detail = {
-                "weights": np.asarray(weights).tolist(),
-                "client_loss": np.asarray(result.client_metrics.loss).tolist(),
-                "client_accuracy": np.asarray(result.client_metrics.accuracy).tolist(),
-                "update_sq_norms": np.asarray(result.update_sq_norms).tolist(),
-            }
-            if self._cohort_mode:
-                # Cohort-slot order, not client-id order: record which client each
-                # slot hosted (weight-0 slots host a placeholder row).
-                self._last_client_detail["client_ids"] = idx.tolist()
+            with self._tracer.span("client-detail", round=round_id):
+                self._last_client_detail = {
+                    "weights": np.asarray(weights).tolist(),
+                    "client_loss": np.asarray(result.client_metrics.loss).tolist(),
+                    "client_accuracy": np.asarray(
+                        result.client_metrics.accuracy
+                    ).tolist(),
+                    "update_sq_norms": np.asarray(result.update_sq_norms).tolist(),
+                }
+                if self._cohort_mode:
+                    # Cohort-slot order, not client-id order: record which client
+                    # each slot hosted (weight-0 slots host a placeholder row).
+                    self._last_client_detail["client_ids"] = idx.tolist()
 
         # fedlint: disable=FED001 (deliberate end-of-round barrier: duration_s must measure the round, not the async dispatch queue)
         jax.block_until_ready(self.params)
@@ -1991,7 +2064,7 @@ class Coordinator:
             eval_metrics=eval_metrics,
             duration_s=duration,
             timestamp=_now_iso(),
-        )
+        ), (dispatch, wait)
 
     def run(self) -> list[RoundMetrics]:
         """Drain the round generator (parity with ``coordinate()``,

@@ -96,8 +96,9 @@ class RoundLedger:
         )
         self._m_critical_path = registry.histogram(
             "nanofed_round_critical_path_seconds",
-            "Per-round walltime by critical-path segment "
-            "(wire_wait/decode/drain/collective/apply/publish)",
+            "Per-round walltime by critical-path segment (federate worker: "
+            "wire_wait/decode/drain/collective/apply/publish; synchronous loop: "
+            "prepare/dispatch/device_wait/readback/publish)",
             labels=("segment",),
         )
 
@@ -117,7 +118,8 @@ class RoundLedger:
 
         ``segments`` is the round's critical-path decomposition (segment name
         -> seconds; the federate worker passes wire_wait/decode/drain/
-        collective/apply/publish, which tile ``duration_s``): each observes
+        collective/apply/publish, the SPMD Coordinator prepare/dispatch/
+        device_wait/readback/publish; either tiles ``duration_s``): each observes
         ``nanofed_round_critical_path_seconds{segment}`` and the rounded dict
         rides the ``round`` telemetry record as ``segments``."""
         self._m_rounds.inc(status=str(status).lower())

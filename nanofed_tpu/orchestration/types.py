@@ -50,6 +50,11 @@ class RoundMetrics:
     eval_metrics: dict[str, float] = field(default_factory=dict)
     duration_s: float = 0.0
     timestamp: str = ""
+    # Where the round's walltime went, in seconds: the Coordinator's tiling of one
+    # generator step (prepare / dispatch / device_wait / readback / publish; see
+    # docs/observability.md, "Per-round critical-path segments").  ``duration_s`` is
+    # the round alone; the segments also cover what runs around it.
+    segments: dict[str, float] = field(default_factory=dict)
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -60,6 +65,7 @@ class RoundMetrics:
             "eval_metrics": self.eval_metrics,
             "duration_s": self.duration_s,
             "timestamp": self.timestamp,
+            "segments": self.segments,
         }
 
 

@@ -249,17 +249,22 @@ def build_sharded_round(
 
         def step_chunk(acc, chunk):
             c_data, c_rngs, c_weights = chunk
-            result = jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, c_data, c_rngs)
+            with jax.named_scope("local_fit"):
+                result = jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, c_data, c_rngs)
             delta = jax.tree.map(lambda p, g: p - g[None], result.params, gp_v)
             if uniform_dp:
                 delta = clip_deltas(delta)
                 w = (c_weights > 0).astype(jnp.float32)
             else:
                 w = c_weights
-            acc = jax.tree.map(
-                lambda a, d: a + jnp.tensordot(w.astype(d.dtype), d, axes=1), acc, delta
-            )
-            return acc, (result.metrics, jax.vmap(tree_sq_norm)(delta))
+            with jax.named_scope("client_reduce"):
+                acc = jax.tree.map(
+                    lambda a, d: a + jnp.tensordot(w.astype(d.dtype), d, axes=1),
+                    acc, delta,
+                )
+            with jax.named_scope("round_metrics"):
+                sq_norms = jax.vmap(tree_sq_norm)(delta)
+            return acc, (result.metrics, sq_norms)
 
         acc, (metrics, sq_norms) = lax.scan(step_chunk, acc0, chunked)
         flat = lambda x: x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
@@ -273,12 +278,13 @@ def build_sharded_round(
         # ``gp``/``sos`` are this device's MODEL SHARDS on a 2-D mesh (full leaves on
         # 1-D); ``agg_delta`` arrives full and is sliced down, so the server optimizer
         # only ever touches shard-sized state.
-        agg_delta = layout.slice_shard(agg_delta)
-        neg_delta = jax.tree.map(jnp.negative, agg_delta)
-        updates, new_sos = server_tx.update(neg_delta, sos, gp)
-        ok = total_w > 0
-        new_gp = tree_where(ok, optax.apply_updates(gp, updates), gp)
-        new_sos = tree_where(ok, new_sos, sos)
+        with jax.named_scope("server_apply"):
+            agg_delta = layout.slice_shard(agg_delta)
+            neg_delta = jax.tree.map(jnp.negative, agg_delta)
+            updates, new_sos = server_tx.update(neg_delta, sos, gp)
+            ok = total_w > 0
+            new_gp = tree_where(ok, optax.apply_updates(gp, updates), gp)
+            new_sos = tree_where(ok, new_sos, sos)
         return new_gp, new_sos
 
     def add_central_noise(agg_delta, noise_rng, participants):
@@ -293,9 +299,10 @@ def build_sharded_round(
         """Aggregate a streamed local weighted-delta sum: one tree-psum, then the same
         server transform / metrics as the materializing path."""
         total_w = layout.client_psum(weights.sum())
-        global_wsum = jax.tree.map(
-            layout.client_psum, local_wsum
-        )
+        with jax.named_scope("client_reduce"):
+            global_wsum = jax.tree.map(
+                layout.client_psum, local_wsum
+            )
         if central_privacy is not None:
             # local_wsum was accumulated with UNIFORM weights over clipped deltas, so
             # sensitivity of the mean is exactly C/K — identical math to the
@@ -313,9 +320,10 @@ def build_sharded_round(
             den = jnp.maximum(total_w, 1e-12)
             agg_delta = jax.tree.map(lambda x: x / den.astype(x.dtype), global_wsum)
         new_gp, new_sos = apply_server_update(gp, sos, agg_delta, total_w)
-        metrics = psum_weighted_metrics(client_metrics, weights, c_axes)
-        metrics["participating_clients"] = layout.client_psum(
-            (weights > 0).sum())
+        with jax.named_scope("round_metrics"):
+            metrics = psum_weighted_metrics(client_metrics, weights, c_axes)
+            metrics["participating_clients"] = layout.client_psum(
+                (weights > 0).sum())
         return new_gp, new_sos, metrics, client_metrics, sq_norms
 
     def shard_body(gp, sos, data: ClientData, weights, rngs, noise_rng, lr_scale,
@@ -368,15 +376,17 @@ def build_sharded_round(
             chunked = jax.tree.map(
                 lambda x: x.reshape(n_chunks, client_chunk, *x.shape[1:]), (data, rngs)
             )
-            result = lax.map(
-                lambda args: jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, *args),
-                chunked,
-            )
+            with jax.named_scope("local_fit"):
+                result = lax.map(
+                    lambda args: jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, *args),
+                    chunked,
+                )
             result = jax.tree.map(
                 lambda x: x.reshape(c_local, *x.shape[2:]), result
             )
         else:
-            result = jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, data, rngs)
+            with jax.named_scope("local_fit"):
+                result = jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, data, rngs)
         delta = jax.tree.map(lambda p, g: p - g[None], result.params, gp_v)
 
         if validation is not None:
@@ -436,13 +446,16 @@ def build_sharded_round(
             participants = jnp.maximum(
                 layout.client_psum(uniform.sum()), 1.0
             )
-            agg_delta = psum_weighted_mean(delta, uniform, c_axes)
+            with jax.named_scope("client_reduce"):
+                agg_delta = psum_weighted_mean(delta, uniform, c_axes)
             agg_delta = add_central_noise(agg_delta, noise_rng, participants)
         else:
-            agg_delta = psum_weighted_mean(delta, weights, c_axes)
+            with jax.named_scope("client_reduce"):
+                agg_delta = psum_weighted_mean(delta, weights, c_axes)
         new_gp, new_sos = apply_server_update(gp, sos, agg_delta, total_w)
 
-        metrics = psum_weighted_metrics(result.metrics, weights, c_axes)
+        with jax.named_scope("round_metrics"):
+            metrics = psum_weighted_metrics(result.metrics, weights, c_axes)
         if robust_kept is not None:
             # The attacker's DELTA is trimmed but its metric row would still ride
             # the weighted mean (a NaN loss from one client would corrupt every
@@ -468,7 +481,8 @@ def build_sharded_round(
         else:
             metrics["participating_clients"] = layout.client_psum(
                 (weights > 0).sum())
-        sq_norms = jax.vmap(tree_sq_norm)(delta)
+        with jax.named_scope("round_metrics"):
+            sq_norms = jax.vmap(tree_sq_norm)(delta)
         return new_gp, new_sos, metrics, result.metrics, sq_norms
 
     # On a 2-D mesh the params/opt-state specs are per-leaf trees carrying the

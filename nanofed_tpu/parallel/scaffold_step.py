@@ -143,12 +143,14 @@ def build_scaffold_round_step(
                 lambda x: x.reshape(n_chunks, client_chunk, *x.shape[1:]),
                 (data, rngs, c_stack),
             )
-            result = lax.map(
-                lambda args: vfit(gp_v, args[0], args[1], args[2]), chunked
-            )
+            with jax.named_scope("local_fit"):
+                result = lax.map(
+                    lambda args: vfit(gp_v, args[0], args[1], args[2]), chunked
+                )
             result = jax.tree.map(lambda x: x.reshape(c_local, *x.shape[2:]), result)
         else:
-            result = vfit(gp_v, data, rngs, c_stack)
+            with jax.named_scope("local_fit"):
+                result = vfit(gp_v, data, rngs, c_stack)
 
         delta_y = jax.tree.map(lambda p, g: p - g[None], result.params, gp_v)
         participating = (weights > 0).astype(jnp.float32)
@@ -157,14 +159,15 @@ def build_scaffold_round_step(
         # Model update: server_tx over the UNIFORM participant mean of delta y —
         # full aggregate sliced down to this device's model shard first, so the
         # server optimizer only ever touches shard-sized state.
-        agg_delta = layout.slice_shard(
-            psum_weighted_mean(delta_y, participating, c_axes)
-        )
-        neg_delta = jax.tree.map(jnp.negative, agg_delta)
-        updates, new_sos = server_tx.update(neg_delta, sos, gp)
-        ok = total_w > 0
-        new_gp = tree_where(ok, optax.apply_updates(gp, updates), gp)
-        new_sos = tree_where(ok, new_sos, sos)
+        with jax.named_scope("client_reduce"):
+            agg_delta = psum_weighted_mean(delta_y, participating, c_axes)
+        with jax.named_scope("server_apply"):
+            agg_delta = layout.slice_shard(agg_delta)
+            neg_delta = jax.tree.map(jnp.negative, agg_delta)
+            updates, new_sos = server_tx.update(neg_delta, sos, gp)
+            ok = total_w > 0
+            new_gp = tree_where(ok, optax.apply_updates(gp, updates), gp)
+            new_sos = tree_where(ok, new_sos, sos)
 
         # Control updates: dc rows zeroed outside the cohort (the scatter-add then
         # writes exact zeros for padding/dropped slots); the server control moves by
@@ -175,22 +178,24 @@ def build_scaffold_round_step(
             ).astype(d.dtype),
             result.delta_c,
         )
-        c_sum = layout.slice_shard(
-            jax.tree.map(
+        with jax.named_scope("client_reduce"):
+            c_sum = jax.tree.map(
                 lambda d: layout.client_psum(d.sum(axis=0)), delta_c
             )
-        )
-        new_c_global = jax.tree.map(
-            lambda c, s: jnp.where(ok, c + s / float(num_clients_total), c).astype(
-                c.dtype
-            ),
-            c_global, c_sum,
-        )
+        with jax.named_scope("server_apply"):
+            c_sum = layout.slice_shard(c_sum)
+            new_c_global = jax.tree.map(
+                lambda c, s: jnp.where(
+                    ok, c + s / float(num_clients_total), c
+                ).astype(c.dtype),
+                c_global, c_sum,
+            )
 
-        metrics = psum_weighted_metrics(result.metrics, weights, c_axes)
-        metrics["participating_clients"] = layout.client_psum(
-            (weights > 0).sum())
-        sq_norms = jax.vmap(tree_sq_norm)(delta_y)
+        with jax.named_scope("round_metrics"):
+            metrics = psum_weighted_metrics(result.metrics, weights, c_axes)
+            metrics["participating_clients"] = layout.client_psum(
+                (weights > 0).sum())
+            sq_norms = jax.vmap(tree_sq_norm)(delta_y)
         return new_gp, new_sos, new_c_global, delta_c, metrics, result.metrics, sq_norms
 
     dspec = layout.data_spec

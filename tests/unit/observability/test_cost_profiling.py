@@ -26,7 +26,7 @@ from nanofed_tpu.observability.profiling import (
     extract_cost_analysis,
     extract_memory_analysis,
 )
-from nanofed_tpu.observability.spans import SPAN_HISTOGRAM, SpanTracer
+from nanofed_tpu.observability.spans import SpanTracer
 
 
 def _matmul_jit():
@@ -209,55 +209,56 @@ def test_jit_program_attribute_is_honored():
 
 
 def test_device_occupancy_from_fused_spans():
+    # A fused block's segments, summed over its rounds: dispatch 1 s (prepare
+    # included), device_wait 3 s.
     reg = MetricsRegistry()
-    hist = reg.histogram(SPAN_HISTOGRAM, labels=("span",))
-    hist.observe(1.0, span="dispatch")
-    hist.observe(3.0, span="host_sync")
-    ratio = update_device_occupancy(reg)
+    block = {"prepare": 0.25, "dispatch": 0.75, "device_wait": 3.0, "readback": 0.0}
+    ratio = update_device_occupancy(block, reg)
     assert ratio == pytest.approx(0.75)
     assert reg.gauge(DEVICE_OCCUPANCY_GAUGE).value() == pytest.approx(0.75)
-    # publish is host time the device spends idle — it must DILUTE the ratio
-    # (it lives outside dispatch/host_sync in the coordinator loop), or a
-    # publish-heavy run would overstate occupancy above the lower bound.
-    hist.observe(4.0, span="publish")
-    assert update_device_occupancy(reg) == pytest.approx(3.0 / 8.0)
+    # publish is host time the device spends idle — it must DILUTE the ratio,
+    # or a publish-heavy run would overstate occupancy above the lower bound.
+    assert update_device_occupancy({**block, "publish": 4.0}, reg) == pytest.approx(
+        3.0 / 8.0
+    )
 
 
 def test_device_occupancy_single_round_fallback_and_empty():
     reg = MetricsRegistry()
-    assert update_device_occupancy(reg) is None  # nothing recorded yet
-    hist = reg.histogram(SPAN_HISTOGRAM, labels=("span",))
-    hist.observe(8.0, span="round")
-    hist.observe(6.0, span="local-train")
-    assert update_device_occupancy(reg) == pytest.approx(0.75)
-    # publish sits outside the round span in the single-round loop too.
-    hist.observe(4.0, span="publish")
-    assert update_device_occupancy(reg) == pytest.approx(0.5)
-    # Once fused spans exist they win over the single-round basis (publish
-    # still in the denominator).
-    hist.observe(1.0, span="dispatch")
-    hist.observe(3.0, span="host_sync")
-    assert update_device_occupancy(reg) == pytest.approx(3.0 / 8.0)
+    assert update_device_occupancy({}, reg) is None  # nothing timed yet
+    # A round that FAILED before any dispatch has no device_wait: no ratio, and
+    # the gauge keeps what the last dispatched round left.
+    assert update_device_occupancy({"prepare": 1.0, "publish": 1.0}, reg) is None
+    assert reg.gauge(DEVICE_OCCUPANCY_GAUGE).value() == 0.0
+    round_ = {"prepare": 1.0, "dispatch": 0.5, "device_wait": 6.0, "readback": 0.5}
+    assert update_device_occupancy(round_, reg) == pytest.approx(0.75)
+    assert update_device_occupancy({**round_, "publish": 4.0}, reg) == pytest.approx(0.5)
+    # The ratio is of the LAST round (block) alone: a slow first round does not
+    # linger in it, as it did in the span histogram's process-lifetime sums.
+    assert update_device_occupancy(
+        {"prepare": 0.25, "dispatch": 0.75, "device_wait": 3.0, "publish": 4.0}, reg
+    ) == pytest.approx(3.0 / 8.0)
+    assert reg.gauge(DEVICE_OCCUPANCY_GAUGE).value() == pytest.approx(3.0 / 8.0)
 
 
 def test_device_occupancy_ratio_is_clamped():
+    # A host segment cut between two spans' readings can come out a hair
+    # negative; the published ratio must stay a ratio.
     reg = MetricsRegistry()
-    hist = reg.histogram(SPAN_HISTOGRAM, labels=("span",))
-    # local-train can nominally exceed its parent round under clock skew of
-    # nested perf_counter reads; the published ratio must stay a ratio.
-    hist.observe(2.0, span="local-train")
-    hist.observe(1.0, span="round")
-    assert update_device_occupancy(reg) == 1.0
+    assert update_device_occupancy({"dispatch": -1.0, "device_wait": 2.0}, reg) == 1.0
 
 
 def test_occupancy_integrates_with_real_tracer_spans():
     reg = MetricsRegistry()
     tracer = SpanTracer(registry=reg)
-    with tracer.span("dispatch"):
+    with tracer.span("dispatch") as dispatch:
         pass
-    with tracer.span("host_sync"):
+    with tracer.span("device-wait") as wait:
         pass
-    ratio = update_device_occupancy(reg)
+    ratio = update_device_occupancy(
+        {"dispatch": wait.t_start - dispatch.t_start, "device_wait": wait.duration_s},
+        reg,
+    )
     assert ratio is not None and 0.0 <= ratio <= 1.0
 
 
