@@ -19,7 +19,11 @@ position (``[N, vocab]``) so the model drops into the standard federated
 pipeline — ``ClientData.y`` is the true next token, the masked-NLL ``grad_fn``,
 evaluator, and every round builder work unchanged; :func:`apply_sequence`
 exposes the full ``[N, T, vocab]`` per-position logits (causality tests, future
-all-position training).
+all-position training).  Both are the same head (final LayerNorm, unembedding,
+log-softmax — all row-wise) over the same trunk (embeddings + blocks);
+``apply`` takes the last position's hidden state BEFORE the head, so the
+``width x vocab`` matmul and the softmax run on ``[N, width]`` and never on the
+``T - 1`` positions whose log-probs nothing reads.
 
 Every matrix the FSDP layout rule cares about is 2-D: attention ``wq/wk/wv/wo``
 ``[D, D]``, MLP ``[D, 4D]``/``[4D, D]``, embeddings/head ``[V, D]``/``[D, V]``
@@ -173,21 +177,11 @@ def _attention(params: Params, x: jax.Array, heads: int) -> jax.Array:
     return nn.dense(params["wo"], out)
 
 
-def apply_sequence(
-    params: Params,
-    tokens: jax.Array,
-    *,
-    heads: int = DEFAULT_HEADS,
-    train: bool = False,
-    rng: PRNGKey | None = None,
-) -> jax.Array:
-    """Full per-position next-token log-probs ``[N, T, vocab]`` for int token
-    ids ``[N, T]``.  Deterministic (no dropout) — ``train``/``rng`` are accepted
-    for apply-signature parity and unused, which keeps fused-vs-single round
-    parity exact on every mesh."""
-    del train, rng
+def _trunk(params: Params, tokens: jax.Array, heads: int) -> jax.Array:
+    """Embeddings + ``depth`` blocks: int token ids ``[N, T]`` -> the hidden
+    state ``[N, T, D]`` the final LayerNorm and the head read."""
     tokens = tokens.astype(jnp.int32)
-    n, t = tokens.shape
+    t = tokens.shape[1]
     x = params["tok_emb"][tokens] + params["pos_emb"][None, :t]
 
     def block(x, blk):
@@ -206,8 +200,31 @@ def apply_sequence(
         depth = sum(1 for k in params if k.startswith("block_"))
         for i in range(depth):
             x = block(x, params[f"block_{i}"])
-    x = _layer_norm(params["ln_f"], x)
-    return nn.log_softmax(nn.dense(params["head"], x))
+    return x
+
+
+def _head(params: Params, hidden: jax.Array) -> jax.Array:
+    """Final LayerNorm -> unembedding -> log-softmax over the LAST axis of
+    ``hidden`` (``[..., D]`` -> ``[..., vocab]``).  Every step is row-wise, so
+    the head of a slice of positions is that slice of the head."""
+    hidden = _layer_norm(params["ln_f"], hidden)
+    return nn.log_softmax(nn.dense(params["head"], hidden))
+
+
+def apply_sequence(
+    params: Params,
+    tokens: jax.Array,
+    *,
+    heads: int = DEFAULT_HEADS,
+    train: bool = False,
+    rng: PRNGKey | None = None,
+) -> jax.Array:
+    """Full per-position next-token log-probs ``[N, T, vocab]`` for int token
+    ids ``[N, T]``.  Deterministic (no dropout) — ``train``/``rng`` are accepted
+    for apply-signature parity and unused, which keeps fused-vs-single round
+    parity exact on every mesh."""
+    del train, rng
+    return _head(params, _trunk(params, tokens, heads))
 
 
 def transformer_param_count(
@@ -243,7 +260,10 @@ def transformer_lm(
     """The causal-LM zoo entry.  ``apply`` returns the LAST position's
     next-token log-probs ``[N, vocab]`` so the standard masked-NLL pipeline
     trains it with ``y`` = true next token; the full ``[N, T, vocab]`` surface
-    is :func:`apply_sequence`.
+    is :func:`apply_sequence`.  ``apply`` slices the trunk's hidden state to the
+    last position and runs the head on that ``[N, width]`` alone — the same
+    values as ``apply_sequence(...)[:, -1, :]`` without the head's work (forward
+    and backward) on the other ``T - 1`` positions.
 
     ``scan_layers=True`` (also registered as ``transformer_lm_scan``) selects
     the scan-over-layers parameter layout: the ``depth`` block trees stack into
@@ -263,8 +283,10 @@ def transformer_lm(
     def apply(
         params: Params, x: jax.Array, *, train: bool = False, rng=None
     ) -> jax.Array:
-        logp = apply_sequence(params, x, heads=heads, train=train, rng=rng)
-        return logp[:, -1, :]
+        # The loss reads the last position only, so the head (ln_f, the
+        # width x vocab matmul, log-softmax) runs on [N, D], not [N, T, D].
+        del train, rng
+        return _head(params, _trunk(params, x, heads)[:, -1, :])
 
     return Model(
         name="transformer_lm_scan" if scan_layers else "transformer_lm",

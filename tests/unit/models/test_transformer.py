@@ -20,6 +20,13 @@ from nanofed_tpu.models.transformer import (
 
 VOCAB, SEQ, WIDTH, DEPTH, HEADS = 32, 8, 16, 2, 2
 
+#: ``apply`` runs the head on the last position's hidden state alone;
+#: ``apply_sequence(...)[:, -1]`` runs it on every position and slices.  The two
+#: are the same function, computed by matmuls of different shapes, so float32
+#: values agree to rounding and not bit for bit: log-probs of magnitude ~4 and
+#: gradients of magnitude <= 1, a few float32 ulps of each.
+SLICE_ATOL = 1e-5
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -64,7 +71,130 @@ def test_apply_returns_last_position_log_probs(model, params):
     assert logp.shape == (4, VOCAB)
     np.testing.assert_allclose(np.exp(np.asarray(logp)).sum(-1), 1.0, atol=1e-5)
     full = apply_sequence(params, x, heads=HEADS)
-    np.testing.assert_allclose(np.asarray(full[:, -1]), np.asarray(logp), atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(full[:, -1]), np.asarray(logp), atol=SLICE_ATOL
+    )
+
+
+@pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+def test_apply_is_head_of_last_hidden_state(name):
+    """``apply`` slices the hidden state BEFORE ln_f/head; values and every
+    leaf's gradient of the masked NLL (``head`` and ``ln_f`` included) equal
+    the full-sequence head sliced afterwards, in float32 to ``SLICE_ATOL``."""
+    from nanofed_tpu.trainer.local import make_grad_fn
+
+    m = get_model(
+        name, vocab=VOCAB, seq_len=SEQ, width=WIDTH, depth=3, heads=HEADS
+    )
+    p = m.init(jax.random.key(5))
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.integers(0, VOCAB, (4, SEQ)), jnp.int32)
+    y = jnp.asarray(rng.integers(0, VOCAB, (4,)), jnp.int32)
+    mask = jnp.asarray([1.0, 1.0, 0.0, 1.0], jnp.float32)
+
+    def sliced_after(q, tokens, *, train=False, rng=None):
+        return apply_sequence(q, tokens, heads=HEADS)[:, -1, :]
+
+    got, want = m.apply(p, x), sliced_after(p, x)
+    assert got.dtype == jnp.float32 and got.shape == (4, VOCAB)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=SLICE_ATOL)
+
+    g_got, _ = make_grad_fn(m.apply)(p, x, y, mask, jax.random.key(0))
+    g_want, _ = make_grad_fn(sliced_after)(p, x, y, mask, jax.random.key(0))
+    got_leaves = jax.tree_util.tree_leaves_with_path(g_got)
+    want_leaves = jax.tree_util.tree_leaves_with_path(g_want)
+    assert [k for k, _ in got_leaves] == [k for k, _ in want_leaves]
+    paths = {jax.tree_util.keystr(k) for k, _ in got_leaves}
+    assert any("head" in k for k in paths) and any("ln_f" in k for k in paths)
+    for (path, a), (_, b) in zip(got_leaves, want_leaves):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=SLICE_ATOL,
+            err_msg=jax.tree_util.keystr(path),
+        )
+        # every leaf is reached: the rows dropped carried a cotangent of zero,
+        # not a share of the gradient
+        assert np.abs(np.asarray(b)).max() > 0.0, jax.tree_util.keystr(path)
+
+
+def _largest_intermediate(jaxpr) -> int:
+    """Most elements any equation of ``jaxpr`` writes, sub-jaxprs (scan and
+    jit bodies, custom-derivative rules) included."""
+    worst = 0
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            worst = max(worst, int(np.prod(v.aval.shape, dtype=np.int64)))
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    worst = max(worst, _largest_intermediate(sub))
+    return worst
+
+
+class TestHeadStaysOffTheSequence:
+    """The guard that keeps the head from growing back: in the training step
+    (``value_and_grad`` of ``make_grad_fn``'s masked NLL) nothing has
+    ``seq_len x vocab`` elements per sequence.  Shapes chosen so that every
+    legitimate intermediate is far below that: the widest are the scan's
+    stacked MLP residuals (``depth x seq x 4 width`` = 4096 a sequence) and the
+    head's kernel (``width x vocab`` = 31,904 in all) against
+    ``seq x vocab`` = 15,952 a sequence, 63,808 in the batch of 4."""
+
+    V, T, D, L, H, N = 997, 16, 32, 2, 2, 4
+
+    def _step_jaxpr(self, apply_fn, params):
+        from nanofed_tpu.trainer.local import make_grad_fn
+
+        rng = np.random.default_rng(8)
+        x = jnp.asarray(rng.integers(0, self.V, (self.N, self.T)), jnp.int32)
+        y = jnp.asarray(rng.integers(0, self.V, (self.N,)), jnp.int32)
+        mask = jnp.ones((self.N,), jnp.float32)
+        grad_fn = make_grad_fn(apply_fn, compute_dtype="bfloat16")
+        return jax.make_jaxpr(grad_fn)(params, x, y, mask, jax.random.key(0)).jaxpr
+
+    def _model(self, name):
+        return get_model(
+            name, vocab=self.V, seq_len=self.T, width=self.D, depth=self.L,
+            heads=self.H,
+        )
+
+    @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+    def test_no_sequence_by_vocab_intermediate(self, name):
+        m = self._model(name)
+        params = m.init(jax.random.key(0))
+        worst = _largest_intermediate(self._step_jaxpr(m.apply, params))
+        assert worst < self.N * self.T * self.V, (
+            f"{name}: an intermediate of {worst} elements — the head runs over "
+            f"the sequence again ({self.N} x {self.T} x {self.V} = "
+            f"{self.N * self.T * self.V})"
+        )
+
+    @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+    def test_the_walk_sees_a_full_sequence_head(self, name):
+        """Control: the same walk over the step built on
+        ``apply_sequence(...)[:, -1]`` finds the ``[N, T, vocab]`` tensors."""
+        m = self._model(name)
+        params = m.init(jax.random.key(0))
+
+        def full_head(p, x, *, train=False, rng=None):
+            return apply_sequence(p, x, heads=self.H)[:, -1, :]
+
+        worst = _largest_intermediate(self._step_jaxpr(full_head, params))
+        assert worst >= self.N * self.T * self.V
+
+
+def test_the_walk_descends_into_loop_and_jit_bodies():
+    """A tensor that exists only inside a ``scan`` body inside a ``jit`` is
+    counted — a head wrapped in either could not hide from the guard."""
+
+    @jax.jit
+    def f(x):
+        def body(c, _):
+            return c + jnp.ones((7, 11, 13)).sum(), None
+
+        return jax.lax.scan(body, x, None, length=3)[0]
+
+    assert _largest_intermediate(jax.make_jaxpr(f)(0.0).jaxpr) == 7 * 11 * 13
 
 
 def test_causality(params):
