@@ -35,6 +35,7 @@ def aggregate_metrics(metrics: ClientMetrics, weights: jax.Array) -> dict[str, j
         "loss": (metrics.loss * weights).sum() / den,
         "accuracy": (metrics.accuracy * weights).sum() / den,
         "samples": (metrics.samples * participating).sum(),
+        **{name: (value * weights).sum() / den for name, value in metrics.counters.items()},
     }
 
 
@@ -91,11 +92,15 @@ def psum_weighted_mean(
 def psum_weighted_metrics(
     metrics: ClientMetrics, weights: jax.Array, axis_name: str | tuple[str, ...]
 ) -> dict[str, jax.Array]:
-    """In-mesh weighted metric means + total sample count (masked by participation)."""
+    """In-mesh weighted metric means + total sample count (masked by participation).
+    A model's own counters (``ClientMetrics.counters``; none for most models) are
+    weighted like the loss and appear under their own names."""
     den = jnp.maximum(_client_psum(weights.sum(), axis_name), 1e-12)
     participating = (weights > 0).astype(metrics.samples.dtype)
+    mean = lambda per_client: _client_psum((per_client * weights).sum(), axis_name) / den
     return {
-        "loss": _client_psum((metrics.loss * weights).sum(), axis_name) / den,
-        "accuracy": _client_psum((metrics.accuracy * weights).sum(), axis_name) / den,
+        "loss": mean(metrics.loss),
+        "accuracy": mean(metrics.accuracy),
         "samples": _client_psum((metrics.samples * participating).sum(), axis_name),
+        **{name: mean(value) for name, value in metrics.counters.items()},
     }

@@ -55,17 +55,25 @@ class ClientMetrics(NamedTuple):
 
     Parity with the reference's ``TrainingMetrics`` (``nanofed/trainer/base.py:28-43``):
     loss, accuracy, samples processed.  As arrays these stack/vmap over clients.
+
+    ``counters`` is the optional channel for what a model counts about its own layers
+    (an expert layer's load; ``apply.with_counters``, see ``trainer.local``): name ->
+    the client's sample-weighted mean over its last local epoch.  Empty for a model that
+    reports none — an empty dict has no leaves, so nothing is traced, stacked or reduced
+    for it and every compiled program stays what it was.
     """
 
     loss: jax.Array
     accuracy: jax.Array
     samples: jax.Array
+    counters: dict[str, jax.Array] = {}
 
     def to_dict(self) -> dict[str, Any]:
         return {
             "loss": float(self.loss),
             "accuracy": float(self.accuracy),
             "samples_processed": int(self.samples),
+            **{name: float(value) for name, value in self.counters.items()},
         }
 
 

@@ -2,12 +2,14 @@
 the ResNets serve the BASELINE.json benchmark configs)."""
 
 from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
+    hybrid,
     linear,
     mnist,
     resnet,
     transformer,
 )
 from nanofed_tpu.models.base import Model, get_model, list_models, register_model
+from nanofed_tpu.models.hybrid import hybrid_lm
 from nanofed_tpu.models.mnist import mnist_cnn
 from nanofed_tpu.models.resnet import resnet8, resnet18
 from nanofed_tpu.models.transformer import (
@@ -22,6 +24,7 @@ __all__ = [
     "get_model",
     "list_models",
     "register_model",
+    "hybrid_lm",
     "mnist_cnn",
     "resnet8",
     "resnet18",

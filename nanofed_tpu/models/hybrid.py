@@ -1,0 +1,484 @@
+"""Hybrid state-space / mixture-of-experts causal LM: a decoder whose layers are named
+by a pattern string, one letter a layer — ``M`` a Mamba-2 mixer, ``E`` a
+mixture-of-experts feed-forward, ``*`` grouped-query attention (the ``nemotron_h``
+layout).  Pre-norm residual stack ``x <- x + Mixer_l(RMSNorm_l(x))``, final RMSNorm,
+untied head, no bias but the convolution's, no positional term anywhere: the
+state-space layers carry the order.
+
+Like ``transformer_lm`` it drops into the standard federated pipeline: ``apply`` returns
+next-token log-probabilities at the LAST position (``[N, vocab]``), the head running on
+that position's hidden state alone.  Layers of one kind are stacked on a leading axis
+(``params["mamba"]["in_proj"]`` is ``[M layers, width, ...]``, the routed experts
+``[E layers, experts held, width, expert width]``), so the tree has the same few leaves
+at any depth; the forward pass walks the pattern and takes each layer's slice.  Every
+layer is rematerialized (``jax.checkpoint``): the backward pass keeps one ``[N, T,
+width]`` activation a layer and recomputes inside it.
+
+**Mamba-2** (``ssm_mixer``): ``[z | xBC | dt] = u W_in``; a causal depthwise convolution
+and SiLU on ``xBC``; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t +
+D x_t`` per head (heads in groups sharing ``B``/``C``), evaluated by chunks
+(``ssm_scan``, :func:`ssd_chunked`): inside a chunk the quadratic masked form, between
+chunks a ``lax.scan`` over the chunk states; then ``RMSNorm_grouped(y * silu(z)) W_out``.
+Step sizes, decays and their cumulative sums stay float32 under mixed precision.
+
+**Experts** (:func:`expert_layer`): the layer is TOLD which experts it holds
+(``first_expert``, ``experts_held``).  The router (``moe_router``, float32) scores all
+``experts`` with a sigmoid, picks ``top_k`` and normalises over all picks; picks that
+land on held experts are laid out by expert in whole blocks of ``EXPERT_BLOCK`` rows
+(``moe_dispatch``) and the held experts' squared-ReLU MLPs run over the blocks in use
+(``moe_experts``, :func:`expert_blocks`: a loop whose trip count follows the routing, so
+no capacity limit and no dropped token, and no work on blocks nobody fills); the shared
+expert (``moe_shared``) sees every token.  What experts held elsewhere would add is
+left out — on one chip the layer runs without its exchange, and a sum over all the
+shares, the shared expert counted once, is the uncut layer (tests).  The layer reports
+two counters (:data:`COUNTERS`) through ``apply.with_counters``.
+
+**Attention** (``gqa_attention``): grouped-query, causal, full-square scores, no rotary.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from nanofed_tpu.core.types import Params, PRNGKey
+from nanofed_tpu.models.base import Model, register_model
+
+#: Rows a block of the expert loop holds: a held expert's picks are padded to whole
+#: blocks, so a block multiplies one expert's matrices.  A block's cost is mostly fixed
+#: (its expert's two matrices read, two float32 gradient accumulators read and written),
+#: so the round's time follows the loop's trip count.  At 1536 an expert with up to eight
+#: times the mean load of a 4096-token step at 6 of 128 (192 rows) fits ONE block: the loop
+#: runs once an expert whatever the routing, and a round's time does not move with the
+#: seed.  At 256 the trip count followed the router's imbalance (9 to 13 blocks a layer)
+#: and round times spread by 2.4% across seeds; at 1024 an expert still crossed a block on
+#: two seeds in six (PERF.md section 6).  A fuller expert takes more blocks: nothing is
+#: dropped.
+EXPERT_BLOCK = 1536
+
+#: What ``apply.with_counters`` reports beside the log-probabilities, each the mean over
+#: the ``E`` layers of one batch: the share of all picks that landed on held experts, and
+#: the held experts' largest token count over their mean (1.0 is even).
+COUNTERS = ("moe_held_pick_share", "moe_load_max_over_mean")
+
+# Initialisation ranges of the Mamba-2 mixer: step sizes log-uniform in [DT_MIN, DT_MAX],
+# floored; A uniform in A_RANGE.
+DT_MIN, DT_MAX, DT_FLOOR, A_RANGE = 0.001, 0.1, 1e-4, (1.0, 16.0)
+
+_F32 = jnp.float32
+
+
+def mamba_sizes(mamba_heads: int, mamba_head_dim: int, ssm_groups: int, ssm_state: int):
+    """``(inner width, convolved channels, in-projection width)`` of the mixer."""
+    d_in = mamba_heads * mamba_head_dim
+    conv = d_in + 2 * ssm_groups * ssm_state
+    return d_in, conv, d_in + conv + mamba_heads
+
+
+def init_hybrid(rng: PRNGKey, *, vocab, width, pattern, mamba_heads, mamba_head_dim,
+                ssm_groups, ssm_state, conv_kernel, attn_heads, kv_heads, head_dim,
+                experts, experts_held, expert_width, shared_width, **_) -> Params:
+    """N(0, 0.02) matrices and embeddings, the projections back into the residual stream
+    scaled by ``1/sqrt(depth)``, ``A_log = log U(1, 16)``, ``dt_bias`` the inverse
+    softplus of a log-uniform step size, ``D = 1``, norms 1."""
+    n_m, n_e, n_a = pattern.count("M"), pattern.count("E"), pattern.count("*")
+    d_in, conv, proj = mamba_sizes(mamba_heads, mamba_head_dim, ssm_groups, ssm_state)
+    resid = 1.0 / math.sqrt(len(pattern))
+    k = jax.random.split(rng, 17)
+    normal = lambda key, *shape, scale=1.0: 0.02 * scale * jax.random.normal(key, shape, _F32)
+    ones = lambda *shape: jnp.ones(shape, _F32)
+    bound = 1.0 / math.sqrt(conv_kernel)
+    dt = jnp.exp(jax.random.uniform(k[5], (n_m, mamba_heads), _F32)
+                 * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
+    dt = jnp.maximum(dt, DT_FLOOR)
+    return {
+        "embed": normal(k[0], vocab, width),
+        "head": normal(k[1], width, vocab),
+        "norm_f": ones(width),
+        "mamba": {
+            "norm": ones(n_m, width),
+            "in_proj": normal(k[2], n_m, width, proj),
+            "conv_w": jax.random.uniform(k[3], (n_m, conv_kernel, conv), _F32, -bound, bound),
+            "conv_b": jax.random.uniform(k[4], (n_m, conv), _F32, -bound, bound),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(k[6], (n_m, mamba_heads), _F32, *A_RANGE)),
+            "D": ones(n_m, mamba_heads),
+            "gate_norm": ones(n_m, d_in),
+            "out_proj": normal(k[7], n_m, d_in, width, scale=resid),
+        },
+        "attn": {
+            "norm": ones(n_a, width),
+            "wq": normal(k[8], n_a, width, attn_heads * head_dim),
+            "wk": normal(k[9], n_a, width, kv_heads * head_dim),
+            "wv": normal(k[10], n_a, width, kv_heads * head_dim),
+            "wo": normal(k[11], n_a, attn_heads * head_dim, width, scale=resid),
+        },
+        "moe": {
+            "norm": ones(n_e, width),
+            "router": normal(k[12], n_e, width, experts),
+            "w_up": normal(k[13], n_e, experts_held, width, expert_width),
+            "w_down": normal(k[14], n_e, experts_held, expert_width, width, scale=resid),
+            "shared_up": normal(k[15], n_e, width, shared_width),
+            "shared_down": normal(k[16], n_e, shared_width, width, scale=resid),
+        },
+    }
+
+
+def rms_norm(weight: jax.Array, x: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis, statistics in float32, result in ``x``'s dtype."""
+    x32 = x.astype(_F32)
+    scale = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale * weight.astype(_F32)).astype(x.dtype)
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2
+# ---------------------------------------------------------------------------
+
+
+def ssd_chunked(x, dt, da, b, c, chunk: int) -> jax.Array:
+    """``y_t = S_t C_t`` with ``S_t = exp(da_t) S_{t-1} + dt_t x_t (x) B_t`` by chunks.
+
+    ``x`` [N,T,G,Hg,P] (heads as ``G`` groups of ``Hg``), ``dt``/``da`` [N,T,G,Hg]
+    float32 (``da = dt * A <= 0``), ``b``/``c`` [N,T,G,S].  Inside a chunk of ``L``
+    tokens the output is a masked ``L x L`` product (``(C B^T) * decay``), across chunks
+    the ``[P, S]`` states follow a recurrence under ``lax.scan``; ``T`` is a multiple of
+    ``L``.  Matrix products take operands in ``x.dtype`` and accumulate in float32."""
+    n, t, g, hg, p = x.shape
+    nc, dtype = t // chunk, x.dtype
+    split = lambda a: a.reshape(n, nc, chunk, *a.shape[2:])
+    x, b, c = split(x), split(b), split(c)
+    # [N, nc, G, Hg, L]: within-chunk cumulative log-decay, and the step sizes.
+    cum = jnp.cumsum(jnp.moveaxis(split(da), 2, -1), axis=-1)
+    dt = jnp.moveaxis(split(dt), 2, -1)
+    # Inside the chunk: y_l += sum_{s<=l} (C_l . B_s) exp(cum_l - cum_s) dt_s x_s.
+    cb = jnp.einsum("nclgs,ncmgs->ncglm", c, b, preferred_element_type=_F32)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    mixed = (cb[:, :, :, None] * decay * dt[..., None, :]).astype(dtype)
+    y = jnp.einsum("ncghlm,ncmghp->nclghp", mixed, x, preferred_element_type=_F32)
+    # Each chunk's own contribution to the state at its end ...
+    to_end = jnp.moveaxis(jnp.exp(cum[..., -1:] - cum) * dt, -1, 2)[..., None]
+    own = jnp.einsum("ncmghp,ncmgs->ncghps", (x * to_end).astype(dtype), b,
+                     preferred_element_type=_F32)
+    # ... and the state each chunk starts from: a recurrence over chunks.
+    chunk_decay = jnp.exp(cum[..., -1])
+
+    def carry_on(state, inp):
+        decay_c, own_c = inp
+        return decay_c[..., None, None] * state + own_c, state
+
+    _, entering = lax.scan(carry_on, jnp.zeros_like(own[:, 0]),
+                           (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(own, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1).astype(dtype)
+    carried = jnp.einsum("nclgs,ncghps->nclghp", c, entering, preferred_element_type=_F32)
+    y = y + carried * jnp.moveaxis(jnp.exp(cum), -1, 2)[..., None]
+    return y.reshape(n, t, g, hg, p).astype(dtype)
+
+
+def mamba_mixer(p: Params, u: jax.Array, cfg: dict) -> jax.Array:
+    n, t, _ = u.shape
+    heads, hp, groups, state = (cfg["mamba_heads"], cfg["mamba_head_dim"],
+                                cfg["ssm_groups"], cfg["ssm_state"])
+    d_in, conv, _ = mamba_sizes(heads, hp, groups, state)
+    grouped = (groups, heads // groups)
+    with jax.named_scope("ssm_mixer"):
+        zxbcdt = u @ p["in_proj"]
+        z, xbc, dt = zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + conv], zxbcdt[..., d_in + conv:]
+        k_conv = cfg["conv_kernel"]
+        padded = jnp.pad(xbc, ((0, 0), (k_conv - 1, 0), (0, 0)))
+        xbc = sum(padded[:, k:k + t] * p["conv_w"][k] for k in range(k_conv)) + p["conv_b"]
+        xbc = jax.nn.silu(xbc)
+        x = xbc[..., :d_in].reshape(n, t, *grouped, hp)
+        b = xbc[..., d_in:d_in + groups * state].reshape(n, t, groups, state)
+        c = xbc[..., d_in + groups * state:].reshape(n, t, groups, state)
+        dt = jax.nn.softplus(dt.astype(_F32) + p["dt_bias"].astype(_F32)).reshape(n, t, *grouped)
+        da = dt * -jnp.exp(p["A_log"].astype(_F32)).reshape(grouped)
+        with jax.named_scope("ssm_scan"):
+            y = ssd_chunked(x, dt, da, b, c, cfg["chunk"])
+        y = (y + p["D"].reshape(*grouped, 1) * x).reshape(n, t, d_in) * jax.nn.silu(z)
+        y = rms_norm(jnp.ones((), _F32), y.reshape(n, t, groups, d_in // groups), cfg["eps"])
+        return (y.reshape(n, t, d_in) * p["gate_norm"]) @ p["out_proj"]
+
+
+# ---------------------------------------------------------------------------
+# Attention
+# ---------------------------------------------------------------------------
+
+
+#: Queries a block of the attention loop holds.  The scores are the full square, one
+#: ``[block, T]`` band at a time, each band recomputed in the backward pass: at ``T`` =
+#: 2048 a whole float32 ``[N, heads, T, T]`` would not fit beside the round's parameters.
+QUERY_BLOCK = 512
+
+
+def gqa_attention(p: Params, x: jax.Array, cfg: dict) -> jax.Array:
+    """Causal grouped-query attention with no positional term: ``attn_heads`` query
+    heads share ``kv_heads`` keys and values; every query block against all keys
+    (masked), softmax in float32."""
+    n, t, _ = x.shape
+    hq, hkv, hd = cfg["attn_heads"], cfg["kv_heads"], cfg["head_dim"]
+    block = QUERY_BLOCK if t % QUERY_BLOCK == 0 else t
+    with jax.named_scope("gqa_attention"):
+        q = (x @ p["wq"]).reshape(n, t // block, block, hkv, hq // hkv, hd)
+        k = (x @ p["wk"]).reshape(n, t, hkv, hd)
+        v = (x @ p["wv"]).reshape(n, t, hkv, hd)
+
+        @jax.checkpoint
+        def band(args):
+            q_block, first = args
+            scores = jnp.einsum("nqkgd,nskd->nkgqs", q_block, k, preferred_element_type=_F32)
+            seen = jnp.arange(t)[None, :] <= first + jnp.arange(block)[:, None]
+            att = jax.nn.softmax(jnp.where(seen, scores / math.sqrt(hd), -jnp.inf), axis=-1)
+            return jnp.einsum("nkgqs,nskd->nqkgd", att.astype(x.dtype), v)
+
+        out = lax.map(band, (jnp.moveaxis(q, 1, 0), jnp.arange(t // block) * block))
+        return jnp.moveaxis(out, 0, 1).reshape(n, t, hq * hd) @ p["wo"]
+
+
+# ---------------------------------------------------------------------------
+# Experts
+# ---------------------------------------------------------------------------
+
+
+def route(router: jax.Array, x: jax.Array, cfg: dict):
+    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL ``experts``:
+    sigmoid scores in float32, the ``top_k`` largest, normalised over the picks and
+    scaled.  (The published router adds a balancing bias before picking; it is zero.)"""
+    scores = jax.nn.sigmoid(jnp.matmul(x.astype(_F32), router.astype(_F32),
+                                       precision=lax.Precision.HIGHEST))
+    top, picks = lax.top_k(scores, cfg["top_k"])
+    return picks, cfg["routed_scale"] * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
+
+
+def _zeros_varying_like(shape, *like):
+    """Float32 zeros that vary over every mesh axis one of ``like`` varies over: inside
+    ``shard_map`` a loop's carry has to start with the type its update will have."""
+    axes = set().union(*(jax.typeof(a).vma for a in like))
+    zeros = jnp.zeros(shape, _F32)
+    return lax.pcast(zeros, tuple(axes), to="varying") if axes else zeros
+
+
+def _block_operands(b, x, gate, src, block_expert, w_up, w_down):
+    """Block ``b``: its rows' picks and tokens, the token rows, their gates, its expert's
+    two matrices.  An empty row's pick is ``n * top_k`` and its token ``n``, one past the
+    end: such a row reads zeros (``mode="fill"``) and what it writes is dropped."""
+    picks = lax.dynamic_slice_in_dim(src, b * EXPERT_BLOCK, EXPERT_BLOCK)
+    tokens = picks // (gate.shape[0] // x.shape[0])
+    expert = block_expert[b]
+    return (picks, tokens, x.at[tokens].get(mode="fill", fill_value=0),
+            gate.at[picks].get(mode="fill", fill_value=0), expert,
+            lax.dynamic_index_in_dim(w_up, expert, keepdims=False),
+            lax.dynamic_index_in_dim(w_down, expert, keepdims=False))
+
+
+@jax.custom_vjp
+def expert_blocks(x, gate, src, block_expert, n_blocks, w_up, w_down):
+    """``out[t] = sum over t's held picks of gate[pick] * W_down,e relu(W_up,e x[t])^2``.
+
+    ``x`` [n, d] tokens, ``gate`` [n * top_k] float32 one weight a pick (pick ``i`` is
+    token ``i // top_k``'s).  The picks that landed on held experts are laid out by
+    expert in whole blocks of ``EXPERT_BLOCK`` rows: ``src`` [rows] names each row's
+    pick (``n * top_k``: empty row), block ``b`` belongs to expert ``block_expert[b]``,
+    and only the first ``n_blocks`` blocks are in use.  Only they are computed: forward
+    and backward are loops whose trip count is ``n_blocks``, each block gathering its own
+    token rows and adding its result back to them, so blocks nobody fills cost nothing —
+    which is why the backward pass is written out (a loop of unknown length has no
+    automatic transpose, and the transpose of a gather over all rows is a scatter over
+    all rows)."""
+    def body(b, out):
+        _, tokens, rows, gates, _, up, down = _block_operands(b, x, gate, src, block_expert, w_up, w_down)
+        y = relu2(rows @ up) @ down
+        return out.at[tokens].add(y * gates[:, None].astype(y.dtype), mode="drop")
+
+    zeros = _zeros_varying_like(x.shape, x, gate, src, w_up, w_down).astype(x.dtype)
+    return lax.fori_loop(0, n_blocks, body, zeros)
+
+
+def _expert_blocks_fwd(x, gate, src, block_expert, n_blocks, w_up, w_down):
+    saved = (x, gate, src, block_expert, n_blocks, w_up, w_down)
+    return expert_blocks(*saved), saved
+
+
+def _expert_blocks_bwd(saved, d_out):
+    x, gate, src, block_expert, n_blocks, w_up, w_down = saved
+
+    def body(b, carry):
+        dx, d_gate, d_up, d_down = carry
+        picks, tokens, rows, gates, expert, up, down = _block_operands(
+            b, x, gate, src, block_expert, w_up, w_down)
+        pre = jax.nn.relu(rows @ up)
+        hidden = jnp.square(pre)
+        dy = d_out.at[tokens].get(mode="fill", fill_value=0)
+        d_gate = d_gate.at[picks].set(
+            jnp.sum((hidden @ down).astype(_F32) * dy.astype(_F32), axis=-1), mode="drop")
+        dy = dy * gates[:, None].astype(dy.dtype)
+        d_pre = (dy @ down.T) * 2 * pre
+        dx = dx.at[tokens].add(d_pre @ up.T, mode="drop")
+        add = lambda acc, term: lax.dynamic_update_index_in_dim(
+            acc, lax.dynamic_index_in_dim(acc, expert, keepdims=False) + term, expert, 0)
+        d_up = add(d_up, jnp.matmul(rows.T, d_pre, preferred_element_type=_F32))
+        d_down = add(d_down, jnp.matmul(hidden.T, dy, preferred_element_type=_F32))
+        return dx, d_gate, d_up, d_down
+
+    zeros = lambda like: _zeros_varying_like(like.shape, x, gate, src, d_out, w_up, w_down)
+    start = (zeros(x).astype(x.dtype), zeros(gate), zeros(w_up), zeros(w_down))
+    dx, d_gate, d_up, d_down = lax.fori_loop(0, n_blocks, body, start)
+    return (dx, d_gate.astype(gate.dtype), None, None, None,
+            d_up.astype(w_up.dtype), d_down.astype(w_down.dtype))
+
+
+expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
+
+
+def routed_experts(p: Params, x: jax.Array, cfg: dict):
+    """The held experts' part of the routed output for tokens ``x`` [n, d], and the two
+    counters.  ``p["w_up"]``/``p["w_down"]`` hold experts ``first_expert ..
+    first_expert + experts_held`` of the ``experts`` the router scores."""
+    n, d = x.shape
+    top_k, held = cfg["top_k"], cfg["experts_held"]
+    with jax.named_scope("moe_router"):
+        picks, weights = route(p["router"], x, cfg)
+    with jax.named_scope("moe_dispatch"):
+        local = (picks - cfg["first_expert"]).reshape(n * top_k)
+        key = jnp.where((local >= 0) & (local < held), local, held)  # held: lands elsewhere
+        counts = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0, dtype=jnp.int32)
+        # Picks by expert, in pick order within an expert; then every expert's picks
+        # padded to whole blocks: row r of the layout is the rank-th pick of its expert.
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        padded = -(-counts // EXPERT_BLOCK) * EXPERT_BLOCK
+        ends = jnp.cumsum(padded)
+        rows = n * min(top_k, held) + held * EXPERT_BLOCK
+        rows = -(-rows // EXPERT_BLOCK) * EXPERT_BLOCK
+        block_expert = jnp.clip(jnp.searchsorted(
+            ends, jnp.arange(rows // EXPERT_BLOCK, dtype=jnp.int32) * EXPERT_BLOCK, side="right"),
+            0, held - 1).astype(jnp.int32)
+        r = jnp.arange(rows, dtype=jnp.int32)
+        expert = block_expert[r // EXPERT_BLOCK]
+        rank = r - (ends - padded)[expert]
+        taken = (rank < counts[expert]) & (r < ends[-1])
+        first_pick = jnp.cumsum(counts) - counts
+        src = jnp.where(taken, order[jnp.clip(first_pick[expert] + rank, 0, n * top_k - 1)],
+                        n * top_k)
+    with jax.named_scope("moe_experts"):
+        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert,
+                            ends[-1] // EXPERT_BLOCK, p["w_up"], p["w_down"])
+    landed = counts.sum().astype(_F32)
+    even = jnp.where(landed > 0, counts.max() * held / jnp.maximum(landed, 1.0), 1.0)
+    return out, jnp.stack([landed / (n * top_k), even])
+
+
+def expert_layer(p: Params, x: jax.Array, cfg: dict):
+    """``(routed part of the held experts + shared expert, counters)`` for ``x`` [N,T,d]."""
+    n, t, d = x.shape
+    tokens = x.reshape(n * t, d)
+    routed, counters = routed_experts(p, tokens, cfg)
+    with jax.named_scope("moe_shared"):
+        shared = relu2(tokens @ p["shared_up"]) @ p["shared_down"]
+    return (routed + shared).reshape(n, t, d), counters
+
+
+# ---------------------------------------------------------------------------
+# The stack
+# ---------------------------------------------------------------------------
+
+def _counting_nothing(mixer):
+    """``mixer`` with an expert layer's return: ``(output, counters)``, the counters zero."""
+    return lambda p, x, cfg: (mixer(p, x, cfg), jnp.zeros((len(COUNTERS),), _F32))
+
+
+#: Pattern letter -> (the subtree of ``params`` its layers are stacked in, the mixer).
+_MIXERS = {"M": ("mamba", _counting_nothing(mamba_mixer)),
+           "*": ("attn", _counting_nothing(gqa_attention)), "E": ("moe", expert_layer)}
+
+
+def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
+    """``([N, T, width]`` after the last layer, counters summed over the ``E`` layers)."""
+    x = params["embed"][tokens.astype(jnp.int32)]
+    seen = dict.fromkeys(_MIXERS, 0)
+    counters = jnp.zeros((len(COUNTERS),), _F32)
+    for letter in cfg["pattern"]:
+        kind, mixer = _MIXERS[letter]
+        index = seen[letter]
+        seen[letter] += 1
+
+        @jax.checkpoint
+        def layer(p, x, mixer=mixer):
+            mixed, counted = mixer(p, rms_norm(p["norm"], x, cfg["eps"]), cfg)
+            return x + mixed, counted
+
+        x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
+        counters = counters + counted
+    return x, counters
+
+
+@register_model("hybrid_lm")
+def hybrid_lm(
+    vocab: int = 256,
+    seq_len: int = 32,
+    width: int = 64,
+    pattern: str = "MEM*E",
+    mamba_heads: int = 2,
+    mamba_head_dim: int = 16,
+    ssm_groups: int = 2,
+    ssm_state: int = 16,
+    conv_kernel: int = 4,
+    chunk: int = 8,
+    attn_heads: int = 4,
+    kv_heads: int = 2,
+    head_dim: int = 16,
+    experts: int = 16,
+    first_expert: int = 0,
+    experts_held: int = 4,
+    top_k: int = 3,
+    expert_width: int = 48,
+    shared_width: int = 96,
+    routed_scale: float = 2.5,
+    eps: float = 1e-5,
+) -> Model:
+    """The hybrid decoder as a zoo entry (defaults are test-sized).  ``experts`` is what
+    the router scores; ``first_expert`` and ``experts_held`` say which of them this
+    program holds (all of them: ``0`` and ``experts``)."""
+    cfg = dict(locals())
+    if set(pattern) - set(_MIXERS) or not pattern:
+        raise ValueError(f"pattern {pattern!r}: one of {sorted(_MIXERS)} a layer")
+    if seq_len % chunk:
+        raise ValueError(f"seq_len {seq_len} must be a multiple of the scan's chunk {chunk}")
+    if mamba_heads % ssm_groups or attn_heads % kv_heads:
+        raise ValueError("mamba_heads must divide into ssm_groups, attn_heads into kv_heads")
+    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
+        raise ValueError("the held experts must lie among the routed ones, top_k within them")
+    n_e = pattern.count("E")
+
+    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
+        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
+        del train, rng  # no dropout
+        if x.shape[1] % chunk:
+            raise ValueError(f"sequence length {x.shape[1]} is not a multiple of chunk {chunk}")
+        hidden, counters = hidden_states(params, x, cfg)
+        last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
+        logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
+        counters = lax.stop_gradient(counters) / max(n_e, 1)
+        return logp, dict(zip(COUNTERS, counters))
+
+    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
+        return with_counters(params, x, train=train, rng=rng)[0]
+
+    if n_e:
+        apply.with_counters = with_counters
+    return Model(
+        name="hybrid_lm",
+        init=partial(init_hybrid, **cfg),
+        apply=apply,
+        input_shape=(seq_len,),
+        num_classes=vocab,
+        token_stream=True,
+    )

@@ -1,4 +1,7 @@
-"""GPT-style causal transformer LM — the first workload with a model worth sharding.
+"""GPT-style causal transformer LM — the first workload with a model worth sharding
+(by CPU accounting; what a round really holds on the chip is measured since PR 27 by
+``nemotron-twotower-ctx-9l-xsilo-4.sync``: a 667M-parameter ``models.hybrid`` tree,
+PERF.md).
 
 Every other zoo member is digits-MLP/CNN/ResNet-8 scale, so the FSDP model axis
 (``parallel.mesh.param_partition_spec``) has never sharded a parameter that would
@@ -326,7 +329,9 @@ FLAGSHIP_CONFIGS = {
     "base": (8192, 128, 768, 12, 12),
     # ~1.21B params (4.8 GiB f32): params + momentum + one gathered copy +
     # one delta ≈ 19.4 GiB replicated — over a 16 GiB v5e HBM budget on its
-    # own, which is what "the model axis is binding" means.
+    # own, which is what "the model axis is binding" means.  (CPU accounting;
+    # the chip's reading is ≈ 19 B a parameter for plain SGD: PERF.md, the
+    # nemotron-twotower-ctx-9l-xsilo-4 configuration.)
     "large": (32768, 256, 2048, 24, 16),
 }
 

@@ -491,11 +491,13 @@ def param_partition_spec(
     Pure shape arithmetic, so it works on traced values inside a jit as well as
     on concrete arrays.
 
-    The LEADING dim of a rank>=3 leaf is never chosen: at rank 3+ that dim is a
-    stacking/window dim — scan-over-layers stacks the ``L`` transformer blocks
-    into ``[L, ...]`` leaves, conv kernels lead with window dims — and sharding
-    it over the model axis would split ACROSS layers/windows instead of within
-    a matrix, forcing a gather inside every scan step.  The rule must stay
+    Only the LAST TWO dims of a rank>=3 leaf are candidates: the dims before them
+    are stacking/window dims — scan-over-layers stacks the ``L`` transformer blocks
+    into ``[L, ...]`` leaves, an expert layer's kernels are ``[layers, experts, d,
+    f]``, conv kernels lead with window dims — and sharding one over the model axis
+    would split ACROSS layers, experts or windows instead of within a matrix,
+    forcing a gather inside every scan step (and an expert axis split this way is
+    not expert parallelism: no token is exchanged).  The rule must stay
     pure-shape (``MeshLayout`` recomputes specs from ``x.shape`` inside traced
     code where no path information exists), so the exclusion keys on rank
     alone; a stacked rank-2 leaf (e.g. ``[L, D]`` layer-norm scales) can still
@@ -506,7 +508,7 @@ def param_partition_spec(
         return P()
     best_dim, best_size = -1, 0
     for i, d in enumerate(shape):
-        if i == 0 and len(shape) >= 3:
+        if len(shape) >= 3 and i < len(shape) - 2:
             continue
         if d % n_model_shards == 0 and d > best_size:
             best_dim, best_size = i, int(d)

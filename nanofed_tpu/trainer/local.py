@@ -37,6 +37,10 @@ class StepStats(NamedTuple):
     loss_sum: jax.Array  # sum of per-sample loss over real samples
     correct: jax.Array  # count of correct predictions over real samples
     count: jax.Array  # number of real samples in the batch
+    # What the model counts about its own layers for this batch, times ``count`` (so it
+    # sums like the loss).  Empty — no leaves, nothing compiled — unless the model's
+    # ``apply`` carries ``with_counters``.
+    counters: dict[str, jax.Array] = {}
 
 
 class LocalFitResult(NamedTuple):
@@ -60,8 +64,14 @@ def make_grad_fn(
     ``compute_dtype`` enables mixed precision: params and activations are cast (inside
     the differentiated function, so gradients flow back to the float32 masters) and the
     loss/metric reductions stay float32.
+
+    A model may count things about its own layers (an expert layer's load): its
+    ``apply`` then carries ``with_counters``, the same call returning ``(log-probs,
+    {name: scalar})``.  The scalars ride ``StepStats.counters`` weighted by the batch's
+    real samples, and reach the round's metrics under their names.
     """
     cdt = jnp.dtype(compute_dtype) if compute_dtype is not None else None
+    counted_apply = getattr(apply_fn, "with_counters", None)
 
     def loss_fn(params, xb, yb, mb, rng):
         if cdt is not None:
@@ -71,18 +81,24 @@ def make_grad_fn(
             # fedlint: disable=FED002 (branches on xb.dtype — static trace-time metadata, not a traced value; both arms compile into one program)
             if jnp.issubdtype(xb.dtype, jnp.floating):
                 xb = xb.astype(cdt)
-        logp = apply_fn(params, xb, train=True, rng=rng).astype(jnp.float32)
+        if counted_apply is None:
+            logp, counters = apply_fn(params, xb, train=True, rng=rng), {}
+        else:
+            logp, counters = counted_apply(params, xb, train=True, rng=rng)
+        logp = logp.astype(jnp.float32)
         nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
         count = mb.sum()
         loss = (nll * mb).sum() / jnp.maximum(count, 1.0)
         correct = ((jnp.argmax(logp, -1) == yb) * mb).sum()
-        return loss, (correct, count)
+        return loss, (correct, count, counters)
 
     def grad_fn(params, xb, yb, mb, rng):
-        (loss, (correct, count)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, (correct, count, counters)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, xb, yb, mb, rng
         )
-        return grads, StepStats(loss_sum=loss * count, correct=correct, count=count)
+        counters = {name: value.astype(jnp.float32) * count for name, value in counters.items()}
+        return grads, StepStats(loss_sum=loss * count, correct=correct, count=count,
+                                counters=counters)
 
     return grad_fn
 
@@ -180,17 +196,21 @@ def make_local_fit(
             count = jnp.maximum(stats.count.sum(), 1.0)
             e_loss = stats.loss_sum.sum() / count
             e_acc = stats.correct.sum() / count
+            e_counters = {name: sums.sum() / count for name, sums in stats.counters.items()}
             if config.collect_batch_metrics:
                 b_loss = stats.loss_sum / jnp.maximum(stats.count, 1.0)
             else:
                 b_loss = jnp.zeros((steps,))
-            return (params, opt_state), (e_loss, e_acc, b_loss)
+            return (params, opt_state), (e_loss, e_acc, b_loss, e_counters)
 
         epoch_keys = jax.random.split(rng, config.local_epochs)
-        (params, _), (e_loss, e_acc, b_loss) = lax.scan(
+        (params, _), (e_loss, e_acc, b_loss, e_counters) = lax.scan(
             epoch_body, (global_params, opt_state), epoch_keys
         )
-        metrics = ClientMetrics(loss=e_loss[-1], accuracy=e_acc[-1], samples=data.mask.sum())
+        metrics = ClientMetrics(
+            loss=e_loss[-1], accuracy=e_acc[-1], samples=data.mask.sum(),
+            counters={name: per_epoch[-1] for name, per_epoch in e_counters.items()},
+        )
         return LocalFitResult(
             params=params,
             metrics=metrics,

@@ -130,6 +130,21 @@ def test_param_partition_spec_never_shards_stacked_layer_dim():
     assert param_partition_spec((4, 3, 8, 5), 4) == P(None, None, MODEL_AXIS)
 
 
+@pytest.mark.parametrize("shape,shards,want", [
+    # [layers, experts, d, f]: neither stacking dim, though both divide and the
+    # expert axis is the largest divisible dim of the second.
+    ((4, 8, 2688, 1856), 4, P(None, None, MODEL_AXIS)),
+    ((4, 8, 6, 5), 4, P()),
+    ((4, 8, 6, 8), 2, P(None, None, None, MODEL_AXIS)),
+    # [layers, d, f] keeps the rank-3 rule.
+    ((4, 2688, 10304), 4, P(None, None, MODEL_AXIS)),
+])
+def test_param_partition_spec_stacked_expert_leaves(shape, shards, want):
+    """An expert layer's stacked kernels get a spec that shards neither the layer nor
+    the expert axis, and the rule does not throw at rank 4."""
+    assert param_partition_spec(shape, shards) == want
+
+
 def test_param_sharding_mixed_tree(devices):
     mesh = make_mesh(shape=(2, 4))
     tree = {"kernel": jnp.zeros((8, 16)), "odd_bias": jnp.zeros((3,)), "s": jnp.zeros(())}
