@@ -65,6 +65,7 @@ class SmokeSize:
     wire: dict[str, Any]
     kernel_params: int
     kernel_cohorts: tuple[int, ...]
+    attention_shape: tuple[int, int, int, int] = (4, 12, 1024, 64)  # GPT-2's [N, H, T, hd]
     rounds: int = 3
     interpret: bool = False  # Pallas interpreter: CPU rehearsal only
     loss_tolerance: float = 1e-3  # single-step vs fused vs other meshes, absolute
@@ -409,6 +410,29 @@ def phase_kernels(size: SmokeSize) -> dict[str, Any]:
     )
     _check(err < 2e-5, f"weighted_mean_tree: off by {err:.3g}")
     out["weighted_mean_tree"] = err
+
+    # --- blockwise causal attention, output and gradients, bfloat16 as the models run it
+    # (float32 reference on the same rounded inputs; 2**-8 on the probabilities and dS).
+    from nanofed_tpu.ops.attention import dense_causal_attention
+
+    q, k, v, w = (
+        jax.random.normal(key(5 + i), size.attention_shape, jnp.float32).astype(jnp.bfloat16)
+        for i in range(4)
+    )
+
+    def attend_and_grads(attend, *qkv):
+        loss = lambda *a: (attend(*a).astype(jnp.float32) * w.astype(jnp.float32)).sum()
+        return attend(*qkv), *jax.grad(loss, (0, 1, 2))(*qkv)
+
+    got = jax.jit(lambda *a: attend_and_grads(
+        lambda *b: ops.causal_attention(*b, interpret=interp), *a))(q, k, v)
+    want = jax.jit(lambda *a: attend_and_grads(dense_causal_attention, *a))(
+        *(a.astype(jnp.float32) for a in (q, k, v)))
+    errs = {name: _rel_err(g.astype(jnp.float32), r)
+            for name, g, r in zip(("out", "dq", "dk", "dv"), got, want)}
+    for name, err in errs.items():
+        _check(err < 1.5e-2, f"causal_attention {name}: off its dense form by {err:.3g}")
+    out["causal_attention"] = errs
     return out
 
 

@@ -54,6 +54,9 @@ import jax.numpy as jnp
 from nanofed_tpu import nn
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
+from nanofed_tpu.observability.registry import get_registry
+from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention
+from nanofed_tpu.ops.attention import engages as attention_engages
 
 #: Defaults sized so tier-1 tests compile in seconds; the flagship configs in
 #: runs/adapter_* scale width/depth/vocab up through the same factory.
@@ -62,6 +65,9 @@ DEFAULT_SEQ_LEN = 32
 DEFAULT_WIDTH = 64
 DEFAULT_DEPTH = 2
 DEFAULT_HEADS = 4
+
+#: Registry counter: traces of ``_attention``, label ``path`` = ``blockwise`` | ``dense``.
+ATTENTION_TRACES = "nanofed_attention_traces_total"
 
 
 def _layer_norm_init(dim: int) -> Params:
@@ -158,8 +164,24 @@ def unstack_blocks(params: Params) -> Params:
     return out
 
 
+def _count_attention_trace(path: str) -> None:
+    """One more trace of :func:`_attention` by the path it took (``blockwise`` or
+    ``dense``), in the process's registry: counted when a program is traced, not when it
+    runs, so a run can say which form its programs hold."""
+    get_registry().counter(
+        ATTENTION_TRACES,
+        "Traces of the transformer's causal attention, by the form the shapes chose",
+        labels=("path",),
+    ).inc(path=path)
+
+
 def _attention(params: Params, x: jax.Array, heads: int) -> jax.Array:
-    """Multi-head causal self-attention over ``x`` [N, T, D]."""
+    """Multi-head causal self-attention over ``x`` [N, T, D].
+
+    The sequence length decides the form: whole blocks of ``ops.attention`` and at
+    least ``MIN_SEQ`` positions run block by block (no ``[N, H, T, T]`` array, forward
+    or backward); anything shorter keeps the dense spelling, where a ``[T, T]`` tile is
+    small and every small model's values stay what they were."""
     n, t, d = x.shape
     hd = d // heads
 
@@ -169,13 +191,10 @@ def _attention(params: Params, x: jax.Array, heads: int) -> jax.Array:
     q = split_heads(nn.dense(params["wq"], x))
     k = split_heads(nn.dense(params["wk"], x))
     v = split_heads(nn.dense(params["wv"], x))
-    scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / math.sqrt(hd)
-    # Causal mask: position q attends to keys <= q only.  Additive -inf keeps the
-    # softmax exact for the allowed band.
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    scores = jnp.where(causal[None, None], scores, jnp.finfo(scores.dtype).min)
-    att = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("nhqk,nhkd->nhqd", att, v)
+    blockwise = attention_engages(t)
+    _count_attention_trace("blockwise" if blockwise else "dense")
+    with jax.named_scope("causal_attention"):
+        out = (causal_attention if blockwise else dense_causal_attention)(q, k, v)
     out = out.transpose(0, 2, 1, 3).reshape(n, t, d)
     return nn.dense(params["wo"], out)
 

@@ -16,10 +16,16 @@ hardware PRNG buys something XLA's pattern library doesn't express:
   (``masked_weighted_mean_flat``): non-finite sanitization + validity mask +
   weighted reduce in one read pass instead of sanitize-write-reduce.
 
+* ``ops.attention`` — causal self-attention block by block with an online softmax and a
+                      recomputing backward (``causal_attention``): no ``[N, H, T, T]``
+                      array on either pass; ``models.transformer`` takes it for long
+                      sequences.
+
 Every op takes ``interpret=None`` (auto: real kernels on TPU, interpreter elsewhere) so
 the same code paths are exercised by the CPU-mesh test suite.
 """
 
+from nanofed_tpu.ops.attention import causal_attention
 from nanofed_tpu.ops.quantize import (
     add_mask,
     dequant_accumulate_flat,
@@ -34,6 +40,7 @@ from nanofed_tpu.ops.reduce import (
 
 __all__ = [
     "add_mask",
+    "causal_attention",
     "dequant_accumulate_flat",
     "dequantize_u32",
     "masked_weighted_mean_flat",

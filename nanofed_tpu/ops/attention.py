@@ -1,0 +1,293 @@
+"""Causal self-attention, block by block, as Pallas TPU kernels.
+
+``causal_attention(q, k, v)`` over ``[N, H, T, hd]`` is ``softmax(q k^T / sqrt(hd)) v``
+with position ``t`` attending to keys ``<= t``: what the dense ``einsum`` / ``where`` /
+``softmax`` spelling computes, without any ``[N, H, T, T]`` array in HBM on either pass.
+
+Both kernels keep a score block *transposed*, keys down the sublanes and queries along
+the lanes: the softmax's maximum and sum then run down the sublanes, vreg against vreg on
+the VPU, the per-query statistics are lane-major rows that broadcast over a block as
+stored, and no product needs a ``[block, block]`` transpose.  The small operands that
+want the sequence along the lanes (``V`` forward, a ``K`` block backward) are turned once
+a head or a grid step into VMEM scratch; the output and ``dQ`` leave the kernels as
+``[hd, T]`` and are turned back by XLA, beside the head-merge transpose the model does
+anyway.
+
+Forward: one grid step a (head, query block).  The head's whole ``K`` and ``V`` stay in
+VMEM; the kernel walks the key blocks left of the diagonal with an online softmax
+(running maximum, running sum and output accumulator in float32) and finishes on the
+diagonal block, the only one that needs the mask.  Blocks above the diagonal are never
+visited.  It writes the output and one float32 log-sum-exp a query.
+
+Backward (``jax.custom_vjp``): the saved values are ``q``, ``k``, ``v``, the output and
+the log-sum-exp.  One grid step a (head, key block) recomputes each score block on or
+under the diagonal from them: ``dV`` and ``dK`` accumulate in the step's registers,
+``dQ`` in a float32 VMEM scratch that lives across the head's key blocks.  Five
+products a block pair, against the forward's two.
+
+Precision: scores, softmax statistics and every accumulator are float32; the
+probabilities (and ``dS``) are cast to the inputs' dtype for the products that consume
+them, as a dense bfloat16 model's ``att`` is.  ``1/sqrt(hd)`` is folded into ``q`` where
+that is exact (a power of two: head sizes 16, 64, 256), else applied to the float32
+scores.
+
+MEASURED (v5e, ``[4, 12, 1024, 64]`` bfloat16, a layer of a scanned stack, PERF.md §6,
+PR 28): forward 0.21 ms, forward + backward 0.59 ms, against the dense spelling's 0.24
+and 1.19 with its two ``[N, H, T, T]`` residuals a layer.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from nanofed_tpu.ops._common import auto_interpret
+
+#: Rows of a query block and of a key block: the largest of these that divides ``T``.
+#: Measured on a v5e at ``[4, 12, 1024, 64]`` bfloat16 (PERF.md §6, PR 28), forward +
+#: backward a layer: 1.51 ms at 128, 0.71 at 256, 0.59 at 512 (dense: 1.19).  Small
+#: blocks skip more of what lies above the diagonal (62.5% of the pairs are visited at
+#: four blocks a sequence, 75% at two) and lose more to each pair's fixed cost.
+BLOCKS = (512, 256)
+#: Below this a ``[T, T]`` score tile is VMEM-sized traffic for XLA too.
+MIN_SEQ = 512
+#: A head's whole ``K`` and ``V`` sit in VMEM: compiles for a v5e through 8192 positions
+#: of 128 (bfloat16), not at 16384.  Longer sequences want the keys streamed.
+MAX_SEQ = 8192
+
+#: Masked scores: finite, so that ``exp(masked - max)`` is 0 and never ``inf - inf``.
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+_F32 = jnp.float32
+#: ``a @ b^T``: contract the last axes of both.
+_NT = (((1,), (1,)), ((), ()))
+
+
+def block_for(seq_len: int) -> int | None:
+    """The block ``causal_attention`` cuts a sequence of this length into, ``None`` where
+    it is not whole blocks."""
+    return next((b for b in BLOCKS if seq_len % b == 0), None)
+
+
+def engages(seq_len: int) -> bool:
+    """Whether ``causal_attention`` takes a sequence of this length: whole blocks, and
+    long enough that keeping the scores out of HBM pays, short enough for VMEM."""
+    return MIN_SEQ <= seq_len <= MAX_SEQ and block_for(seq_len) is not None
+
+
+def _scale(hd: int) -> tuple[float, bool]:
+    """``1/sqrt(hd)`` and whether multiplying a bfloat16 ``q`` by it is exact."""
+    scale = 1.0 / math.sqrt(hd)
+    return scale, math.frexp(scale)[0] == 0.5
+
+
+def _struct(shape, dtype, *like):
+    """``out_shape`` varying over every mesh axis one of ``like`` varies over: inside
+    ``shard_map`` a ``pallas_call`` has to say so itself."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _scores(k, q, *, scale, fold, masked):
+    """A score block transposed, ``[keys, queries]`` float32, from a key block and a query
+    block whose first rows share a position.  ``masked`` is for the diagonal block:
+    inside it a key past its query gets ``_MASKED``."""
+    s = lax.dot_general(k, q, _NT, preferred_element_type=_F32)
+    if not fold:
+        s = s * scale
+    if masked:
+        ki = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        qi = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(ki <= qi, s, _MASKED)
+    return s
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fold):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():  # once a head: V with the sequence along the lanes
+        vt_ref[...] = v_ref[...].T
+
+    q = q_ref[...]
+    if fold:
+        q = q * scale
+    hd = q.shape[-1]
+
+    def step(j, carry, masked):
+        m, l, acc = carry
+        rows = pl.ds(pl.multiple_of(j * block, block), block)
+        # Key down the sublanes, query along the lanes: the softmax's reductions run
+        # down the sublanes, vreg against vreg.
+        s = _scores(k_ref[rows, :], q, scale=scale, fold=fold, masked=masked)
+        m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + p.sum(axis=0, keepdims=True)
+        vt = vt_ref[:, rows]
+        acc = alpha * acc + jnp.dot(vt, p.astype(vt.dtype), preferred_element_type=_F32)
+        return m_new, l, acc
+
+    carry = (jnp.full((1, block), _MASKED, _F32), jnp.zeros((1, block), _F32),
+             jnp.zeros((hd, block), _F32))
+    carry = lax.fori_loop(0, i, lambda j, c: step(j, c, masked=False), carry)
+    m, l, acc = step(i, carry, masked=True)
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+    lse_ref[...] = m + jnp.log(l)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, kt_ref, *, block, scale, fold):
+    j = pl.program_id(1)
+    n_blocks = pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[...]
+    v = v_ref[...]
+    kt_ref[...] = k.T  # K's block with its rows along the lanes, for dQ^T = K^T dS^T
+
+    def pair(i, carry, masked):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(i * block, block), block)
+        q = q_ref[rows, :]
+        if fold:
+            q = q * scale
+        do = do_ref[rows, :]
+        s = _scores(k, q, scale=scale, fold=fold, masked=masked)
+        p = jnp.exp(s - lse_ref[i])
+        dp = lax.dot_general(v, do, _NT, preferred_element_type=_F32)
+        ds = (p * (dp - delta_ref[i])).astype(q.dtype)
+        dv = dv + jnp.dot(p.astype(do.dtype), do, preferred_element_type=_F32)
+        dk = dk + jnp.dot(ds, q, preferred_element_type=_F32)
+        dq_acc[:, rows] += jnp.dot(kt_ref[...], ds, preferred_element_type=_F32)
+        return dk, dv
+
+    zeros = jnp.zeros(k.shape, _F32)
+    carry = pair(j, (zeros, zeros), masked=True)
+    dk, dv = lax.fori_loop(j + 1, n_blocks, lambda i, c: pair(i, c, masked=False), carry)
+    dk_ref[...] = (dk if fold else dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(j == n_blocks - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _forward(q, k, v, block, interpret):
+    """``q, k, v`` [B, T, hd] -> output *transposed* [B, hd, T], log-sum-exp
+    [B, T/block, 1, block]."""
+    b, t, hd = q.shape
+    n_blocks = t // block
+    scale, fold = _scale(hd)
+    head = pl.BlockSpec((None, t, hd), lambda h, i: (h, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold),
+        grid=(b, n_blocks),
+        in_specs=[pl.BlockSpec((None, block, hd), lambda h, i: (h, i, 0)), head, head],
+        out_specs=[pl.BlockSpec((None, hd, block), lambda h, i: (h, 0, i)),
+                   pl.BlockSpec((None, None, 1, block), lambda h, i: (h, i, 0, 0))],
+        out_shape=[_struct((b, hd, t), q.dtype, q, k, v),
+                   _struct((b, n_blocks, 1, block), _F32, q, k, v)],
+        scratch_shapes=[pltpu.VMEM((hd, t), v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="causal_attention_fwd",
+    )(q, k, v)
+
+
+def _backward(q, k, v, do, lse, delta, block, interpret):
+    """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk``, ``dv`` [B, T, hd]."""
+    b, t, hd = q.shape
+    n_blocks = t // block
+    scale, fold = _scale(hd)
+    head = pl.BlockSpec((None, t, hd), lambda h, j: (h, 0, 0))
+    rows = pl.BlockSpec((None, block, hd), lambda h, j: (h, j, 0))
+    stats = pl.BlockSpec((None, n_blocks, 1, block), lambda h, j: (h, 0, 0, 0))
+    like = (q, k, v, do, lse, delta)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold),
+        grid=(b, n_blocks),
+        in_specs=[head, rows, rows, head, stats, stats],
+        out_specs=[pl.BlockSpec((None, hd, t), lambda h, j: (h, 0, 0)), rows, rows],
+        out_shape=[_struct((b, hd, t), q.dtype, *like), _struct(k.shape, k.dtype, *like),
+                   _struct(v.shape, v.dtype, *like)],
+        scratch_shapes=[pltpu.VMEM((hd, t), _F32), pltpu.VMEM((hd, block), k.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="causal_attention_bwd",
+    )(q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attend(q, k, v, block, interpret):
+    return _attend_fwd(q, k, v, block, interpret)[0]
+
+
+def _attend_fwd(q, k, v, block, interpret):
+    o_t, lse = _forward(q, k, v, block, interpret)
+    o = jnp.swapaxes(o_t, 1, 2)
+    return o, (q, k, v, o, lse)
+
+
+def _attend_bwd(block, interpret, saved, do):
+    q, k, v, o, lse = saved
+    delta = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1).reshape(lse.shape)
+    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret)
+    return jnp.swapaxes(dq_t, 1, 2), dk, dv
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """The same function spelled densely, in the inputs' dtype throughout: ``[N, H, T, T]``
+    scores, mask, softmax.  What short sequences run, and what the kernels are tested
+    against."""
+    t, hd = q.shape[-2:]
+    scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / math.sqrt(hd)
+    # Causal mask: position q attends to keys <= q only.  Additive -inf keeps the
+    # softmax exact for the allowed band.
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    scores = jnp.where(causal[None, None], scores, jnp.finfo(scores.dtype).min)
+    att = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("nhqk,nhkd->nhqd", att, v)
+
+
+def causal_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    block: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Causal attention ``[N, H, T, hd] x 3 -> [N, H, T, hd]``, scale ``1/sqrt(hd)``,
+    differentiable in all three; ``T`` whole blocks of ``block`` (default:
+    :func:`block_for`).
+
+    Off the TPU the kernels run in Pallas's interpreter, which cannot evaluate a kernel
+    on values that vary over a ``shard_map`` axis under its varying-axes check (the
+    kernel's own constants do not vary); there, and only there, the dense spelling
+    answers."""
+    n, h, t, hd = q.shape
+    if k.shape != q.shape or v.shape != q.shape:
+        raise ValueError(f"q, k, v must share a shape: {q.shape}, {k.shape}, {v.shape}")
+    block = block_for(t) if block is None else block
+    if block is None or t % block or block % 128:
+        raise ValueError(f"T={t} is not whole blocks of {block or BLOCKS} (multiples of 128)")
+    interpret = auto_interpret(interpret)
+    if interpret and any(jax.typeof(a).vma for a in (q, k, v)):
+        return dense_causal_attention(q, k, v)
+    flat = lambda a: a.reshape(n * h, t, hd)
+    out = _attend(flat(q), flat(k), flat(v), block, interpret)
+    return out.reshape(n, h, t, hd)

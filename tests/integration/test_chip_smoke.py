@@ -29,6 +29,7 @@ TINY = chip_smoke.SmokeSize(
     ),
     kernel_params=1300,  # not a lane multiple: the padding paths run
     kernel_cohorts=(8, 40),
+    attention_shape=(1, 2, 512, 32),
     interpret=True,
 )
 
@@ -46,7 +47,8 @@ def test_every_phase_passes_tiny_on_the_cpu_mesh(tmp_path, devices):
     wire = records["wire_ingest"]
     assert wire["accepted"] == 12 and wire["aggregations_completed"] == 3
     assert wire["flat_size"] == 4810  # digits_mlp
-    assert set(records["kernels"]) >= {"u32", "C=8", "C=40", "weighted_mean_tree"}
+    assert set(records["kernels"]) >= {
+        "u32", "C=8", "C=40", "weighted_mean_tree", "causal_attention"}
     multi = records["multichip"]
     assert multi["4"]["client_rows_per_device"] == 4
     assert multi["2x2"]["client_rows_per_device"] == 8

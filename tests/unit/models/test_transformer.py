@@ -197,6 +197,117 @@ def test_the_walk_descends_into_loop_and_jit_bodies():
     assert _largest_intermediate(jax.make_jaxpr(f)(0.0).jaxpr) == 7 * 11 * 13
 
 
+def _square_intermediates(jaxpr, t) -> list[tuple]:
+    """Shapes of every array some equation of ``jaxpr`` writes whose two trailing axes
+    are both ``t`` — sub-jaxprs (scan and jit bodies, custom-derivative rules)
+    included, a kernel's body excepted: what a ``pallas_call`` holds lives in VMEM."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [v.aval.shape for v in eqn.outvars
+                  if getattr(v.aval, "shape", ())[-2:] == (t, t)]
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _square_intermediates(sub, t)
+    return found
+
+
+class TestAttentionFormFollowsTheSequence:
+    """``_attention`` reads its form off the sequence length (``ops.attention.engages``):
+    block by block from 512 positions in whole blocks, dense below.  No option says
+    so."""
+
+    V, D, L, H, N = 61, 32, 2, 2, 2
+
+    def _step_jaxpr(self, name, seq_len):
+        from nanofed_tpu.trainer.local import make_grad_fn
+
+        m = get_model(name, vocab=self.V, seq_len=seq_len, width=self.D, depth=self.L,
+                      heads=self.H)
+        params = jax.eval_shape(m.init, jax.random.key(0))
+        x = jax.ShapeDtypeStruct((self.N, seq_len), jnp.int32)
+        y = jax.ShapeDtypeStruct((self.N,), jnp.int32)
+        mask = jax.ShapeDtypeStruct((self.N,), jnp.float32)
+        grad_fn = make_grad_fn(m.apply, compute_dtype="bfloat16")
+        return jax.make_jaxpr(grad_fn)(params, x, y, mask, jax.random.key(0)).jaxpr
+
+    @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+    @pytest.mark.parametrize("seq_len", [512, 1024])
+    def test_no_score_tensor_in_the_training_step(self, name, seq_len):
+        found = _square_intermediates(self._step_jaxpr(name, seq_len), seq_len)
+        assert not found, f"{name}: [.., {seq_len}, {seq_len}] arrays outside a kernel: {found}"
+
+    @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+    def test_the_walk_sees_a_dense_score_tensor(self, name):
+        """Control: 384 positions are not whole blocks, the dense form runs, and the same
+        walk finds its ``[N, H, T, T]`` scores."""
+        found = _square_intermediates(self._step_jaxpr(name, 384), 384)
+        assert (self.N, self.H, 384, 384) in found
+
+    @pytest.mark.parametrize("name,traces", [("transformer_lm", DEPTH),
+                                             ("transformer_lm_scan", 1)])
+    @pytest.mark.parametrize("seq_len,path,other", [(SEQ, "dense", "blockwise"),
+                                                    (512, "blockwise", "dense")])
+    def test_counter_reads_what_was_traced(self, name, traces, seq_len, path, other):
+        from nanofed_tpu.models.transformer import ATTENTION_TRACES
+        from nanofed_tpu.observability.registry import get_registry
+
+        m = get_model(name, vocab=VOCAB, seq_len=seq_len, width=WIDTH, depth=DEPTH,
+                      heads=HEADS)
+        counter = get_registry().counter(ATTENTION_TRACES, labels=("path",))
+        before = {p: counter.value(path=p) for p in (path, other)}
+        jax.eval_shape(m.apply, jax.eval_shape(m.init, jax.random.key(0)),
+                       jax.ShapeDtypeStruct((2, seq_len), jnp.int32))
+        # one trace a block unrolled, one for the scanned body
+        assert counter.value(path=path) - before[path] == traces
+        assert counter.value(path=other) == before[other]
+
+    @pytest.mark.parametrize("seq_len", [SEQ, 512])
+    def test_named_scope_is_in_the_lowered_program(self, seq_len):
+        m = get_model("transformer_lm_scan", vocab=VOCAB, seq_len=seq_len, width=WIDTH,
+                      depth=DEPTH, heads=HEADS)
+        params = jax.eval_shape(m.init, jax.random.key(0))
+        x = jax.ShapeDtypeStruct((2, seq_len), jnp.int32)
+        text = jax.jit(m.apply).lower(params, x).as_text(debug_info=True)
+        assert "causal_attention" in text
+
+    @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+    def test_short_sequences_keep_their_values_bit_for_bit(self, name, monkeypatch):
+        """At the file's ``SEQ`` the dense form runs and ``apply`` returns what it did
+        before the blockwise form existed: the same eight lines, spelled here as they
+        stood, give identical bits."""
+        import math
+
+        from nanofed_tpu import nn
+        from nanofed_tpu.models import transformer
+
+        def attention_as_it_stood(params, x, heads):
+            n, t, d = x.shape
+            hd = d // heads
+            split = lambda y: y.reshape(n, t, heads, hd).transpose(0, 2, 1, 3)
+            q = split(nn.dense(params["wq"], x))
+            k = split(nn.dense(params["wk"], x))
+            v = split(nn.dense(params["wv"], x))
+            scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / math.sqrt(hd)
+            causal = jnp.tril(jnp.ones((t, t), bool))
+            scores = jnp.where(causal[None, None], scores, jnp.finfo(scores.dtype).min)
+            att = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("nhqk,nhkd->nhqd", att, v)
+            return nn.dense(params["wo"], out.transpose(0, 2, 1, 3).reshape(n, t, d))
+
+        m = get_model(name, vocab=VOCAB, seq_len=SEQ, width=WIDTH, depth=DEPTH, heads=HEADS)
+        p = m.init(jax.random.key(4))
+        x = jnp.asarray(np.random.default_rng(4).integers(0, VOCAB, (3, SEQ)), jnp.int32)
+        now = (np.asarray(m.apply(p, x)), np.asarray(apply_sequence(p, x, heads=HEADS)))
+        monkeypatch.setattr(transformer, "_attention", attention_as_it_stood)
+        then = (np.asarray(m.apply(p, x)), np.asarray(apply_sequence(p, x, heads=HEADS)))
+        for a, b in zip(now, then):
+            np.testing.assert_array_equal(a, b)
+
+
 def test_causality(params):
     """Perturbing token t must not change any position < t — the causal mask
     is load-bearing, not decorative."""

@@ -109,6 +109,7 @@ _TPU_LOWERINGS = {
         ops.dequant_accumulate_flat, (_X.astype(jnp.int8), _W, _W, _X[0]),
     ),
     "weighted_mean_tree": (ops.weighted_mean_tree, ({"w": _X.reshape(40, 13, 100)}, _W)),
+    "causal_attention": (ops.causal_attention, (jnp.zeros((1, 2, 512, 64), jnp.bfloat16),) * 3),
 }
 
 
