@@ -1,6 +1,6 @@
 # Parity with the reference's Makefile targets (install/test/lint/format/docs/release).
 
-.PHONY: test test-fast lint lint-fed audit-smoke bench chip-smoke bench-smoke chaos-smoke hostchaos-smoke federation-smoke trace-smoke profile-smoke loadtest-smoke autotune-smoke retune-smoke warm-cache adapter-smoke adapter-evidence fleet-smoke fleet-evidence multihost-smoke multihost-bench tenants-smoke tenants-bench example dryrun dryrun-multichip-2d api-docs notebook accuracy metrics-summary clean
+.PHONY: test test-fast lint lint-fed audit-smoke chip-smoke bench-smoke chaos-smoke hostchaos-smoke federation-smoke trace-smoke profile-smoke loadtest-smoke autotune-smoke retune-smoke warm-cache adapter-smoke adapter-evidence fleet-smoke fleet-evidence multihost-smoke multihost-bench tenants-smoke tenants-bench example dryrun dryrun-multichip-2d api-docs notebook accuracy metrics-summary clean
 
 test:
 	python -m pytest tests/ -q
@@ -26,15 +26,12 @@ lint-fed:
 audit-smoke:
 	python -m nanofed_tpu.analysis --programs --mutants nanofed_tpu/
 
-# On a machine with a TPU only: both exit non-zero when JAX finds none.
-bench:
-	python bench.py
-
+# On a machine with a TPU only: exits non-zero when JAX finds none.
 chip-smoke:
 	python chip_smoke.py
 
-# Tiny fused-vs-single-round timing sanity on CPU (seconds, not minutes): catches
-# perf-plumbing regressions (fused engine, dispatch/host_sync spans) in tier-1.
+# Fused block against single rounds on a tiny CPU workload (seconds): catches a broken
+# fused engine or missing dispatch/host_sync spans in tier-1.  Not a measurement.
 bench-smoke:
 	python -m pytest tests/integration/test_bench_smoke.py -q -s
 
@@ -120,7 +117,7 @@ tenants-bench:
 
 # Autotune smoke (nanofed_tpu.tuning): sweep a tiny MLP config space on CPU
 # with the compiler's cost model — a winner must be chosen via AOT analysis
-# alone (zero round executions), the ranked runs/autotune_*.json artifact must
+# alone (zero round executions), the ranked autotune_*.json artifact must
 # parse with its scoring basis stated, the fused q8 aggregation epilogue must
 # show a measured bytes-accessed reduction in the catalog's cost table, and a
 # repeat sweep must hit the result cache with ZERO compiles.  Tier-1-safe.

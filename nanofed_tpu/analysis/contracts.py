@@ -14,8 +14,7 @@ it on data:
 * :func:`strict_mode` wraps dispatch in ``jax.transfer_guard("disallow")``:
   inside the context any *implicit* host<->device transfer raises, proving the
   fused hot path syncs only where the Coordinator says it does
-  (``Coordinator(strict=True)`` / CLI ``--strict`` / bench
-  ``NANOFED_BENCH_STRICT=1``).
+  (``Coordinator(strict=True)`` / CLI ``--strict``).
 * :func:`check_input_shardings` spot-checks the parallel layout: client data
   sharded over the client axis (and nothing else; jointly over
   ``(hosts, clients)`` on a 3-axis multi-host mesh), params replicated — or

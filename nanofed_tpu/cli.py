@@ -4,10 +4,9 @@ The reference declares a CLI entry point that doesn't exist (``pyproject.toml:22
 ``nanofed.cli:main`` but no module is shipped — SURVEY.md layer-map quirks).  This one is
 real: ``run`` drives a simulated federated experiment (``--dp-epsilon`` engages
 budget-calibrated central DP), ``serve`` hosts the real-network federation server
-(``--secure`` for masked rounds, ``--validate`` for update validation), ``bench`` runs
-the BASELINE.json suite, ``profile`` compiles the round programs WITHOUT running a
-federation and prints the compiler's cost/roofline table, ``info`` prints environment
-and model-zoo facts.
+(``--secure`` for masked rounds, ``--validate`` for update validation), ``profile``
+compiles the round programs WITHOUT running a federation and prints the compiler's
+cost/roofline table, ``info`` prints environment and model-zoo facts.
 """
 
 from __future__ import annotations
@@ -825,26 +824,6 @@ def _cmd_tenants(args: argparse.Namespace) -> int:
     return 0 if ok else 1
 
 
-def _cmd_bench(args: argparse.Namespace) -> int:
-    from nanofed_tpu.benchmarks import BENCHMARKS, run_benchmark
-
-    if args.list:
-        print(json.dumps(sorted(BENCHMARKS), indent=2))
-        return 0
-    overrides = {}
-    if args.train_size is not None:
-        overrides["train_size"] = args.train_size
-    if args.rounds is not None:
-        overrides["num_rounds"] = args.rounds
-    if args.client_chunk is not None:
-        overrides["client_chunk"] = args.client_chunk
-    if args.dtype is not None:
-        overrides["compute_dtype"] = args.dtype
-    summary = run_benchmark(args.name, out_dir=args.out_dir, **overrides)
-    print(json.dumps(summary, indent=2, default=str))
-    return 0
-
-
 def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(prog="nanofed-tpu", description=__doc__)
     sub = parser.add_subparsers(dest="cmd", required=True)
@@ -1403,17 +1382,8 @@ def main(argv: list[str] | None = None) -> int:
         "(read back with `nanofed-tpu metrics-summary`)",
     )
 
-    bench = sub.add_parser("bench", help="run a named benchmark (BASELINE.json suite)")
-    bench.add_argument("name", nargs="?", default="mnist_iid")
-    bench.add_argument("--list", action="store_true", help="list benchmark names")
-    bench.add_argument("--rounds", type=int, default=None)
-    bench.add_argument("--train-size", type=int, default=None)
-    bench.add_argument("--client-chunk", type=int, default=None)
-    bench.add_argument("--dtype", default=None, choices=["bfloat16", "float32"])
-    bench.add_argument("--out-dir", default="runs/bench")
-
     args = parser.parse_args(argv)
-    if args.cmd in ("run", "bench", "loadtest"):
+    if args.cmd in ("run", "loadtest"):
         # The commands that compile round programs keep them in the one
         # persistent cache (utils.platform.compilation_cache_dir).
         from nanofed_tpu.utils.platform import enable_compilation_cache
@@ -1421,8 +1391,6 @@ def main(argv: list[str] | None = None) -> int:
         enable_compilation_cache()
     if args.cmd == "info":
         return _cmd_info(args)
-    if args.cmd == "bench":
-        return _cmd_bench(args)
     if args.cmd == "serve":
         return _cmd_serve(args)
     if args.cmd == "chaos-plan":

@@ -1,13 +1,9 @@
 """Compiled-program cost profiling: what the COMPILER says a round program costs.
 
-Everything this framework measures about its hot path so far is wall-clock — span
-durations, round times, bench medians — and the only FLOP number anywhere is
-``bench.py``'s analytic hand-count (3x forward MACs of the CNN).  The ROADMAP north
-star ("as fast as the hardware allows") is unfalsifiable on that basis: an analytic
-count cannot say whether a round is compute-bound or HBM-bound, and a hand-derived
-MFU has no memory-bandwidth story at all.  FedJAX (arXiv:2108.02117) reports only
-rounds/sec; Flower/NVFLARE-class systems (arXiv:2407.00031) stop at run-level
-metrics — none of them ask the compiler.
+What the program itself measures about its hot path is wall-clock — span durations,
+round times.  A wall-clock number cannot say whether a round is compute-bound or
+HBM-bound.  FedJAX (arXiv:2108.02117) reports only rounds/sec; Flower/NVFLARE-class
+systems (arXiv:2407.00031) stop at run-level metrics — none of them ask the compiler.
 
 This module asks the compiler.  Every round program the framework builds — single
 step, fused R-round block, SCAFFOLD, on 1-D and 2-D meshes — is a ``jax.jit``
@@ -17,8 +13,7 @@ callable whose AOT path (``.lower(...).compile()``) yields XLA's own
 :class:`ProgramCostReport` pairs those with a per-platform peaks table (bf16 peak
 FLOP/s + HBM bandwidth) into a roofline verdict: arithmetic intensity vs the ridge
 point, compute-bound vs HBM-bound, and the achievable lower-bound walltime.
-Pairing a report with a MEASURED walltime yields compiler-FLOPs MFU — the number
-the analytic estimate could only approximate.
+Pairing a report with a MEASURED walltime yields compiler-FLOPs MFU.
 
 :class:`ProgramCatalog` is the integration point: the ``Coordinator`` registers
 every program it builds (registration is free — no compile), and ``profile()``
@@ -29,7 +24,7 @@ CLI subcommand drives the same path without running a federation.
 Numbers are PER-DEVICE: XLA reports the cost of the SPMD module each device runs
 (the per-device program), which is exactly the basis a per-chip peak wants.  A
 fused R-round block's numbers cover all R rounds — divide by R for per-round
-comparisons (the CLI table and bench records do, and say so).
+comparisons (the CLI table does, and says so).
 
 Profiling compiles.  ``jit``'s call-site executable cache is NOT shared with the
 AOT path on this JAX version, so profiling an already-run program pays a second
@@ -227,7 +222,7 @@ class ProgramCostReport:
 
     def to_dict(self) -> dict[str, Any]:
         """JSON-friendly dump — the shape of a ``telemetry.jsonl``
-        ``program_profile`` record and of bench's ``cost_analysis`` field."""
+        ``program_profile`` record."""
         out: dict[str, Any] = {
             "program": self.program,
             "platform": self.platform,

@@ -185,8 +185,8 @@ class SpanTracer:
             return list(self._records)
 
     def phase_summary(self) -> dict[str, dict[str, float]]:
-        """Per-span-name digest: count / total / mean / max seconds (what ``bench.py``
-        embeds in its JSON records and ``metrics-summary`` prints)."""
+        """Per-span-name digest: count / total / mean / max seconds (what
+        ``metrics-summary`` prints)."""
         out: dict[str, dict[str, float]] = {}
         for r in self.records:
             agg = out.setdefault(

@@ -423,9 +423,7 @@ class Coordinator:
 
         # Cohort gathering (participation < 1): running the round step over ALL N
         # clients and zero-weighting non-participants burns (1-q) of every round's
-        # FLOPs — at the DP benchmark's q=0.1 that is a 10x waste, on any platform
-        # (measured: 10.98x at q=0.1 over 240 clients once rounds are compute-bound
-        # — runs/cohort_gather_r05.json, scripts/measure_cohort_gather.py).
+        # FLOPs: at q=0.1 the step trains ten times the clients the round uses.
         # Instead, gather the sampled cohort's rows into a [K_pad, ...] batch (one
         # jitted device-side take, sharded like the source) and run the step over K
         # clients.  The math is identical: FedAvg weights, DP uniform weights,

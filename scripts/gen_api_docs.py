@@ -90,8 +90,7 @@ MODULES = [
                "nanofed_tpu.utils.trees", "nanofed_tpu.utils.platform",
                "nanofed_tpu.utils.clock", "nanofed_tpu.utils.aio",
                "nanofed_tpu.utils.dates"]),
-    ("top-level", ["nanofed_tpu.experiments", "nanofed_tpu.benchmarks",
-                   "nanofed_tpu.cli"]),
+    ("top-level", ["nanofed_tpu.experiments", "nanofed_tpu.cli"]),
 ]
 
 

@@ -1,12 +1,11 @@
-"""Fused-vs-single-round timing smoke (the `make bench-smoke` target).
+"""Fused-vs-single-round smoke (the `make bench-smoke` target).
 
-A miniature of bench.py's flagship measurement: time R single-round steps (one
-dispatch + one block_until_ready each) against one fused R-round block (one
-dispatch + one sync total), on a tiny CPU workload.  This is a PLUMBING test, not
-a benchmark: it pins that the fused engine runs end to end, that its phase spans
-(dispatch / host_sync) record, and that fused throughput has not regressed to
-absurdity relative to the single-round path — so perf-path regressions surface in
-tier-1 instead of 20 minutes into a driver bench run.
+R single-round steps (one dispatch + one block_until_ready each) against one fused
+R-round block (one dispatch + one sync total), on a tiny CPU workload.  This is a
+PLUMBING test, not a benchmark: it pins that the fused engine runs end to end, that
+its phase spans (dispatch / host_sync) record, and that the fused block has not
+regressed to absurdity relative to the single-round path — so a broken block surfaces
+in tier-1 and not in a chip run of `benchmark/run.py`.
 """
 
 import time
@@ -84,7 +83,7 @@ def test_bench_smoke_fused_vs_single_round(devices):
     assert bres.metrics["loss"].shape == (R,)
     assert np.isfinite(np.asarray(bres.metrics["loss"])).all()
     assert np.asarray(bres.survivors).tolist() == [8] * R
-    # ...the phase split recorded (what bench.py embeds in the flagship record)...
+    # ...the phase split recorded...
     phases = tracer.phase_summary()
     assert phases["dispatch"]["count"] == 1
     assert phases["host_sync"]["count"] == 1

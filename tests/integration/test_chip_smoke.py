@@ -3,6 +3,7 @@ kernels interpreted, and the entry's refusal to run without a TPU.
 
 The chip is budgeted; a typo in a phase must be found here, not there."""
 
+import json
 import os
 import subprocess
 import sys
@@ -61,6 +62,31 @@ def test_a_failed_check_raises():
     with pytest.raises(RuntimeError, match="did not fall"):
         chip_smoke._losses_ok([1.0, 2.0], "rehearsal")
     assert chip_smoke._rel_err(np.ones(4), np.ones(4) * (1 + 1e-3)) > 2e-5
+
+
+def _flagship_samples_per_client():
+    clients, _ = chip_smoke.experiment_data(chip_smoke.FULL)
+    (real,) = set(np.asarray(clients.mask).sum(axis=1).tolist())
+    return int(real)
+
+
+@pytest.mark.parametrize("field", [
+    "num_clients", "samples_per_client", "local_epochs", "batch_size", "learning_rate",
+    "compute_dtype",
+])
+def test_flagship_is_the_federation_of_the_benchmarks_cnn_cell(field):
+    """What the bring-up proof runs is the federation the benchmark's one-chip CNN cell
+    measures; ``client_chunk`` is the one stated difference (PERF.md section 4)."""
+    cell = json.loads(
+        (REPO / "benchmark" / "configs" / "mnist-cnn-xdevice-1000.json").read_text()
+    )
+    flagship = chip_smoke.FULL.experiment
+    ours = (
+        _flagship_samples_per_client() if field == "samples_per_client" else flagship[field]
+    )
+    assert ours == {**cell["federation"], **cell["precision"]}[field]
+    assert flagship["model"] == cell["model"]["factory"]
+    assert flagship["client_chunk"] == 125 != cell["client_chunk"]
 
 
 def test_entry_refuses_to_run_without_a_tpu():

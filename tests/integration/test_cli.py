@@ -23,8 +23,9 @@ def test_info(capsys):
 
 
 def test_compiling_commands_enable_the_compile_cache(tmp_path, capsys, monkeypatch):
-    """run/bench/loadtest keep their programs in the one persistent cache; info, which
+    """run/loadtest keep their programs in the one persistent cache; info, which
     compiles nothing, does not touch the setting."""
+    from nanofed_tpu import cli
     from nanofed_tpu.utils import platform
 
     calls = []
@@ -33,7 +34,8 @@ def test_compiling_commands_enable_the_compile_cache(tmp_path, capsys, monkeypat
     )
     assert main(["info"]) == 0
     assert calls == []
-    assert main(["bench", "--list"]) == 0
+    monkeypatch.setattr(cli, "_cmd_run", lambda args: 0)
+    assert main(["run"]) == 0
     assert calls == [1]
     capsys.readouterr()
 
@@ -285,11 +287,6 @@ def test_profile_table_output(capsys):
     assert "round_step" in out
     assert "roofline basis" in out
     assert "flops/round" in out
-
-
-def test_unknown_benchmark_name_errors():
-    with pytest.raises(KeyError):
-        main(["bench", "not_a_benchmark"])
 
 
 def test_run_robust_with_dp_fails_fast(capsys):

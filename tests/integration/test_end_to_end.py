@@ -303,3 +303,73 @@ def test_no_privacy_no_accounting(mlp, tmp_path, devices):
     rounds = coord.run()
     assert coord.privacy_spent is None
     assert "privacy_epsilon" not in rounds[0].agg_metrics
+
+
+# Six small federations through the public ``run_experiment``: the only tier-1 runs of
+# label-skew sampling, FedProx, central DP with a calibrated sigma, and bf16 compute with
+# ``client_chunk``.  An MLP stands in for the CNN/ResNet each would train on a chip (their
+# XLA compiles take minutes on the CPU mesh; unit forward tests cover the models).
+_EXPERIMENT_SMOKE = {
+    "mnist_iid": dict(
+        num_clients=10, num_rounds=2, local_epochs=2, batch_size=64, learning_rate=0.1,
+        scheme="iid", participation=1.0, train_size=640,
+    ),
+    "mnist_labelskew": dict(
+        num_clients=16, num_rounds=2, local_epochs=1, batch_size=32, learning_rate=0.1,
+        scheme="label_skew", participation=0.1, shards_per_client=2, train_size=1600,
+    ),
+    "fedprox_cifar10": dict(
+        num_clients=8, num_rounds=1, local_epochs=1, batch_size=32, learning_rate=0.05,
+        scheme="dirichlet", participation=0.1, alpha=0.5, prox_mu=0.01, train_size=512,
+    ),
+    "dp_fedavg_mnist": dict(
+        num_clients=10, num_rounds=2, local_epochs=1, batch_size=64, learning_rate=0.1,
+        scheme="iid", participation=1.0, train_size=640,
+    ),
+    "cross_silo": dict(
+        num_clients=8, num_rounds=1, local_epochs=1, batch_size=32, learning_rate=0.05,
+        scheme="iid", participation=1.0, train_size=256,
+    ),
+    # 32 clients >> 8 devices with client_chunk=2: the sequential-chunk path and bf16
+    # mixed precision (the flagship configuration, scaled down for the CPU mesh).
+    "mnist_1000": dict(
+        num_clients=32, num_rounds=2, local_epochs=2, batch_size=64, learning_rate=0.1,
+        scheme="iid", participation=1.0, client_chunk=2, compute_dtype="bfloat16",
+        train_size=640,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_EXPERIMENT_SMOKE))
+def test_run_experiment_smoke(name, tmp_path):
+    from nanofed_tpu.experiments import run_experiment
+
+    config = dict(_EXPERIMENT_SMOKE[name], model="mlp")
+    if name == "dp_fedavg_mnist":
+        from nanofed_tpu.aggregation.privacy import PrivacyAwareAggregationConfig
+        from nanofed_tpu.orchestration import cohort_size
+        from nanofed_tpu.privacy import PrivacyConfig
+        from nanofed_tpu.privacy.accounting import noise_multiplier_for_budget
+
+        # Sigma calibrated so the whole run spends exactly the (8, 1e-5) budget at the
+        # realized cohort rate.
+        q = cohort_size(config["num_clients"], config["participation"]) / config["num_clients"]
+        sigma = noise_multiplier_for_budget(
+            8.0, 1e-5, sampling_rate=q, num_events=config["num_rounds"]
+        )
+        config["central_privacy"] = PrivacyAwareAggregationConfig(
+            privacy=PrivacyConfig(
+                epsilon=8.0, delta=1e-5, max_gradient_norm=1.0, noise_multiplier=sigma
+            )
+        )
+    summary = run_experiment(out_dir=str(tmp_path), **config)
+    assert summary["rounds_failed"] == 0
+    assert summary["rounds_completed"] >= 1
+    assert "accuracy" in summary["final_eval_metrics"]
+    if name == "dp_fedavg_mnist":
+        # The experiment summary surfaces cumulative DP spend.
+        spent = summary["privacy_spent"]
+        assert spent["epsilon_spent"] > 0
+        assert 0 < spent["delta_spent"] <= 1e-5
+    else:
+        assert "privacy_spent" not in summary

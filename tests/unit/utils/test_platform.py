@@ -3,17 +3,12 @@ CPU mesh set-up.  (The compile-cache location rule is pinned in
 tests/unit/tuning/test_compile_cache.py.)"""
 
 import os
-import subprocess
-import sys
-from pathlib import Path
 from types import SimpleNamespace
 
 import jax
 import pytest
 
 from nanofed_tpu.utils import platform
-
-REPO = Path(__file__).resolve().parents[3]
 
 
 def _fake_devices(monkeypatch, kind: str, n: int = 1):
@@ -49,15 +44,3 @@ def test_force_cpu_mesh_replaces_a_preset_device_count(monkeypatch):
     assert os.environ["XLA_FLAGS"] == "--foo=1 --xla_force_host_platform_device_count=4"
     assert os.environ["JAX_PLATFORMS"] == "cpu"
     assert updates == [("jax_platforms", "cpu")]
-
-
-def test_bench_exits_nonzero_and_prints_no_record_without_a_tpu():
-    """bench.py measures on the chip or fails: no CPU fallback, no stale line."""
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "bench.py")],
-        capture_output=True, text=True, timeout=300, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""
-    assert "need a TPU" in proc.stderr
