@@ -16,8 +16,9 @@ adapters pay" carries the math).
 
 Architecture (functional, pure ``(init, apply)`` like the rest of the zoo):
 token embedding + learned positional embedding, ``depth`` pre-LN blocks of
-multi-head CAUSAL self-attention and a 4x GELU MLP, final LayerNorm, untied
-unembedding head.  ``apply`` returns next-token log-probabilities at the LAST
+multi-head CAUSAL self-attention and a 4x GELU MLP (whose backward is handed the
+GELU's input and nothing else of that width: :func:`_mlp_tail`), final LayerNorm,
+untied unembedding head.  ``apply`` returns next-token log-probabilities at the LAST
 position (``[N, vocab]``) so the model drops into the standard federated
 pipeline — ``ClientData.y`` is the true next token, the masked-NLL ``grad_fn``,
 evaluator, and every round builder work unchanged; :func:`apply_sequence`
@@ -45,6 +46,7 @@ leading dim of rank>=3 leaves from the model axis).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -199,6 +201,22 @@ def _attention(params: Params, x: jax.Array, heads: int) -> jax.Array:
     return nn.dense(params["wo"], out)
 
 
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _mlp_tail(fc2: Params, h: jax.Array) -> jax.Array:
+    """The MLP's tail ``fc2(gelu(h))`` over ``h = fc1(ln2(x))`` ``[N, T, 4D]``, keeping
+    ``h`` alone for its backward.
+
+    Plain autodiff of the tanh GELU and of fc2 saves six arrays of ``h``'s shape a block
+    (``h``, its square, the tanh, the cdf, the product, fc2's input), each an elementwise
+    function of ``h``; under ``checkpoint`` the backward recomputes them where it uses
+    them, by the same arithmetic, so values and gradients are autodiff's bit for bit.
+    fc2's own product is not run again: its output is no input of the backward.
+    ``prevent_cse=False``: the default's optimization barriers cost two slice copies a
+    layer inside the layer scan, and without a scan XLA's scheduler decides what to keep
+    either way."""
+    return nn.dense(fc2, jax.nn.gelu(h))
+
+
 def _trunk(params: Params, tokens: jax.Array, heads: int) -> jax.Array:
     """Embeddings + ``depth`` blocks: int token ids ``[N, T]`` -> the hidden
     state ``[N, T, D]`` the final LayerNorm and the head read."""
@@ -209,7 +227,7 @@ def _trunk(params: Params, tokens: jax.Array, heads: int) -> jax.Array:
     def block(x, blk):
         x = x + _attention(blk["attn"], _layer_norm(blk["ln1"], x), heads)
         h = nn.dense(blk["mlp"]["fc1"], _layer_norm(blk["ln2"], x))
-        return x + nn.dense(blk["mlp"]["fc2"], jax.nn.gelu(h))
+        return x + _mlp_tail(blk["mlp"]["fc2"], h)
 
     if "blocks" in params:
         # Scan layout: one traced block body, scanned over the stacked

@@ -308,6 +308,146 @@ class TestAttentionFormFollowsTheSequence:
             np.testing.assert_array_equal(a, b)
 
 
+def _tail_as_it_stood(fc2, h):
+    """The block's last line as it stood: plain autodiff of the tanh GELU and of fc2
+    keeps six arrays of ``h``'s shape (``h``, its square, the tanh, the cdf, the product
+    and fc2's input)."""
+    from nanofed_tpu import nn
+
+    return nn.dense(fc2, jax.nn.gelu(h))
+
+
+class TestMlpTailKeepsItsInputAlone:
+    """``_mlp_tail`` is ``fc2(gelu(h))`` whose backward is handed ``h`` and nothing else
+    of that width: GELU, its derivative and fc2's input are recomputed from it.  The
+    forward is the old line's; the gradients are plain autodiff's.  No option says so."""
+
+    V, T, D, L, H, N = 61, 16, 32, 3, 2, 2
+    LAYOUTS = ["transformer_lm", "transformer_lm_scan"]
+
+    def _model(self, name):
+        return get_model(name, vocab=self.V, seq_len=self.T, width=self.D, depth=self.L,
+                         heads=self.H)
+
+    def _batch(self):
+        rng = np.random.default_rng(9)
+        x = jnp.asarray(rng.integers(0, self.V, (self.N, self.T)), jnp.int32)
+        y = jnp.asarray(rng.integers(0, self.V, (self.N,)), jnp.int32)
+        return x, y, jnp.ones((self.N,), jnp.float32)
+
+    @staticmethod
+    def _put_the_line_back(monkeypatch):
+        from nanofed_tpu.models import transformer
+
+        monkeypatch.setattr(transformer, "_mlp_tail", _tail_as_it_stood)
+
+    def _wide_residuals(self, name):
+        """Shapes of what the training loss's VJP saves with trailing ``[T, 4 width]``."""
+        from jax._src.ad_checkpoint import saved_residuals
+
+        m = self._model(name)
+        x, y, _ = self._batch()
+
+        def loss(p):  # make_grad_fn's: bfloat16 compute on float32 parameters
+            p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+            logp = m.apply(p, x, train=True).astype(jnp.float32)
+            return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
+
+        saved = saved_residuals(loss, jax.eval_shape(m.init, jax.random.key(0)))
+        return [a.shape for a, _ in saved if a.shape[-2:] == (self.T, 4 * self.D)]
+
+    @pytest.mark.parametrize("name,per_block", [
+        ("transformer_lm", [(N, T, 4 * D)] * L),  # one a block
+        ("transformer_lm_scan", [(L, N, T, 4 * D)]),  # one stacked scan output
+    ])
+    def test_one_wide_residual_a_block(self, name, per_block):
+        assert self._wide_residuals(name) == per_block
+
+    @pytest.mark.parametrize("name,count", [("transformer_lm", 6 * L),
+                                            ("transformer_lm_scan", 6)])
+    def test_the_count_sees_six_in_the_plain_spelling(self, name, count, monkeypatch):
+        """Control: the same count over the line as it stood."""
+        self._put_the_line_back(monkeypatch)
+        assert len(self._wide_residuals(name)) == count
+
+    @pytest.mark.parametrize("name", LAYOUTS)
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    def test_forward_is_the_plain_spelling_bit_for_bit(self, name, dtype, monkeypatch):
+        m = self._model(name)
+        p = jax.tree.map(lambda a: a.astype(dtype), m.init(jax.random.key(1)))
+        x, _, _ = self._batch()
+        now = (m.apply(p, x), apply_sequence(p, x, heads=self.H))
+        self._put_the_line_back(monkeypatch)
+        then = (m.apply(p, x), apply_sequence(p, x, heads=self.H))
+        for a, b in zip(now, then):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def _assert_grads_equal(self, got, want):
+        """The same arithmetic, recomputed: every leaf's gradient is autodiff's own, in
+        float32 and in bfloat16 alike."""
+        got_leaves = jax.tree_util.tree_leaves_with_path(got)
+        want_leaves = jax.tree_util.tree_leaves_with_path(want)
+        assert [k for k, _ in got_leaves] == [k for k, _ in want_leaves]
+        for (path, a), (_, b) in zip(got_leaves, want_leaves):
+            assert np.abs(np.asarray(b)).max() > 0.0, jax.tree_util.keystr(path)
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("name", LAYOUTS)
+    @pytest.mark.parametrize("compute_dtype", [None, "bfloat16"], ids=["f32", "bf16"])
+    def test_gradients_are_plain_autodiffs(self, name, compute_dtype, monkeypatch):
+        """Every leaf's gradient of the training loss against autodiff of the line as it
+        stood; the scanned layout is ``grad`` through ``lax.scan``."""
+        from nanofed_tpu.trainer.local import make_grad_fn
+
+        m = self._model(name)
+        p = m.init(jax.random.key(2))
+        batch = (*self._batch(), jax.random.key(0))
+        got, _ = make_grad_fn(m.apply, compute_dtype=compute_dtype)(p, *batch)
+        self._put_the_line_back(monkeypatch)
+        want, _ = make_grad_fn(m.apply, compute_dtype=compute_dtype)(p, *batch)
+        self._assert_grads_equal(got, want)
+
+    @pytest.mark.parametrize("name", LAYOUTS)
+    def test_per_example_gradients_under_vmap(self, name, monkeypatch):
+        """The private trainer's nesting: ``vmap`` over examples of ``grad``."""
+        from nanofed_tpu.trainer.local import make_grad_fn
+
+        m = self._model(name)
+        p = m.init(jax.random.key(3))
+        x, y, mask = self._batch()
+
+        def per_example():
+            one = lambda xi, yi, mi: make_grad_fn(m.apply)(
+                p, xi[None], yi[None], mi[None], jax.random.key(0))[0]
+            return jax.vmap(one)(x, y, mask)
+
+        got = per_example()
+        self._put_the_line_back(monkeypatch)
+        self._assert_grads_equal(got, per_example())
+
+    @pytest.mark.parametrize("name", LAYOUTS)
+    def test_gradients_through_the_adapter_apply(self, name, monkeypatch):
+        """``make_adapter_apply`` merges ``W + s A B`` and calls ``apply``: the adapters'
+        gradients are autodiff's too."""
+        from nanofed_tpu.adapters.lora import AdapterSpec, init_adapters, make_adapter_apply
+        from nanofed_tpu.trainer.local import make_grad_fn
+
+        m = self._model(name)
+        base = m.init(jax.random.key(4))
+        spec = AdapterSpec(rank=2)
+        rng = np.random.default_rng(5)
+        # B starts at zero, where A's gradient is zero: move every leaf off it
+        adapters = jax.tree.map(
+            lambda a: a + 0.01 * rng.standard_normal(a.shape).astype(np.float32),
+            init_adapters(spec, base, rng=0))
+        batch = (*self._batch(), jax.random.key(0))
+        grads = lambda: make_grad_fn(make_adapter_apply(m.apply, spec, base))(adapters, *batch)[0]
+        got = grads()
+        self._put_the_line_back(monkeypatch)
+        self._assert_grads_equal(got, grads())
+
+
 def test_causality(params):
     """Perturbing token t must not change any position < t — the causal mask
     is load-bearing, not decorative."""

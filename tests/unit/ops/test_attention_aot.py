@@ -2,14 +2,20 @@
 GPT-2 cell's shapes: what the TPU's compiler refuses (a tiling, a VMEM budget, a
 transpose it cannot place) fails here, at no chip time.  Nothing runs: this says nothing
 about values or times.  All such compiles live in this one file (one worker loads the
-TPU's library, inside the fixture)."""
+TPU's library, inside the fixture): the cell's training step is here too, for what the
+compiler keeps of the MLP between its forward and its backward."""
+
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from nanofed_tpu import nn
+from nanofed_tpu.models import get_model, transformer
 from nanofed_tpu.ops.attention import causal_attention
+from nanofed_tpu.trainer.local import make_grad_fn
 
 CELL = (4, 12, 1024, 64)  # gpt2-124m-xsilo-8: batch 4, 12 heads, 1024 positions of 64
 
@@ -69,3 +75,61 @@ def test_compiles_under_vmap_and_scan(one_chip):
     x = jax.ShapeDtypeStruct((1, *CELL), jnp.bfloat16, sharding=one_chip)
     text = jax.jit(jax.vmap(jax.grad(through))).lower(x).compile().as_text()
     assert "causal_attention_bwd" in text
+
+
+GPT2_BLOCKS = dict(seq_len=1024, width=768, depth=12, heads=12)  # the cell's, vocab aside
+WIDE = "bf16[12,1,4,1024,3072]"  # a [4,1024,3072] array a layer, stacked by the scan
+
+
+def _tail_as_it_stood(fc2, h):
+    return nn.dense(fc2, jax.nn.gelu(h))
+
+
+@pytest.fixture(scope="module")
+def compiled_steps(one_chip):
+    """The cell's gradient step (batch 4 of 1024 tokens through twelve scanned blocks,
+    bfloat16 compute, one client under ``vmap``) compiled twice: as the model spells the
+    MLP's tail, and with the line as it stood before it kept ``h`` alone."""
+    m = get_model("transformer_lm_scan", vocab=256, **GPT2_BLOCKS)
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda a: shaped(a.shape, a.dtype),
+                          jax.eval_shape(m.init, jax.random.key(0)))
+    batch = (shaped((1, 4, 1024), jnp.int32), shaped((1, 4), jnp.int32),
+             shaped((1, 4), jnp.float32))
+
+    def compile_step():
+        grad_fn = make_grad_fn(m.apply, compute_dtype="bfloat16")
+        step = jax.vmap(lambda p, x, y, mask: grad_fn(p, x, y, mask, jax.random.key(0))[0],
+                        in_axes=(None, 0, 0, 0))
+        return jax.jit(step).lower(params, *batch).compile()
+
+    now = compile_step()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(transformer, "_mlp_tail", _tail_as_it_stood)
+        stood = compile_step()
+    return {"now": now, "stood": stood}
+
+
+def _wide_buffers_carried(compiled) -> int:
+    """Most ``WIDE`` buffers any loop of the program carries: the backward scan's tuple
+    holds every stacked residual the forward scan wrote."""
+    return max(line.split(" while(")[0].count(WIDE)
+               for line in compiled.as_text().splitlines() if " while(" in line)
+
+
+@pytest.mark.parametrize("which,buffers", [("now", 1), ("stood", 6)])
+def test_mlp_residuals_in_the_compiled_step(compiled_steps, which, buffers):
+    assert _wide_buffers_carried(compiled_steps[which]) == buffers
+
+
+def test_backward_reruns_no_product(compiled_steps):
+    """fc2's forward product is not computed again for the backward (its primal output
+    is unused there): the step holds as many matrix products as it did."""
+    products = {k: len(re.findall(r" (?:convolution|dot)\(", c.as_text()))
+                for k, c in compiled_steps.items()}
+    assert products["now"] == products["stood"] > 0
+
+
+def test_temporaries_fall_by_a_gigabyte(compiled_steps):
+    temp = {k: c.memory_analysis().temp_size_in_bytes for k, c in compiled_steps.items()}
+    assert temp["stood"] - temp["now"] >= 1e9, temp
