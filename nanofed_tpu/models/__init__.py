@@ -5,12 +5,14 @@ from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
     hybrid,
     linear,
     mnist,
+    moe_decoder,
     resnet,
     transformer,
 )
 from nanofed_tpu.models.base import Model, get_model, list_models, register_model
 from nanofed_tpu.models.hybrid import hybrid_lm
 from nanofed_tpu.models.mnist import mnist_cnn
+from nanofed_tpu.models.moe_decoder import moe_decoder_lm
 from nanofed_tpu.models.resnet import resnet8, resnet18
 from nanofed_tpu.models.transformer import (
     stack_blocks,
@@ -26,6 +28,7 @@ __all__ = [
     "register_model",
     "hybrid_lm",
     "mnist_cnn",
+    "moe_decoder_lm",
     "resnet8",
     "resnet18",
     "stack_blocks",

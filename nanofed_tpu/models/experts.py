@@ -1,0 +1,184 @@
+"""The held experts of a mixture-of-experts layer: dispatch and the block loop, shared
+by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``).
+
+A layer is TOLD which experts it holds (``first_expert``, ``held``) of the ``experts``
+its router scores.  The model routes — its own scores, its own normalisation — and hands
+the picks and their weights to :func:`held_experts`: picks that land on held experts are
+laid out by expert in whole blocks of ``block`` rows (``moe_dispatch``) and the held
+experts' MLPs run over the blocks in use (``moe_experts``, :func:`expert_blocks`: a loop
+whose trip count follows the routing, so no capacity limit and no dropped token, and no
+work on blocks nobody fills).  What experts held elsewhere would add is left out: on one
+chip the layer runs without its exchange, and a sum over all the shares is the uncut
+layer (tests).
+
+An expert is ``W_out act(W_in x)``; ``act`` is an :class:`Activation`, a parameter of
+the loop and of its hand-written backward: :data:`RELU2` on ``[rows, f]``, :data:`REGLU`
+on a fused ``[rows, 2f]`` product (``W_gate | W_up`` stored as one ``[d, 2f]`` leaf).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+_F32 = jnp.float32
+
+#: What :func:`held_experts` counts of one layer's routing, in this order: the share of
+#: all picks that landed on held experts; the held experts' largest token count over
+#: their mean (1.0 is even); rows taken over rows of the blocks the loop ran.
+COUNTERS = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_block_fill")
+
+
+class Activation(NamedTuple):
+    """An expert's activation between its two products.  ``apply(pre) -> hidden``;
+    ``with_grad(pre) -> (hidden, d_hidden -> d_pre)`` for the loop's backward."""
+
+    apply: Callable
+    with_grad: Callable
+
+
+def _relu2_with_grad(pre):
+    kept = jax.nn.relu(pre)
+    return jnp.square(kept), lambda d_hidden: d_hidden * 2 * kept
+
+
+def _reglu(pre):
+    f = pre.shape[-1] // 2
+    return jax.nn.relu(pre[..., :f]) * pre[..., f:]
+
+
+def _reglu_with_grad(pre):
+    f = pre.shape[-1] // 2
+    gate, up = pre[..., :f], pre[..., f:]
+    kept = jax.nn.relu(gate)
+    return kept * up, lambda d_hidden: jnp.concatenate(
+        [jnp.where(gate > 0, d_hidden * up, 0), d_hidden * kept], axis=-1)
+
+
+#: ``relu(pre)^2``.
+RELU2 = Activation(lambda pre: jnp.square(jax.nn.relu(pre)), _relu2_with_grad)
+#: ``relu(gate) * up`` of ``pre = [gate | up]``: the product is twice the hidden width.
+REGLU = Activation(_reglu, _reglu_with_grad)
+
+
+def _zeros_varying_like(shape, *like):
+    """Float32 zeros that vary over every mesh axis one of ``like`` varies over: inside
+    ``shard_map`` a loop's carry has to start with the type its update will have."""
+    axes = set().union(*(jax.typeof(a).vma for a in like))
+    zeros = jnp.zeros(shape, _F32)
+    return lax.pcast(zeros, tuple(axes), to="varying") if axes else zeros
+
+
+def _block_operands(b, block, x, gate, src, block_expert, w_in, w_out):
+    """Block ``b``: its rows' picks and tokens, the token rows, their gates, its expert's
+    two matrices.  An empty row's pick is ``n * top_k`` and its token ``n``, one past the
+    end: such a row reads zeros (``mode="fill"``) and what it writes is dropped."""
+    picks = lax.dynamic_slice_in_dim(src, b * block, block)
+    tokens = picks // (gate.shape[0] // x.shape[0])
+    expert = block_expert[b]
+    return (picks, tokens, x.at[tokens].get(mode="fill", fill_value=0),
+            gate.at[picks].get(mode="fill", fill_value=0), expert,
+            lax.dynamic_index_in_dim(w_in, expert, keepdims=False),
+            lax.dynamic_index_in_dim(w_out, expert, keepdims=False))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def expert_blocks(x, gate, src, block_expert, n_blocks, w_in, w_out, activation, block):
+    """``out[t] = sum over t's held picks of gate[pick] * W_out,e act(W_in,e x[t])``.
+
+    ``x`` [n, d] tokens, ``gate`` [n * top_k] float32 one weight a pick (pick ``i`` is
+    token ``i // top_k``'s).  The picks that landed on held experts are laid out by
+    expert in whole blocks of ``block`` rows: ``src`` [rows] names each row's pick
+    (``n * top_k``: empty row), block ``b`` belongs to expert ``block_expert[b]``, and
+    only the first ``n_blocks`` blocks are in use.  Only they are computed: forward and
+    backward are loops whose trip count is ``n_blocks``, each block gathering its own
+    token rows and adding its result back to them, so blocks nobody fills cost nothing —
+    which is why the backward pass is written out (a loop of unknown length has no
+    automatic transpose, and the transpose of a gather over all rows is a scatter over
+    all rows)."""
+    def body(b, out):
+        _, tokens, rows, gates, _, w_i, w_o = _block_operands(
+            b, block, x, gate, src, block_expert, w_in, w_out)
+        y = activation.apply(rows @ w_i) @ w_o
+        return out.at[tokens].add(y * gates[:, None].astype(y.dtype), mode="drop")
+
+    zeros = _zeros_varying_like(x.shape, x, gate, src, w_in, w_out).astype(x.dtype)
+    return lax.fori_loop(0, n_blocks, body, zeros)
+
+
+def _expert_blocks_fwd(x, gate, src, block_expert, n_blocks, w_in, w_out, activation, block):
+    saved = (x, gate, src, block_expert, n_blocks, w_in, w_out)
+    return expert_blocks(*saved, activation, block), saved
+
+
+def _expert_blocks_bwd(activation, block, saved, d_out):
+    x, gate, src, block_expert, n_blocks, w_in, w_out = saved
+
+    def body(b, carry):
+        dx, d_gate, d_in, d_o = carry
+        picks, tokens, rows, gates, expert, w_i, w_o = _block_operands(
+            b, block, x, gate, src, block_expert, w_in, w_out)
+        hidden, pull = activation.with_grad(rows @ w_i)
+        dy = d_out.at[tokens].get(mode="fill", fill_value=0)
+        d_gate = d_gate.at[picks].set(
+            jnp.sum((hidden @ w_o).astype(_F32) * dy.astype(_F32), axis=-1), mode="drop")
+        dy = dy * gates[:, None].astype(dy.dtype)
+        d_pre = pull(dy @ w_o.T)
+        dx = dx.at[tokens].add(d_pre @ w_i.T, mode="drop")
+        add = lambda acc, term: lax.dynamic_update_index_in_dim(
+            acc, lax.dynamic_index_in_dim(acc, expert, keepdims=False) + term, expert, 0)
+        d_in = add(d_in, jnp.matmul(rows.T, d_pre, preferred_element_type=_F32))
+        d_o = add(d_o, jnp.matmul(hidden.T, dy, preferred_element_type=_F32))
+        return dx, d_gate, d_in, d_o
+
+    zeros = lambda like: _zeros_varying_like(like.shape, x, gate, src, d_out, w_in, w_out)
+    start = (zeros(x).astype(x.dtype), zeros(gate), zeros(w_in), zeros(w_out))
+    dx, d_gate, d_in, d_o = lax.fori_loop(0, n_blocks, body, start)
+    return (dx, d_gate.astype(gate.dtype), None, None, None,
+            d_in.astype(w_in.dtype), d_o.astype(w_out.dtype))
+
+
+expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
+
+
+def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int, block: int,
+                 activation: Activation):
+    """The held experts' part of the routed output for tokens ``x`` [n, d], and the
+    layer's :data:`COUNTERS` (float32 ``[3]``).  ``picks`` [n, top_k] int32 name experts
+    among ALL the router scores, ``weights`` [n, top_k] float32 what each pick's output
+    is scaled by; ``w_in`` / ``w_out`` hold experts ``first_expert .. first_expert +
+    w_in.shape[0]``."""
+    n, top_k = picks.shape
+    held = w_in.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        local = (picks - first_expert).reshape(n * top_k)
+        key = jnp.where((local >= 0) & (local < held), local, held)  # held: lands elsewhere
+        counts = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0, dtype=jnp.int32)
+        # Picks by expert, in pick order within an expert; then every expert's picks
+        # padded to whole blocks: row r of the layout is the rank-th pick of its expert.
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        padded = -(-counts // block) * block
+        ends = jnp.cumsum(padded)
+        rows = n * min(top_k, held) + held * block
+        rows = -(-rows // block) * block
+        block_expert = jnp.clip(jnp.searchsorted(
+            ends, jnp.arange(rows // block, dtype=jnp.int32) * block, side="right"),
+            0, held - 1).astype(jnp.int32)
+        r = jnp.arange(rows, dtype=jnp.int32)
+        expert = block_expert[r // block]
+        rank = r - (ends - padded)[expert]
+        taken = (rank < counts[expert]) & (r < ends[-1])
+        first_pick = jnp.cumsum(counts) - counts
+        src = jnp.where(taken, order[jnp.clip(first_pick[expert] + rank, 0, n * top_k - 1)],
+                        n * top_k)
+    with jax.named_scope("moe_experts"):
+        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert,
+                            ends[-1] // block, w_in, w_out, activation, block)
+    landed = counts.sum().astype(_F32)
+    even = jnp.where(landed > 0, counts.max() * held / jnp.maximum(landed, 1.0), 1.0)
+    fill = jnp.where(landed > 0, landed / jnp.maximum(ends[-1], 1).astype(_F32), 1.0)
+    return out, jnp.stack([landed / (n * top_k), even, fill])

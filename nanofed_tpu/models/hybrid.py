@@ -26,8 +26,9 @@ Step sizes, decays and their cumulative sums stay float32 under mixed precision.
 ``experts`` with a sigmoid, picks ``top_k`` and normalises over all picks; picks that
 land on held experts are laid out by expert in whole blocks of ``EXPERT_BLOCK`` rows
 (``moe_dispatch``) and the held experts' squared-ReLU MLPs run over the blocks in use
-(``moe_experts``, :func:`expert_blocks`: a loop whose trip count follows the routing, so
-no capacity limit and no dropped token, and no work on blocks nobody fills); the shared
+(``moe_experts``: ``models.experts.expert_blocks``, the loop every routing model of the
+zoo shares; its trip count follows the routing, so no capacity limit and no dropped
+token, and no work on blocks nobody fills); the shared
 expert (``moe_shared``) sees every token.  What experts held elsewhere would add is
 left out — on one chip the layer runs without its exchange, and a sum over all the
 shares, the shared expert counted once, is the uncut layer (tests).  The layer reports
@@ -47,6 +48,8 @@ from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
+from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
+from nanofed_tpu.models.experts import RELU2, held_experts
 
 #: Rows a block of the expert loop holds: a held expert's picks are padded to whole
 #: blocks, so a block multiplies one expert's matrices.  A block's cost is mostly fixed
@@ -63,7 +66,7 @@ EXPERT_BLOCK = 1536
 #: What ``apply.with_counters`` reports beside the log-probabilities, each the mean over
 #: the ``E`` layers of one batch: the share of all picks that landed on held experts, and
 #: the held experts' largest token count over their mean (1.0 is even).
-COUNTERS = ("moe_held_pick_share", "moe_load_max_over_mean")
+COUNTERS = EXPERT_COUNTERS[:2]
 
 # Initialisation ranges of the Mamba-2 mixer: step sizes log-uniform in [DT_MIN, DT_MAX],
 # floored; A uniform in A_RANGE.
@@ -259,121 +262,17 @@ def route(router: jax.Array, x: jax.Array, cfg: dict):
     return picks, cfg["routed_scale"] * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
 
 
-def _zeros_varying_like(shape, *like):
-    """Float32 zeros that vary over every mesh axis one of ``like`` varies over: inside
-    ``shard_map`` a loop's carry has to start with the type its update will have."""
-    axes = set().union(*(jax.typeof(a).vma for a in like))
-    zeros = jnp.zeros(shape, _F32)
-    return lax.pcast(zeros, tuple(axes), to="varying") if axes else zeros
-
-
-def _block_operands(b, x, gate, src, block_expert, w_up, w_down):
-    """Block ``b``: its rows' picks and tokens, the token rows, their gates, its expert's
-    two matrices.  An empty row's pick is ``n * top_k`` and its token ``n``, one past the
-    end: such a row reads zeros (``mode="fill"``) and what it writes is dropped."""
-    picks = lax.dynamic_slice_in_dim(src, b * EXPERT_BLOCK, EXPERT_BLOCK)
-    tokens = picks // (gate.shape[0] // x.shape[0])
-    expert = block_expert[b]
-    return (picks, tokens, x.at[tokens].get(mode="fill", fill_value=0),
-            gate.at[picks].get(mode="fill", fill_value=0), expert,
-            lax.dynamic_index_in_dim(w_up, expert, keepdims=False),
-            lax.dynamic_index_in_dim(w_down, expert, keepdims=False))
-
-
-@jax.custom_vjp
-def expert_blocks(x, gate, src, block_expert, n_blocks, w_up, w_down):
-    """``out[t] = sum over t's held picks of gate[pick] * W_down,e relu(W_up,e x[t])^2``.
-
-    ``x`` [n, d] tokens, ``gate`` [n * top_k] float32 one weight a pick (pick ``i`` is
-    token ``i // top_k``'s).  The picks that landed on held experts are laid out by
-    expert in whole blocks of ``EXPERT_BLOCK`` rows: ``src`` [rows] names each row's
-    pick (``n * top_k``: empty row), block ``b`` belongs to expert ``block_expert[b]``,
-    and only the first ``n_blocks`` blocks are in use.  Only they are computed: forward
-    and backward are loops whose trip count is ``n_blocks``, each block gathering its own
-    token rows and adding its result back to them, so blocks nobody fills cost nothing —
-    which is why the backward pass is written out (a loop of unknown length has no
-    automatic transpose, and the transpose of a gather over all rows is a scatter over
-    all rows)."""
-    def body(b, out):
-        _, tokens, rows, gates, _, up, down = _block_operands(b, x, gate, src, block_expert, w_up, w_down)
-        y = relu2(rows @ up) @ down
-        return out.at[tokens].add(y * gates[:, None].astype(y.dtype), mode="drop")
-
-    zeros = _zeros_varying_like(x.shape, x, gate, src, w_up, w_down).astype(x.dtype)
-    return lax.fori_loop(0, n_blocks, body, zeros)
-
-
-def _expert_blocks_fwd(x, gate, src, block_expert, n_blocks, w_up, w_down):
-    saved = (x, gate, src, block_expert, n_blocks, w_up, w_down)
-    return expert_blocks(*saved), saved
-
-
-def _expert_blocks_bwd(saved, d_out):
-    x, gate, src, block_expert, n_blocks, w_up, w_down = saved
-
-    def body(b, carry):
-        dx, d_gate, d_up, d_down = carry
-        picks, tokens, rows, gates, expert, up, down = _block_operands(
-            b, x, gate, src, block_expert, w_up, w_down)
-        pre = jax.nn.relu(rows @ up)
-        hidden = jnp.square(pre)
-        dy = d_out.at[tokens].get(mode="fill", fill_value=0)
-        d_gate = d_gate.at[picks].set(
-            jnp.sum((hidden @ down).astype(_F32) * dy.astype(_F32), axis=-1), mode="drop")
-        dy = dy * gates[:, None].astype(dy.dtype)
-        d_pre = (dy @ down.T) * 2 * pre
-        dx = dx.at[tokens].add(d_pre @ up.T, mode="drop")
-        add = lambda acc, term: lax.dynamic_update_index_in_dim(
-            acc, lax.dynamic_index_in_dim(acc, expert, keepdims=False) + term, expert, 0)
-        d_up = add(d_up, jnp.matmul(rows.T, d_pre, preferred_element_type=_F32))
-        d_down = add(d_down, jnp.matmul(hidden.T, dy, preferred_element_type=_F32))
-        return dx, d_gate, d_up, d_down
-
-    zeros = lambda like: _zeros_varying_like(like.shape, x, gate, src, d_out, w_up, w_down)
-    start = (zeros(x).astype(x.dtype), zeros(gate), zeros(w_up), zeros(w_down))
-    dx, d_gate, d_up, d_down = lax.fori_loop(0, n_blocks, body, start)
-    return (dx, d_gate.astype(gate.dtype), None, None, None,
-            d_up.astype(w_up.dtype), d_down.astype(w_down.dtype))
-
-
-expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
-
-
 def routed_experts(p: Params, x: jax.Array, cfg: dict):
     """The held experts' part of the routed output for tokens ``x`` [n, d], and the two
     counters.  ``p["w_up"]``/``p["w_down"]`` hold experts ``first_expert ..
-    first_expert + experts_held`` of the ``experts`` the router scores."""
-    n, d = x.shape
-    top_k, held = cfg["top_k"], cfg["experts_held"]
+    first_expert + experts_held`` of the ``experts`` the router scores.  Dispatch and the
+    block loop are the zoo's shared ones (``models.experts``), with the squared ReLU."""
     with jax.named_scope("moe_router"):
         picks, weights = route(p["router"], x, cfg)
-    with jax.named_scope("moe_dispatch"):
-        local = (picks - cfg["first_expert"]).reshape(n * top_k)
-        key = jnp.where((local >= 0) & (local < held), local, held)  # held: lands elsewhere
-        counts = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0, dtype=jnp.int32)
-        # Picks by expert, in pick order within an expert; then every expert's picks
-        # padded to whole blocks: row r of the layout is the rank-th pick of its expert.
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        padded = -(-counts // EXPERT_BLOCK) * EXPERT_BLOCK
-        ends = jnp.cumsum(padded)
-        rows = n * min(top_k, held) + held * EXPERT_BLOCK
-        rows = -(-rows // EXPERT_BLOCK) * EXPERT_BLOCK
-        block_expert = jnp.clip(jnp.searchsorted(
-            ends, jnp.arange(rows // EXPERT_BLOCK, dtype=jnp.int32) * EXPERT_BLOCK, side="right"),
-            0, held - 1).astype(jnp.int32)
-        r = jnp.arange(rows, dtype=jnp.int32)
-        expert = block_expert[r // EXPERT_BLOCK]
-        rank = r - (ends - padded)[expert]
-        taken = (rank < counts[expert]) & (r < ends[-1])
-        first_pick = jnp.cumsum(counts) - counts
-        src = jnp.where(taken, order[jnp.clip(first_pick[expert] + rank, 0, n * top_k - 1)],
-                        n * top_k)
-    with jax.named_scope("moe_experts"):
-        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert,
-                            ends[-1] // EXPERT_BLOCK, p["w_up"], p["w_down"])
-    landed = counts.sum().astype(_F32)
-    even = jnp.where(landed > 0, counts.max() * held / jnp.maximum(landed, 1.0), 1.0)
-    return out, jnp.stack([landed / (n * top_k), even])
+    out, counted = held_experts(x, picks, weights, p["w_up"], p["w_down"],
+                                first_expert=cfg["first_expert"], block=EXPERT_BLOCK,
+                                activation=RELU2)
+    return out, counted[:len(COUNTERS)]
 
 
 def expert_layer(p: Params, x: jax.Array, cfg: dict):

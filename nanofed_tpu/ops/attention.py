@@ -25,6 +25,15 @@ under the diagonal from them: ``dV`` and ``dK`` accumulate in the step's registe
 ``dQ`` in a float32 VMEM scratch that lives across the head's key blocks.  Five
 products a block pair, against the forward's two.
 
+Grouped queries (``k``, ``v`` of ``[N, H_kv, T, hd]``, ``H`` a multiple of ``H_kv``): query
+head ``h`` reads key/value head ``h // (H / H_kv)`` through the block index alone, so no
+copy of ``K`` or ``V`` is written and consecutive heads of a group find theirs in VMEM;
+the backward writes each query head's float32 ``dK``, ``dV`` and XLA sums a group's.
+A window (``window=W``: key ``s`` is seen from ``t`` only while ``t - W < s``): key blocks
+wholly behind the window are never visited, the one or two blocks its trailing edge cuts
+are masked, forward and backward (:func:`_window_steps`).  Both are static Python
+branches: with full heads and no window the kernels trace to the program they were.
+
 Precision: scores, softmax statistics and every accumulator are float32; the
 probabilities (and ``dS``) are cast to the inputs' dtype for the products that consume
 them, as a dense bfloat16 model's ``att`` is.  ``1/sqrt(hd)`` is folded into ``q`` where
@@ -60,6 +69,8 @@ MIN_SEQ = 512
 #: A head's whole ``K`` and ``V`` sit in VMEM: compiles for a v5e through 8192 positions
 #: of 128 (bfloat16), not at 16384.  Longer sequences want the keys streamed.
 MAX_SEQ = 8192
+#: Scoped VMEM the backward kernel may take where heads are grouped (a v5e has 128 MiB).
+GROUPED_BWD_VMEM = 32 * 1024 * 1024
 
 #: Masked scores: finite, so that ``exp(masked - max)`` is 0 and never ``inf - inf``.
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -93,21 +104,36 @@ def _struct(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _scores(k, q, *, scale, fold, masked):
+def _window_steps(window: int, block: int) -> tuple[int, int]:
+    """``(a, b)``: a query block ``i`` and a key block ``j <= i`` have every pair inside the
+    window while ``i - j < a``, some pair while ``i - j < b``; from ``b`` on none."""
+    return window // block, (window + block - 2) // block + 1
+
+
+def _scores(k, q, *, scale, fold, masked, behind=None, window=None):
     """A score block transposed, ``[keys, queries]`` float32, from a key block and a query
-    block whose first rows share a position.  ``masked`` is for the diagonal block:
-    inside it a key past its query gets ``_MASKED``."""
+    block.  ``masked`` is for the diagonal block, whose first rows share a position:
+    inside it a key past its query gets ``_MASKED``.  ``behind`` (with ``window``) is how
+    many positions the key block starts behind the query block: a key the window's
+    length or more behind its query gets ``_MASKED`` too."""
     s = lax.dot_general(k, q, _NT, preferred_element_type=_F32)
     if not fold:
         s = s * scale
-    if masked:
+    if masked or behind is not None:
         ki = lax.broadcasted_iota(jnp.int32, s.shape, 0)
         qi = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(ki <= qi, s, _MASKED)
+        if masked:
+            seen = ki <= qi
+            if behind is not None:
+                seen &= qi - ki < window
+        else:
+            seen = qi - ki < window - behind
+        s = jnp.where(seen, s, _MASKED)
     return s
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fold):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fold,
+                window=None):
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -119,12 +145,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
         q = q * scale
     hd = q.shape[-1]
 
-    def step(j, carry, masked):
+    def step(j, carry, masked, behind=None):
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(j * block, block), block)
         # Key down the sublanes, query along the lanes: the softmax's reductions run
         # down the sublanes, vreg against vreg.
-        s = _scores(k_ref[rows, :], q, scale=scale, fold=fold, masked=masked)
+        s = _scores(k_ref[rows, :], q, scale=scale, fold=fold, masked=masked,
+                    behind=behind, window=window)
         m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -135,14 +162,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
 
     carry = (jnp.full((1, block), _MASKED, _F32), jnp.zeros((1, block), _F32),
              jnp.zeros((hd, block), _F32))
-    carry = lax.fori_loop(0, i, lambda j, c: step(j, c, masked=False), carry)
-    m, l, acc = step(i, carry, masked=True)
+    if window is None:
+        carry = lax.fori_loop(0, i, lambda j, c: step(j, c, masked=False), carry)
+        m, l, acc = step(i, carry, masked=True)
+    else:
+        # Key blocks the window's trailing edge cuts, then those wholly inside it, then
+        # the diagonal (which the window cuts too where it is shorter than a block).
+        a, b = _window_steps(window, block)
+        first = jnp.maximum(i - b + 1, 0)
+        whole = jnp.clip(i - a + 1, first, i)
+        carry = lax.fori_loop(
+            first, whole, lambda j, c: step(j, c, masked=False, behind=(i - j) * block), carry)
+        carry = lax.fori_loop(whole, i, lambda j, c: step(j, c, masked=False), carry)
+        m, l, acc = step(i, carry, masked=True, behind=0 if a == 0 else None)
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     lse_ref[...] = m + jnp.log(l)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, kt_ref, *, block, scale, fold):
+                dq_ref, dk_ref, dv_ref, dq_acc, kt_ref, *, block, scale, fold, window=None):
     j = pl.program_id(1)
     n_blocks = pl.num_programs(1)
 
@@ -154,14 +192,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     v = v_ref[...]
     kt_ref[...] = k.T  # K's block with its rows along the lanes, for dQ^T = K^T dS^T
 
-    def pair(i, carry, masked):
+    def pair(i, carry, masked, behind=None):
         dk, dv = carry
         rows = pl.ds(pl.multiple_of(i * block, block), block)
         q = q_ref[rows, :]
         if fold:
             q = q * scale
         do = do_ref[rows, :]
-        s = _scores(k, q, scale=scale, fold=fold, masked=masked)
+        s = _scores(k, q, scale=scale, fold=fold, masked=masked, behind=behind, window=window)
         p = jnp.exp(s - lse_ref[i])
         dp = lax.dot_general(v, do, _NT, preferred_element_type=_F32)
         ds = (p * (dp - delta_ref[i])).astype(q.dtype)
@@ -171,8 +209,18 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk, dv
 
     zeros = jnp.zeros(k.shape, _F32)
-    carry = pair(j, (zeros, zeros), masked=True)
-    dk, dv = lax.fori_loop(j + 1, n_blocks, lambda i, c: pair(i, c, masked=False), carry)
+    if window is None:
+        carry = pair(j, (zeros, zeros), masked=True)
+        dk, dv = lax.fori_loop(j + 1, n_blocks, lambda i, c: pair(i, c, masked=False), carry)
+    else:
+        # The forward's three kinds of block pair, seen from the key block.
+        a, b = _window_steps(window, block)
+        carry = pair(j, (zeros, zeros), masked=True, behind=0 if a == 0 else None)
+        cut = jnp.clip(j + a, j + 1, n_blocks)
+        carry = lax.fori_loop(j + 1, cut, lambda i, c: pair(i, c, masked=False), carry)
+        dk, dv = lax.fori_loop(
+            cut, jnp.minimum(j + b, n_blocks),
+            lambda i, c: pair(i, c, masked=False, behind=(i - j) * block), carry)
     dk_ref[...] = (dk if fold else dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -181,15 +229,25 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _forward(q, k, v, block, interpret):
-    """``q, k, v`` [B, T, hd] -> output *transposed* [B, hd, T], log-sum-exp
-    [B, T/block, 1, block]."""
+def _kernel_options(q, k, window):
+    """``(query heads a key/value head, the kernels' window argument, what a windowed
+    kernel's name ends in)``: static, and ``(1, {}, "")`` for full heads and no window,
+    which leaves the calls as they were."""
+    group = q.shape[0] // k.shape[0]
+    return group, ({} if window is None else {"window": window}), "" if window is None else "_window"
+
+
+def _forward(q, k, v, block, interpret, window=None):
+    """``q`` [B, T, hd], ``k, v`` [B / group, T, hd] -> output *transposed* [B, hd, T],
+    log-sum-exp [B, T/block, 1, block]."""
     b, t, hd = q.shape
     n_blocks = t // block
     scale, fold = _scale(hd)
-    head = pl.BlockSpec((None, t, hd), lambda h, i: (h, 0, 0))
+    group, windowed, kind = _kernel_options(q, k, window)
+    head = pl.BlockSpec((None, t, hd), (lambda h, i: (h, 0, 0)) if group == 1
+                        else (lambda h, i: (h // group, 0, 0)))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold),
+        functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold, **windowed),
         grid=(b, n_blocks),
         in_specs=[pl.BlockSpec((None, block, hd), lambda h, i: (h, i, 0)), head, head],
         out_specs=[pl.BlockSpec((None, hd, block), lambda h, i: (h, 0, i)),
@@ -200,64 +258,79 @@ def _forward(q, k, v, block, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="causal_attention_fwd",
+        name="causal_attention_fwd" + kind,
     )(q, k, v)
 
 
-def _backward(q, k, v, do, lse, delta, block, interpret):
-    """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk``, ``dv`` [B, T, hd]."""
+def _backward(q, k, v, do, lse, delta, block, interpret, window=None):
+    """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk``, ``dv`` [B, T, hd] — one a QUERY
+    head, in float32, where heads are grouped: the caller sums a group's."""
     b, t, hd = q.shape
     n_blocks = t // block
     scale, fold = _scale(hd)
+    group, windowed, kind = _kernel_options(q, k, window)
     head = pl.BlockSpec((None, t, hd), lambda h, j: (h, 0, 0))
     rows = pl.BlockSpec((None, block, hd), lambda h, j: (h, j, 0))
+    kv_rows = rows if group == 1 else pl.BlockSpec((None, block, hd), lambda h, j: (h // group, j, 0))
     stats = pl.BlockSpec((None, n_blocks, 1, block), lambda h, j: (h, 0, 0, 0))
     like = (q, k, v, do, lse, delta)
+    dk_dtype, dv_dtype = (k.dtype, v.dtype) if group == 1 else (_F32, _F32)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold),
+        functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold, **windowed),
         grid=(b, n_blocks),
-        in_specs=[head, rows, rows, head, stats, stats],
+        in_specs=[head, kv_rows, kv_rows, head, stats, stats],
         out_specs=[pl.BlockSpec((None, hd, t), lambda h, j: (h, 0, 0)), rows, rows],
-        out_shape=[_struct((b, hd, t), q.dtype, *like), _struct(k.shape, k.dtype, *like),
-                   _struct(v.shape, v.dtype, *like)],
+        out_shape=[_struct((b, hd, t), q.dtype, *like), _struct(q.shape, dk_dtype, *like),
+                   _struct(q.shape, dv_dtype, *like)],
         scratch_shapes=[pltpu.VMEM((hd, t), _F32), pltpu.VMEM((hd, block), k.dtype)],
+        # A head's q, dO and float32 dQ fill the default 16 MiB at 8192 positions of 128;
+        # a group's float32 dK, dV blocks pass it by 1.25 MiB.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            **({} if group == 1 else {"vmem_limit_bytes": GROUPED_BWD_VMEM})),
         interpret=interpret,
-        name="causal_attention_bwd",
+        name="causal_attention_bwd" + kind,
     )(q, k, v, do, lse, delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attend(q, k, v, block, interpret):
-    return _attend_fwd(q, k, v, block, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attend(q, k, v, block, interpret, window):
+    return _attend_fwd(q, k, v, block, interpret, window)[0]
 
 
-def _attend_fwd(q, k, v, block, interpret):
-    o_t, lse = _forward(q, k, v, block, interpret)
+def _attend_fwd(q, k, v, block, interpret, window):
+    o_t, lse = _forward(q, k, v, block, interpret, window)
     o = jnp.swapaxes(o_t, 1, 2)
     return o, (q, k, v, o, lse)
 
 
-def _attend_bwd(block, interpret, saved, do):
+def _attend_bwd(block, interpret, window, saved, do):
     q, k, v, o, lse = saved
     delta = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1).reshape(lse.shape)
-    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret)
+    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret, window)
+    if k.shape != q.shape:  # a group's query heads each wrote their own share
+        shared = lambda d, like: d.reshape(like.shape[0], -1, *like.shape[1:]).sum(1).astype(like.dtype)
+        dk, dv = shared(dk, k), shared(dv, v)
     return jnp.swapaxes(dq_t, 1, 2), dk, dv
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           window: int | None = None) -> jax.Array:
     """The same function spelled densely, in the inputs' dtype throughout: ``[N, H, T, T]``
     scores, mask, softmax.  What short sequences run, and what the kernels are tested
-    against."""
+    against.  Grouped ``k``/``v`` (``[N, H_kv, T, hd]``) are repeated to the query heads."""
     t, hd = q.shape[-2:]
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
     scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / math.sqrt(hd)
     # Causal mask: position q attends to keys <= q only.  Additive -inf keeps the
     # softmax exact for the allowed band.
     causal = jnp.tril(jnp.ones((t, t), bool))
+    if window is not None:  # ... and to keys less than ``window`` positions behind it
+        causal &= ~jnp.tril(jnp.ones((t, t), bool), -window)
     scores = jnp.where(causal[None, None], scores, jnp.finfo(scores.dtype).min)
     att = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("nhqk,nhkd->nhqd", att, v)
@@ -268,26 +341,35 @@ def causal_attention(
     k: jax.Array,
     v: jax.Array,
     *,
+    window: int | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Causal attention ``[N, H, T, hd] x 3 -> [N, H, T, hd]``, scale ``1/sqrt(hd)``,
     differentiable in all three; ``T`` whole blocks of ``block`` (default:
-    :func:`block_for`).
+    :func:`block_for`).  ``k`` and ``v`` may hold fewer heads, ``[N, H_kv, T, hd]`` with
+    ``H`` a multiple of ``H_kv``: query head ``h`` reads head ``h // (H / H_kv)``.  With
+    ``window``, position ``t`` attends to keys ``t - window < s <= t`` only.
 
     Off the TPU the kernels run in Pallas's interpreter, which cannot evaluate a kernel
     on values that vary over a ``shard_map`` axis under its varying-axes check (the
     kernel's own constants do not vary); there, and only there, the dense spelling
     answers."""
     n, h, t, hd = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
-        raise ValueError(f"q, k, v must share a shape: {q.shape}, {k.shape}, {v.shape}")
+    h_kv = k.shape[1]
+    if k.shape != v.shape or k.shape != (n, h_kv, t, hd) or h_kv == 0 or h % h_kv:
+        raise ValueError("k and v must share a shape, q's but for heads that divide q's: "
+                         f"{q.shape}, {k.shape}, {v.shape}")
+    if window is not None and window < 1:
+        raise ValueError(f"window={window}: a position sees itself at least")
     block = block_for(t) if block is None else block
     if block is None or t % block or block % 128:
         raise ValueError(f"T={t} is not whole blocks of {block or BLOCKS} (multiples of 128)")
+    if window is not None and window >= t:
+        window = None  # no key is that far behind
     interpret = auto_interpret(interpret)
     if interpret and any(jax.typeof(a).vma for a in (q, k, v)):
-        return dense_causal_attention(q, k, v)
-    flat = lambda a: a.reshape(n * h, t, hd)
-    out = _attend(flat(q), flat(k), flat(v), block, interpret)
+        return dense_causal_attention(q, k, v, window=window)
+    flat = lambda a: a.reshape(-1, t, hd)
+    out = _attend(flat(q), flat(k), flat(v), block, interpret, window)
     return out.reshape(n, h, t, hd)
