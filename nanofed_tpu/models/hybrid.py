@@ -50,6 +50,7 @@ from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
 from nanofed_tpu.models.experts import RELU2, held_experts
+from nanofed_tpu.nn import embed_rows
 
 #: Rows a block of the expert loop holds: a held expert's picks are padded to whole
 #: blocks, so a block multiplies one expert's matrices.  A block's cost is mostly fixed
@@ -301,7 +302,7 @@ _MIXERS = {"M": ("mamba", _counting_nothing(mamba_mixer)),
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the ``E`` layers)."""
-    x = params["embed"][tokens.astype(jnp.int32)]
+    x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     seen = dict.fromkeys(_MIXERS, 0)
     counters = jnp.zeros((len(COUNTERS),), _F32)
     for letter in cfg["pattern"]:

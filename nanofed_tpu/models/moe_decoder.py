@@ -45,6 +45,7 @@ from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS, REGLU, held_experts
 from nanofed_tpu.models.hybrid import rms_norm
+from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
 #: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
@@ -144,7 +145,7 @@ def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, rope: bool, window: int
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the layers)."""
-    x = params["embed"][tokens.astype(jnp.int32)]
+    x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     counters = jnp.zeros((len(COUNTERS),), _F32)
     for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
         layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),

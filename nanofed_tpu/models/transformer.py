@@ -222,7 +222,7 @@ def _trunk(params: Params, tokens: jax.Array, heads: int) -> jax.Array:
     state ``[N, T, D]`` the final LayerNorm and the head read."""
     tokens = tokens.astype(jnp.int32)
     t = tokens.shape[1]
-    x = params["tok_emb"][tokens] + params["pos_emb"][None, :t]
+    x = nn.embed_rows(params["tok_emb"], tokens) + params["pos_emb"][None, :t]
 
     def block(x, blk):
         x = x + _attention(blk["attn"], _layer_norm(blk["ln1"], x), heads)

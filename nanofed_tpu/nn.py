@@ -14,6 +14,7 @@ so GroupNorm is the standard choice in FL (cf. FedProx/LEAF practice).
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Sequence
 
 import jax
@@ -64,6 +65,77 @@ def dense_init(rng: PRNGKey, in_features: int, out_features: int, dtype=jnp.floa
 
 def dense(params: Params, x: jax.Array) -> jax.Array:
     return x @ params["kernel"] + params["bias"]
+
+
+# ---------------------------------------------------------------------------
+# Embedding lookup
+# ---------------------------------------------------------------------------
+
+#: Most bytes one accumulator of the lookup's gradient may hold, in the table's dtype.
+#: The gradient of ``table[tokens]`` is a scatter-add of the cotangent's rows into zeros
+#: of the table's shape, and XLA's TPU scatter is fast where memory-space assignment
+#: places that accumulator in the chip's on-chip memory (128 MiB on a v5e).  84 MiB is the
+#: largest accumulator measured whole at the fast rate: the hybrid's ``[16384, 2688]``
+#: bfloat16 slice takes 0.43 us a row, GPT-2's 74 MiB 0.30, while SmallThinker's 185 MiB
+#: stays in HBM and takes 2.0 (PERF.md section 6, PR 32; the float32 convert included).
+#: Its two bands of 93 MiB are still placed on chip, but four of 46 MiB ran 0.7 ms a step
+#: faster (``tests/unit/ops/test_attention_aot.py`` compiles both).
+EMBED_BAND_BYTES = 84 * 2**20
+
+_LANES = 128  # a band is whole lane tiles of the table's minor axis
+
+
+def embed_bands(rows: int, width: int, itemsize: int) -> int:
+    """Column bands the gradient of a ``[rows, width]`` lookup accumulates in: the
+    fewest whole-tile bands whose accumulator fits :data:`EMBED_BAND_BYTES`, the finest
+    whole-tile split where none does, 1 where the width is not whole tiles."""
+    tiles = width // _LANES if width % _LANES == 0 else 1
+    splits = [k for k in range(1, tiles + 1) if tiles % k == 0]
+    return next((k for k in splits if rows * (width // k) * itemsize <= EMBED_BAND_BYTES),
+                splits[-1])
+
+
+def embed_rows(table: jax.Array, tokens: jax.Array) -> jax.Array:
+    """``table[tokens]``: ``[V, D]`` and int ``[...]`` -> ``[..., D]``.
+
+    Where the table's gradient fits one accumulator (:func:`embed_bands` says 1) this IS
+    the indexing expression, autodiff's scatter-add and all.  A larger table gets the
+    same gradient one column band at a time (:func:`_rows_in_bands`); the shape decides,
+    as ``ops.attention.engages`` does by sequence length."""
+    bands = embed_bands(*table.shape, table.dtype.itemsize)
+    return table[tokens] if bands == 1 else _rows_in_bands(table, tokens, bands)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_in_bands(table, tokens, bands):
+    return table[tokens]
+
+
+def _rows_in_bands_fwd(table, tokens, bands):
+    # The table's rows, dtype and mesh type ride on an empty slice: the table is not kept.
+    return table[tokens], (tokens, table[:, :0])
+
+
+def _rows_in_bands_bwd(bands, saved, g):
+    """The transpose of the lookup, band by band: each band's scatter-add (autodiff's own,
+    on ``width / bands`` columns: same dtype, same sums over a row's duplicates) is
+    finished and stored before the next begins, so one accumulator is live at a time."""
+    tokens, like = saved
+    band = g.shape[-1] // bands
+    # A band of the table, typed as the table is: inside ``shard_map`` its cotangent then
+    # varies over the mesh axes the table varies over, as the custom rule's output must.
+    accumulate = jax.linear_transpose(
+        lambda t: t[tokens], jnp.zeros_like(like, shape=(like.shape[0], band)))
+
+    def one_band(_, b):
+        return None, accumulate(lax.dynamic_slice_in_dim(g, b * band, band, axis=-1))[0]
+
+    with jax.named_scope("embed_grad"):
+        _, stacked = lax.scan(one_band, None, jnp.arange(bands))
+        return jnp.moveaxis(stacked, 0, -2).reshape(like.shape[0], -1), None
+
+
+_rows_in_bands.defvjp(_rows_in_bands_fwd, _rows_in_bands_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData, ClientMetrics
 from nanofed_tpu.models import get_model, hybrid
 from nanofed_tpu.parallel.mesh import MODEL_AXIS, make_mesh, param_partition_spec
@@ -81,6 +82,20 @@ def test_gradients_match_the_reference_leaf_by_leaf(reference, seeded, expert_bl
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         gap = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
         assert gap < 1e-4, (jax.tree_util.keystr(path), gap)
+
+
+def test_gradient_is_the_same_with_the_embedding_gradient_in_bands(reference, monkeypatch):
+    """At a width of two lane tiles and a budget of one, ``nn.embed_rows`` accumulates the
+    table's gradient band by band: every leaf's gradient is what one band gives."""
+    kw = {**SMALL, "width": 256}
+    params = reference.init_params(jax.random.key(0), kw)
+    tokens = jax.random.randint(jax.random.key(1), (3, kw["seq_len"]), 0, 9)
+    model = get_model("hybrid_lm", **kw)
+    grads = lambda: jax.grad(lambda p: model.apply(p, tokens)[:, 5].sum())(params)
+    whole = grads()
+    monkeypatch.setattr(nn, "EMBED_BAND_BYTES", kw["vocab"] * 128 * 4)
+    assert nn.embed_bands(kw["vocab"], 256, 4) == 2
+    jax.tree.map(np.testing.assert_array_equal, grads(), whole)
 
 
 def _scan_inputs(t, key=2):

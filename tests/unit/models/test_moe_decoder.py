@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.models import experts, get_model, hybrid, moe_decoder
@@ -118,6 +119,19 @@ def test_log_probs_and_gradients_match_the_reference_in_float32(reference, exper
     np.testing.assert_allclose(got, reference.log_probs(params, tokens, None, SMALL), atol=1e-5)
     leaf, gap = _worst_gradient_gap(model, reference, SMALL, params, tokens)
     assert gap < 1e-4, (leaf, gap)
+
+
+def test_gradient_is_the_same_with_the_embedding_gradient_in_bands(reference, monkeypatch):
+    """At a width of two lane tiles and a budget of one, ``nn.embed_rows`` accumulates the
+    table's gradient band by band: every leaf's gradient is what one band gives."""
+    kw = {**SMALL, "width": 256}
+    params, tokens = _seeded(reference, kw)
+    model = get_model("moe_decoder_lm", **kw)
+    grads = lambda: jax.grad(lambda p: model.apply(p, tokens)[:, 5].sum())(params)
+    whole = grads()
+    monkeypatch.setattr(nn, "EMBED_BAND_BYTES", kw["vocab"] * 128 * 4)
+    assert nn.embed_bands(kw["vocab"], 256, 4) == 2
+    jax.tree.map(np.testing.assert_array_equal, grads(), whole)
 
 
 def test_bfloat16_compute_stays_near_the_float32_reference(reference):

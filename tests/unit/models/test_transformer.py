@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from nanofed_tpu import nn
 from nanofed_tpu.data import synthetic_token_streams
 from nanofed_tpu.models import get_model
 from nanofed_tpu.models.transformer import (
@@ -625,6 +626,20 @@ class TestScanLayers:
         assert n == transformer_param_count(vocab, seq_len, width, depth)
         # the stacked subtree exists with leading depth dim
         assert abs_p["blocks"]["attn"]["wq"]["kernel"].shape[0] == depth
+
+
+@pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
+def test_gradient_is_the_same_with_the_embedding_gradient_in_bands(name, monkeypatch):
+    """At a width of two lane tiles and a budget of one, ``nn.embed_rows`` accumulates the
+    table's gradient band by band: every leaf's gradient is what one band gives."""
+    m = get_model(name, vocab=VOCAB, seq_len=SEQ, width=256, depth=DEPTH, heads=HEADS)
+    p = m.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (3, SEQ), 0, 5)  # rows drawn many times
+    grads = lambda: jax.grad(lambda q: m.apply(q, tokens)[:, 5].sum())(p)
+    whole = grads()
+    monkeypatch.setattr(nn, "EMBED_BAND_BYTES", VOCAB * 128 * 4)
+    assert nn.embed_bands(VOCAB, 256, 4) == 2
+    jax.tree.map(np.testing.assert_array_equal, grads(), whole)
 
 
 def test_grad_fn_keeps_integer_inputs_integer(model, params):

@@ -3,7 +3,8 @@ GPT-2 cell's shapes: what the TPU's compiler refuses (a tiling, a VMEM budget, a
 transpose it cannot place) fails here, at no chip time.  Nothing runs: this says nothing
 about values or times.  All such compiles live in this one file (one worker loads the
 TPU's library, inside the fixture): the cell's training step is here too, for what the
-compiler keeps of the MLP between its forward and its backward."""
+compiler keeps of the MLP between its forward and its backward, and the SmallThinker
+cell's embedding gradient, for where the compiler places its accumulators."""
 
 import re
 
@@ -133,3 +134,44 @@ def test_backward_reruns_no_product(compiled_steps):
 def test_temporaries_fall_by_a_gigabyte(compiled_steps):
     temp = {k: c.memory_analysis().temp_size_in_bytes for k, c in compiled_steps.items()}
     assert temp["stood"] - temp["now"] >= 1e9, temp
+
+
+EMBED = (37984, 2560, 8192)  # smallthinker-21b-4l-xsilo-4: rows held, width, tokens a step
+
+
+def _embed_gradient_text(one_chip) -> str:
+    rows, width, tokens = EMBED
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(table, ids, weight):
+        return (nn.embed_rows(table, ids) * weight).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss)).lower(
+        shaped((rows, width), jnp.bfloat16), shaped((tokens,), jnp.int32),
+        shaped((tokens, width), jnp.bfloat16)).compile().as_text()
+
+
+def _scatter_results(text: str) -> list[str]:
+    """Shape and layout of every scatter's result, as ``bf16[37984,1280]{1,0:T(8,128)(2,1)S(1)}``."""
+    return re.findall(r"= (\w+\[[\d,]*\]\{[^}]*\}) scatter\(", text)
+
+
+@pytest.mark.parametrize("budget,bands", [(nn.EMBED_BAND_BYTES, 4), (96 * 2**20, 2)],
+                         ids=["the-budget", "96MiB"])
+def test_embedding_gradient_accumulates_on_chip(one_chip, monkeypatch, budget, bands):
+    """What ``nn.embed_rows`` is for: each band's accumulator is placed in the chip's
+    on-chip memory (memory space 1), and no scatter accumulates into the whole table."""
+    monkeypatch.setattr(nn, "EMBED_BAND_BYTES", budget)
+    assert nn.embed_bands(*EMBED[:2], 2) == bands
+    results = _scatter_results(_embed_gradient_text(one_chip))
+    band = f"bf16[{EMBED[0]},{EMBED[1] // bands}]"
+    assert results and all(r.startswith(band) and "S(1)" in r for r in results), results
+
+
+def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
+    """The reason for the bands: at one band the 194 MB accumulator gets no ``S(1)``.  If
+    this fails the compiler places it on chip now, and the bands may no longer be needed."""
+    monkeypatch.setattr(nn, "EMBED_BAND_BYTES", 2**40)
+    results = _scatter_results(_embed_gradient_text(one_chip))
+    whole = f"bf16[{EMBED[0]},{EMBED[1]}]"
+    assert results and all(r.startswith(whole) and "S(1)" not in r for r in results), results
