@@ -3,6 +3,7 @@ the ResNets serve the BASELINE.json benchmark configs)."""
 
 from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
     hybrid,
+    latent_moe,
     linear,
     mnist,
     moe_decoder,
@@ -11,6 +12,7 @@ from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
 )
 from nanofed_tpu.models.base import Model, get_model, list_models, register_model
 from nanofed_tpu.models.hybrid import hybrid_lm
+from nanofed_tpu.models.latent_moe import latent_moe_lm
 from nanofed_tpu.models.mnist import mnist_cnn
 from nanofed_tpu.models.moe_decoder import moe_decoder_lm
 from nanofed_tpu.models.resnet import resnet8, resnet18
@@ -27,6 +29,7 @@ __all__ = [
     "list_models",
     "register_model",
     "hybrid_lm",
+    "latent_moe_lm",
     "mnist_cnn",
     "moe_decoder_lm",
     "resnet8",

@@ -1,5 +1,6 @@
 """The held experts of a mixture-of-experts layer: dispatch and the block loop, shared
-by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``).
+by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``,
+``models.latent_moe``), and the sigmoid router two of them share (:func:`sigmoid_route`).
 
 A layer is TOLD which experts it holds (``first_expert``, ``held``) of the ``experts``
 its router scores.  The model routes — its own scores, its own normalisation — and hands
@@ -13,7 +14,8 @@ layer (tests).
 
 An expert is ``W_out act(W_in x)``; ``act`` is an :class:`Activation`, a parameter of
 the loop and of its hand-written backward: :data:`RELU2` on ``[rows, f]``, :data:`REGLU`
-on a fused ``[rows, 2f]`` product (``W_gate | W_up`` stored as one ``[d, 2f]`` leaf).
+and :data:`SWIGLU` on a fused ``[rows, 2f]`` product (``W_gate | W_up`` stored as one
+``[d, 2f]`` leaf).
 """
 
 from __future__ import annotations
@@ -59,10 +61,43 @@ def _reglu_with_grad(pre):
         [jnp.where(gate > 0, d_hidden * up, 0), d_hidden * kept], axis=-1)
 
 
+def _swiglu(pre):
+    f = pre.shape[-1] // 2
+    gate = pre[..., :f]
+    return gate * jax.nn.sigmoid(gate) * pre[..., f:]
+
+
+def _swiglu_with_grad(pre):
+    f = pre.shape[-1] // 2
+    gate, up = pre[..., :f], pre[..., f:]
+    sig = jax.nn.sigmoid(gate)
+    kept = gate * sig  # silu(gate); its derivative is sig + kept (1 - sig)
+    return kept * up, lambda d_hidden: jnp.concatenate(
+        [d_hidden * up * (sig + kept * (1 - sig)), d_hidden * kept], axis=-1)
+
+
 #: ``relu(pre)^2``.
 RELU2 = Activation(lambda pre: jnp.square(jax.nn.relu(pre)), _relu2_with_grad)
 #: ``relu(gate) * up`` of ``pre = [gate | up]``: the product is twice the hidden width.
 REGLU = Activation(_reglu, _reglu_with_grad)
+#: ``silu(gate) * up`` of ``pre = [gate | up]``.
+SWIGLU = Activation(_swiglu, _swiglu_with_grad)
+
+
+def sigmoid_route(router, x, top_k: int, scale: float, bias=None):
+    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL the experts the
+    router scores: sigmoid scores in float32, the ``top_k`` largest, normalised over the
+    picks and scaled.  A selection ``bias`` [experts] is added to the scores for the
+    PICKING alone: the weights are the picked experts' scores without it, and it takes
+    no gradient (its balancing update is a rule of its own, outside the loss)."""
+    scores = jax.nn.sigmoid(jnp.matmul(x.astype(_F32), router.astype(_F32),
+                                       precision=lax.Precision.HIGHEST))
+    if bias is None:
+        top, picks = lax.top_k(scores, top_k)
+    else:
+        _, picks = lax.top_k(scores + lax.stop_gradient(bias.astype(_F32)), top_k)
+        top = jnp.take_along_axis(scores, picks, axis=-1)
+    return picks, scale * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
 
 
 def _zeros_varying_like(shape, *like):

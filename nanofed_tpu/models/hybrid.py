@@ -49,7 +49,7 @@ from jax import lax
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
-from nanofed_tpu.models.experts import RELU2, held_experts
+from nanofed_tpu.models.experts import RELU2, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
 
 #: Rows a block of the expert loop holds: a held expert's picks are padded to whole
@@ -254,13 +254,11 @@ def gqa_attention(p: Params, x: jax.Array, cfg: dict) -> jax.Array:
 
 
 def route(router: jax.Array, x: jax.Array, cfg: dict):
-    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL ``experts``:
-    sigmoid scores in float32, the ``top_k`` largest, normalised over the picks and
-    scaled.  (The published router adds a balancing bias before picking; it is zero.)"""
-    scores = jax.nn.sigmoid(jnp.matmul(x.astype(_F32), router.astype(_F32),
-                                       precision=lax.Precision.HIGHEST))
-    top, picks = lax.top_k(scores, cfg["top_k"])
-    return picks, cfg["routed_scale"] * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
+    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL ``experts``: the
+    zoo's sigmoid router (``experts.sigmoid_route``) with no selection bias.  (The
+    published router adds a balancing bias before picking; this model has no such leaf,
+    which is a bias of zero.  ``latent_moe`` carries one.)"""
+    return sigmoid_route(router, x, cfg["top_k"], cfg["routed_scale"])
 
 
 def routed_experts(p: Params, x: jax.Array, cfg: dict):

@@ -3,6 +3,8 @@
 ``causal_attention(q, k, v)`` over ``[N, H, T, hd]`` is ``softmax(q k^T / sqrt(hd)) v``
 with position ``t`` attending to keys ``<= t``: what the dense ``einsum`` / ``where`` /
 ``softmax`` spelling computes, without any ``[N, H, T, T]`` array in HBM on either pass.
+``v`` may have a head size of its own (``[N, H_kv, T, hd_v]``, latent attention's 192-wide
+scores over 128-wide values): the output is ``hd_v`` wide and the scale stays ``q``'s.
 
 Both kernels keep a score block *transposed*, keys down the sublanes and queries along
 the lanes: the softmax's maximum and sum then run down the sublanes, vreg against vreg on
@@ -31,8 +33,12 @@ copy of ``K`` or ``V`` is written and consecutive heads of a group find theirs i
 the backward writes each query head's float32 ``dK``, ``dV`` and XLA sums a group's.
 A window (``window=W``: key ``s`` is seen from ``t`` only while ``t - W < s``): key blocks
 wholly behind the window are never visited, the one or two blocks its trailing edge cuts
-are masked, forward and backward (:func:`_window_steps`).  Both are static Python
-branches: with full heads and no window the kernels trace to the program they were.
+are masked, forward and backward (:func:`_window_steps`).  A value head of another size
+(``hd_v != hd``): the forward's ``V`` scratch, accumulator and output are ``hd_v`` wide,
+``q`` and ``K`` ``hd``; of the backward's five products three run over ``hd`` (scores,
+``dK``, ``dQ``) and two over ``hd_v`` (``dP``, ``dV``); no column is padded.  All three
+are static Python branches: with full heads, no window and one head size the kernels
+trace to the program they were.
 
 Precision: scores, softmax statistics and every accumulator are float32; the
 probabilities (and ``dS``) are cast to the inputs' dtype for the products that consume
@@ -67,10 +73,15 @@ BLOCKS = (512, 256)
 #: Below this a ``[T, T]`` score tile is VMEM-sized traffic for XLA too.
 MIN_SEQ = 512
 #: A head's whole ``K`` and ``V`` sit in VMEM: compiles for a v5e through 8192 positions
-#: of 128 (bfloat16), not at 16384.  Longer sequences want the keys streamed.
+#: of 128 (bfloat16), not at 16384; and through 8192 positions of 192-wide ``K`` over
+#: 128-wide ``V`` under :data:`WIDE_VMEM` (the default 16 MiB and 32 MiB refuse both
+#: kernels there: a 192-wide row is padded to 256 lanes).  Longer sequences want the keys
+#: streamed.
 MAX_SEQ = 8192
 #: Scoped VMEM the backward kernel may take where heads are grouped (a v5e has 128 MiB).
 GROUPED_BWD_VMEM = 32 * 1024 * 1024
+#: ... and both kernels where a score head is wider than 128.
+WIDE_VMEM = 48 * 1024 * 1024
 
 #: Masked scores: finite, so that ``exp(masked - max)`` is 0 and never ``inf - inf``.
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -143,7 +154,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
     q = q_ref[...]
     if fold:
         q = q * scale
-    hd = q.shape[-1]
+    hd_v = vt_ref.shape[0]
 
     def step(j, carry, masked, behind=None):
         m, l, acc = carry
@@ -161,7 +172,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
         return m_new, l, acc
 
     carry = (jnp.full((1, block), _MASKED, _F32), jnp.zeros((1, block), _F32),
-             jnp.zeros((hd, block), _F32))
+             jnp.zeros((hd_v, block), _F32))
     if window is None:
         carry = lax.fori_loop(0, i, lambda j, c: step(j, c, masked=False), carry)
         m, l, acc = step(i, carry, masked=True)
@@ -209,13 +220,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk, dv
 
     zeros = jnp.zeros(k.shape, _F32)
+    start = (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, _F32))
     if window is None:
-        carry = pair(j, (zeros, zeros), masked=True)
+        carry = pair(j, start, masked=True)
         dk, dv = lax.fori_loop(j + 1, n_blocks, lambda i, c: pair(i, c, masked=False), carry)
     else:
         # The forward's three kinds of block pair, seen from the key block.
         a, b = _window_steps(window, block)
-        carry = pair(j, (zeros, zeros), masked=True, behind=0 if a == 0 else None)
+        carry = pair(j, start, masked=True, behind=0 if a == 0 else None)
         cut = jnp.clip(j + a, j + 1, n_blocks)
         carry = lax.fori_loop(j + 1, cut, lambda i, c: pair(i, c, masked=False), carry)
         dk, dv = lax.fori_loop(
@@ -237,57 +249,67 @@ def _kernel_options(q, k, window):
     return group, ({} if window is None else {"window": window}), "" if window is None else "_window"
 
 
+def _vmem(hd: int, group: int, backward: bool) -> dict:
+    """The kernels' scoped-VMEM limit where the default 16 MiB does not hold a head: a
+    head's ``q``, ``dO`` and float32 ``dQ`` fill it at 8192 positions of 128, and a
+    group's float32 ``dK``, ``dV`` blocks pass it by 1.25 MiB; score heads wider than a
+    lane tile (192: rows padded to 256 lanes) pass it on both passes."""
+    if hd > 128:
+        return {"vmem_limit_bytes": WIDE_VMEM}
+    return {"vmem_limit_bytes": GROUPED_BWD_VMEM} if backward and group != 1 else {}
+
+
 def _forward(q, k, v, block, interpret, window=None):
-    """``q`` [B, T, hd], ``k, v`` [B / group, T, hd] -> output *transposed* [B, hd, T],
-    log-sum-exp [B, T/block, 1, block]."""
+    """``q`` [B, T, hd], ``k`` [B / group, T, hd], ``v`` [B / group, T, hd_v] -> output
+    *transposed* [B, hd_v, T], log-sum-exp [B, T/block, 1, block]."""
     b, t, hd = q.shape
+    hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
     group, windowed, kind = _kernel_options(q, k, window)
-    head = pl.BlockSpec((None, t, hd), (lambda h, i: (h, 0, 0)) if group == 1
-                        else (lambda h, i: (h // group, 0, 0)))
+    head = lambda width: pl.BlockSpec((None, t, width), (lambda h, i: (h, 0, 0)) if group == 1
+                                      else (lambda h, i: (h // group, 0, 0)))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold, **windowed),
         grid=(b, n_blocks),
-        in_specs=[pl.BlockSpec((None, block, hd), lambda h, i: (h, i, 0)), head, head],
-        out_specs=[pl.BlockSpec((None, hd, block), lambda h, i: (h, 0, i)),
+        in_specs=[pl.BlockSpec((None, block, hd), lambda h, i: (h, i, 0)), head(hd), head(hd_v)],
+        out_specs=[pl.BlockSpec((None, hd_v, block), lambda h, i: (h, 0, i)),
                    pl.BlockSpec((None, None, 1, block), lambda h, i: (h, i, 0, 0))],
-        out_shape=[_struct((b, hd, t), q.dtype, q, k, v),
+        out_shape=[_struct((b, hd_v, t), q.dtype, q, k, v),
                    _struct((b, n_blocks, 1, block), _F32, q, k, v)],
-        scratch_shapes=[pltpu.VMEM((hd, t), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((hd_v, t), v.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"), **_vmem(hd, group, backward=False)),
         interpret=interpret,
         name="causal_attention_fwd" + kind,
     )(q, k, v)
 
 
 def _backward(q, k, v, do, lse, delta, block, interpret, window=None):
-    """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk``, ``dv`` [B, T, hd] — one a QUERY
-    head, in float32, where heads are grouped: the caller sums a group's."""
+    """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk`` [B, T, hd], ``dv`` [B, T, hd_v] —
+    one a QUERY head, in float32, where heads are grouped: the caller sums a group's."""
     b, t, hd = q.shape
+    hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
     group, windowed, kind = _kernel_options(q, k, window)
-    head = pl.BlockSpec((None, t, hd), lambda h, j: (h, 0, 0))
-    rows = pl.BlockSpec((None, block, hd), lambda h, j: (h, j, 0))
-    kv_rows = rows if group == 1 else pl.BlockSpec((None, block, hd), lambda h, j: (h // group, j, 0))
+    head = lambda width: pl.BlockSpec((None, t, width), lambda h, j: (h, 0, 0))
+    rows = lambda width: pl.BlockSpec((None, block, width), lambda h, j: (h, j, 0))
+    kv_rows = rows if group == 1 else lambda width: pl.BlockSpec(
+        (None, block, width), lambda h, j: (h // group, j, 0))
     stats = pl.BlockSpec((None, n_blocks, 1, block), lambda h, j: (h, 0, 0, 0))
     like = (q, k, v, do, lse, delta)
     dk_dtype, dv_dtype = (k.dtype, v.dtype) if group == 1 else (_F32, _F32)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold, **windowed),
         grid=(b, n_blocks),
-        in_specs=[head, kv_rows, kv_rows, head, stats, stats],
-        out_specs=[pl.BlockSpec((None, hd, t), lambda h, j: (h, 0, 0)), rows, rows],
+        in_specs=[head(hd), kv_rows(hd), kv_rows(hd_v), head(hd_v), stats, stats],
+        out_specs=[pl.BlockSpec((None, hd, t), lambda h, j: (h, 0, 0)), rows(hd), rows(hd_v)],
         out_shape=[_struct((b, hd, t), q.dtype, *like), _struct(q.shape, dk_dtype, *like),
-                   _struct(q.shape, dv_dtype, *like)],
+                   _struct(do.shape, dv_dtype, *like)],
         scratch_shapes=[pltpu.VMEM((hd, t), _F32), pltpu.VMEM((hd, block), k.dtype)],
-        # A head's q, dO and float32 dQ fill the default 16 MiB at 8192 positions of 128;
-        # a group's float32 dK, dV blocks pass it by 1.25 MiB.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            **({} if group == 1 else {"vmem_limit_bytes": GROUPED_BWD_VMEM})),
+            dimension_semantics=("parallel", "arbitrary"), **_vmem(hd, group, backward=True)),
         interpret=interpret,
         name="causal_attention_bwd" + kind,
     )(q, k, v, do, lse, delta)
@@ -321,7 +343,8 @@ def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            window: int | None = None) -> jax.Array:
     """The same function spelled densely, in the inputs' dtype throughout: ``[N, H, T, T]``
     scores, mask, softmax.  What short sequences run, and what the kernels are tested
-    against.  Grouped ``k``/``v`` (``[N, H_kv, T, hd]``) are repeated to the query heads."""
+    against.  Grouped ``k``/``v`` (``[N, H_kv, T, hd]``) are repeated to the query heads;
+    ``v``'s head size may differ from ``q``'s and ``k``'s (the output takes it)."""
     t, hd = q.shape[-2:]
     if k.shape[1] != q.shape[1]:
         k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
@@ -348,18 +371,20 @@ def causal_attention(
     """Causal attention ``[N, H, T, hd] x 3 -> [N, H, T, hd]``, scale ``1/sqrt(hd)``,
     differentiable in all three; ``T`` whole blocks of ``block`` (default:
     :func:`block_for`).  ``k`` and ``v`` may hold fewer heads, ``[N, H_kv, T, hd]`` with
-    ``H`` a multiple of ``H_kv``: query head ``h`` reads head ``h // (H / H_kv)``.  With
-    ``window``, position ``t`` attends to keys ``t - window < s <= t`` only.
+    ``H`` a multiple of ``H_kv``: query head ``h`` reads head ``h // (H / H_kv)``.  ``v``
+    may have another head size, ``[N, H_kv, T, hd_v]``: the output is ``[N, H, T, hd_v]``.
+    With ``window``, position ``t`` attends to keys ``t - window < s <= t`` only.
 
     Off the TPU the kernels run in Pallas's interpreter, which cannot evaluate a kernel
     on values that vary over a ``shard_map`` axis under its varying-axes check (the
     kernel's own constants do not vary); there, and only there, the dense spelling
     answers."""
     n, h, t, hd = q.shape
-    h_kv = k.shape[1]
-    if k.shape != v.shape or k.shape != (n, h_kv, t, hd) or h_kv == 0 or h % h_kv:
-        raise ValueError("k and v must share a shape, q's but for heads that divide q's: "
-                         f"{q.shape}, {k.shape}, {v.shape}")
+    h_kv, hd_v = k.shape[1], v.shape[-1]
+    if (k.shape != (n, h_kv, t, hd) or v.shape != (n, h_kv, t, hd_v) or h_kv == 0 or h % h_kv
+            or hd_v == 0):
+        raise ValueError("k must have q's shape and v k's, but for heads that divide q's and "
+                         f"v's own head size: {q.shape}, {k.shape}, {v.shape}")
     if window is not None and window < 1:
         raise ValueError(f"window={window}: a position sees itself at least")
     block = block_for(t) if block is None else block
@@ -370,6 +395,6 @@ def causal_attention(
     interpret = auto_interpret(interpret)
     if interpret and any(jax.typeof(a).vma for a in (q, k, v)):
         return dense_causal_attention(q, k, v, window=window)
-    flat = lambda a: a.reshape(-1, t, hd)
+    flat = lambda a: a.reshape(-1, t, a.shape[-1])
     out = _attend(flat(q), flat(k), flat(v), block, interpret, window)
-    return out.reshape(n, h, t, hd)
+    return out.reshape(n, h, t, hd_v)

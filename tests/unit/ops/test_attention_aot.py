@@ -3,8 +3,9 @@ GPT-2 cell's shapes: what the TPU's compiler refuses (a tiling, a VMEM budget, a
 transpose it cannot place) fails here, at no chip time.  Nothing runs: this says nothing
 about values or times.  All such compiles live in this one file (one worker loads the
 TPU's library, inside the fixture): the cell's training step is here too, for what the
-compiler keeps of the MLP between its forward and its backward, and the SmallThinker
-cell's embedding gradient, for where the compiler places its accumulators."""
+compiler keeps of the MLP between its forward and its backward, the SmallThinker
+cell's embedding gradient, for where the compiler places its accumulators, and the
+moonlight cell's kernels with score and value heads of different sizes."""
 
 import re
 
@@ -64,6 +65,18 @@ def test_backward_kernel_compiles(one_chip, shape, dtype):
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     text = jax.jit(jax.grad(_loss, (0, 1, 2))).lower(x, x, x).compile().as_text()
     assert "causal_attention_fwd" in text and "causal_attention_bwd" in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_latent_head_sizes_compile_at_8192_positions(one_chip, backward):
+    """The moonlight cell's shapes: 16 heads, 8192 positions, 192-wide ``q`` and ``K`` over a
+    128-wide ``V`` (a 192-wide row is padded to 256 lanes: both kernels pass the default
+    scoped VMEM there, and compile under ``WIDE_VMEM``)."""
+    shaped = lambda hd: jax.ShapeDtypeStruct((1, 16, 8192, hd), jnp.bfloat16, sharding=one_chip)
+    fn = jax.grad(_loss, (0, 1, 2)) if backward else _attend
+    text = jax.jit(fn).lower(shaped(192), shaped(192), shaped(128)).compile().as_text()
+    assert "causal_attention_bwd" in text if backward else "causal_attention_fwd" in text
+    assert "tpu_custom_call" in text
 
 
 def test_compiles_under_vmap_and_scan(one_chip):
