@@ -25,7 +25,10 @@ auxiliary loss, are not built.  Like ``moe_decoder_lm`` it drops into the standa
 federated pipeline: ``apply`` returns next-token log-probabilities at the LAST position
 (``[N, vocab]``); the dense layers' leaves are stacked on a leading axis under
 ``params["dense"]``, the expert layers' under ``params["moe"]``, and every layer is
-rematerialized (``jax.checkpoint``).
+rematerialized (``jax.checkpoint``) but for the attention kernels' output and
+log-sum-exp (``ops.attention.KEEP_KERNEL_OUTPUTS``: one ``[N, heads, T, value]`` array a
+layer is kept beside the layer's input, and the backward pass does not launch the
+forward kernel again).
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions, with score heads of ``nope + rope``
@@ -56,7 +59,8 @@ from nanofed_tpu.models.experts import COUNTERS, SWIGLU, held_experts, sigmoid_r
 from nanofed_tpu.models.hybrid import rms_norm
 from nanofed_tpu.models.moe_decoder import rotate
 from nanofed_tpu.nn import embed_rows
-from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
+from nanofed_tpu.ops.attention import (
+    KEEP_KERNEL_OUTPUTS, causal_attention, dense_causal_attention, engages)
 
 #: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
 #: block costs).  This model's own number, measured at its cell (8192 tokens a step, 6 of
@@ -171,7 +175,8 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     counters = jnp.zeros((len(COUNTERS),), _F32)
     for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
-        layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"))
+        layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"),
+                               policy=KEEP_KERNEL_OUTPUTS)
         for index in range(count):
             x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
             counters = counters + counted

@@ -17,7 +17,10 @@ feed-forward.  Like ``hybrid_lm`` it drops into the standard federated pipeline:
 ``apply`` returns next-token log-probabilities at the LAST position (``[N, vocab]``);
 the layers are stacked on a leading axis (``params["layers"]["wq"]`` is ``[layers, d,
 heads * head_dim]``, the experts ``[layers, experts held, d, 2 f]`` and ``[layers,
-experts held, f, d]``), and every layer is rematerialized (``jax.checkpoint``).
+experts held, f, d]``), and every layer is rematerialized (``jax.checkpoint``) but for
+the attention kernels' output and log-sum-exp (``ops.attention.KEEP_KERNEL_OUTPUTS``: one
+``[N, heads, T, head_dim]`` array a layer is kept beside the layer's input, and the
+backward pass does not launch the forward kernel again).
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions — grouped heads read their key/value
@@ -46,7 +49,8 @@ from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS, REGLU, held_experts
 from nanofed_tpu.models.hybrid import rms_norm
 from nanofed_tpu.nn import embed_rows
-from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
+from nanofed_tpu.ops.attention import (
+    KEEP_KERNEL_OUTPUTS, causal_attention, dense_causal_attention, engages)
 
 #: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
 #: block costs).  A model's own number, measured at its cell (8192 tokens a step, 6 of
@@ -149,7 +153,8 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     counters = jnp.zeros((len(COUNTERS),), _F32)
     for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
         layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),
-                                       window=cfg["window"] if windowed else None))
+                                       window=cfg["window"] if windowed else None),
+                               policy=KEEP_KERNEL_OUTPUTS)
         x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x)
         counters = counters + counted
     return x, counters

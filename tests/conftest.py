@@ -42,3 +42,24 @@ def rng():
 @pytest.fixture
 def np_rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def kernel_calls():
+    """``kernel_calls(fn, *args)``: every launch of an attention kernel in ``fn``'s jaxpr by
+    the kernel's name (a ``pallas_call``, or a ``jit`` equation that stands in for one),
+    counted equation by equation through every nested jaxpr: two layers that share one
+    traced function are printed once and launched twice."""
+
+    def count(jaxpr, calls):
+        for eqn in jaxpr.eqns:
+            name = eqn.params.get("name")
+            if (eqn.primitive.name in ("pallas_call", "jit", "pjit") and isinstance(name, str)
+                    and name.startswith("causal_attention_")):
+                calls[name] = calls.get(name, 0) + 1
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    count(sub, calls)
+        return calls
+
+    return lambda fn, *args: count(jax.make_jaxpr(fn)(*args).jaxpr, {})

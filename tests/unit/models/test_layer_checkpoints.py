@@ -1,0 +1,195 @@
+"""What the two decoders with rematerialized layers keep for the backward pass
+(``models.moe_decoder``, ``models.latent_moe``): every layer under ``jax.checkpoint`` with
+``ops.attention.KEEP_KERNEL_OUTPUTS``, so the forward kernel's output and log-sum-exp stay
+and the kernel is launched once a layer; a plain checkpoint (the policy taken away, as
+each test does for its other side) launches it twice and computes the same bits."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from nanofed_tpu.aggregation.base import fedavg_strategy
+from nanofed_tpu.core.types import ClientData
+from nanofed_tpu.models import get_model, latent_moe, moe_decoder
+from nanofed_tpu.ops import attention
+from nanofed_tpu.parallel.mesh import make_mesh
+from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
+from nanofed_tpu.trainer import TrainingConfig
+
+#: ``(factory, its module, a tiny configuration)``; at 512 positions the kernels engage
+#: (Pallas's interpreter here), at 32 the dense spelling answers.  One full and three
+#: windowed layers with seven query heads a key/value head; one dense and two expert
+#: layers with 24-wide scores over 16-wide values.
+DECODERS = {
+    "moe_decoder": ("moe_decoder_lm", moe_decoder, {
+        "vocab": 64, "seq_len": 512, "width": 64, "rope_layout": [0, 1, 1, 1],
+        "window_layout": [0, 1, 1, 1], "window": 200, "attn_heads": 7, "kv_heads": 1,
+        "head_dim": 16, "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 48}),
+    "latent_moe": ("latent_moe_lm", latent_moe, {
+        "vocab": 64, "seq_len": 512, "width": 64, "heads": 4, "latent_rank": 32, "nope_dim": 16,
+        "rope_dim": 8, "value_dim": 16, "dense_layers": 1, "dense_width": 160, "expert_layers": 2,
+        "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 48}),
+}
+#: Launches a training step of each holds, forward kernels under the policy first.
+LAUNCHES = {
+    "moe_decoder": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
+                    "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
+    "latent_moe": {"causal_attention_fwd": 3, "causal_attention_bwd": 3},
+}
+
+
+@pytest.fixture(params=list(DECODERS))
+def decoder(request, monkeypatch):
+    """``(name, build(**changes) -> (model, params, tokens [1, T]), plainly())``:
+    ``plainly()`` leaves the module a plain ``jax.checkpoint`` for the rest of the test."""
+    factory, module, kwargs = DECODERS[request.param]
+
+    def build(**changes):
+        model = get_model(factory, **{**kwargs, **changes})
+        tokens = jax.random.randint(jax.random.key(1), (1, model.input_shape[0]), 0, kwargs["vocab"])
+        return model, model.init(jax.random.key(0)), tokens
+
+    return request.param, build, lambda: monkeypatch.setattr(module, "KEEP_KERNEL_OUTPUTS", None)
+
+
+def _training_step(model, tokens, cast=lambda p: p):
+    """Loss and every leaf's gradient of one step on the last position's label."""
+    def loss(params):
+        logp = model.apply(jax.tree.map(cast, params), tokens)
+        return -logp[:, 7].mean()
+
+    return jax.value_and_grad(loss)
+
+
+def test_a_training_step_launches_each_attention_kernel_once_a_layer(decoder, kernel_calls):
+    name, build, plainly = decoder
+    _, params, tokens = build()
+    count = lambda: kernel_calls(_training_step(build()[0], tokens), params)
+    assert count() == LAUNCHES[name]
+    plainly()
+    assert count() == {kernel: n * (2 if "fwd" in kernel else 1)
+                       for kernel, n in LAUNCHES[name].items()}
+
+
+@pytest.mark.parametrize("cast,whole", [(lambda p: p, True), (lambda p: p.astype(jnp.bfloat16), False)],
+                         ids=["float32", "bfloat16"])
+def test_what_the_checkpoint_keeps_changes_no_bit_of_a_training_step(decoder, cast, whole):
+    """Loss and every leaf's gradient equal the plain checkpoint's exactly: the backward
+    kernel reads the same two arrays, kept instead of computed again.  In bfloat16 the
+    step runs operation by operation: compiled whole, XLA keeps a bfloat16 value unrounded
+    inside a fusion (its excess precision), and the two programs, one forward kernel
+    apart, fuse differently around the kernels."""
+    _, build, plainly = decoder
+    layers = {"rope_layout": [0, 1], "window_layout": [0, 1]} if decoder[0] == "moe_decoder" else {
+        "expert_layers": 1}
+    _, params, tokens = build(**layers)
+
+    def step():
+        fn = _training_step(build(**layers)[0], tokens, cast)
+        if whole:
+            return jax.jit(fn)(params)
+        with jax.disable_jit():
+            return fn(params)
+
+    kept = step()
+    plainly()
+    plain = step()
+    moved = [float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(plain[1])]
+    assert sum(moved) > len(moved) // 2  # one label: the last layer's experts may get none
+    jax.tree.map(np.testing.assert_array_equal, kept, plain)
+
+
+def test_under_512_positions_the_checkpoint_keeps_nothing(decoder):
+    """The dense spelling answers, nothing carries a name, and the step is the plain
+    checkpoint's program."""
+    _, build, plainly = decoder
+    _, params, tokens = build(seq_len=32)
+    step = lambda: _training_step(build(seq_len=32)[0], tokens)
+    lowered = lambda: jax.jit(step()).lower(params).as_text()
+    assert "name[name=" not in str(jax.make_jaxpr(step())(params))
+    kept = lowered()
+    plainly()
+    assert lowered() == kept
+
+
+@pytest.fixture
+def kernels_in_plain_jax(monkeypatch):
+    """``ops.attention``'s two ``pallas_call``s replaced by the same functions of the same
+    arguments in plain ``jax.numpy``, each one ``jit`` equation under the kernel's name.
+    Pallas's interpreter cannot run on values that vary over a ``shard_map`` axis, so on
+    the CPU mesh ``causal_attention`` answers densely there and nothing of its
+    ``custom_vjp`` is traced; with the stand-ins it is: the forward rule and its names,
+    the residuals, the backward rule around them."""
+
+    def probabilities(q, k, lse, window):
+        t, hd = q.shape[1:]
+        s = jnp.einsum("bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32) / hd ** 0.5
+        behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+        seen = (behind >= 0) & (behind < (t if window is None else window))
+        s = jnp.where(seen, s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, axis=-1) if lse is None else lse.reshape(q.shape[:2])
+        return jnp.exp(s - lse[..., None]), lse
+
+    def causal_attention_fwd(q, k, v, block, window):
+        group = q.shape[0] // k.shape[0]
+        p, lse = probabilities(q, jnp.repeat(k, group, 0), None, window)
+        o = jnp.einsum("bqk,bkd->bqd", p, jnp.repeat(v, group, 0).astype(jnp.float32))
+        return (jnp.swapaxes(o, 1, 2).astype(q.dtype),
+                lse.reshape(q.shape[0], q.shape[1] // block, 1, block))
+
+    def causal_attention_bwd(q, k, v, do, lse, delta, block, window):
+        group = q.shape[0] // k.shape[0]
+        k, v = jnp.repeat(k, group, 0), jnp.repeat(v, group, 0)
+        f32 = lambda a: a.astype(jnp.float32)
+        p, _ = probabilities(q, k, lse, window)
+        dp = jnp.einsum("bqd,bkd->bqk", f32(do), f32(v))
+        ds = p * (dp - delta.reshape(q.shape[0], -1, 1)) / q.shape[-1] ** 0.5
+        dq = jnp.einsum("bqk,bkd->bqd", ds, f32(k))
+        dk, dv = jnp.einsum("bqk,bqd->bkd", ds, f32(q)), jnp.einsum("bqk,bqd->bkd", p, f32(do))
+        if group == 1:  # the kernel writes a group's shares in float32 for its caller to sum
+            dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
+        return jnp.swapaxes(dq, 1, 2).astype(q.dtype), dk, dv
+
+    fwd = jax.jit(causal_attention_fwd, static_argnums=(3, 4))
+    bwd = jax.jit(causal_attention_bwd, static_argnums=(6, 7))
+    monkeypatch.setattr(attention, "_forward",
+                        lambda q, k, v, block, interpret, window=None: fwd(q, k, v, block, window))
+    monkeypatch.setattr(attention, "_backward", lambda q, k, v, do, lse, delta, block, interpret,
+                        window=None: bwd(q, k, v, do, lse, delta, block, window))
+    monkeypatch.setattr(attention, "auto_interpret", lambda interpret: False)
+
+
+@pytest.mark.parametrize("client_chunk", [None, 1], ids=["vmap", "chunks-of-1"])
+def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chunk,
+                                                             kernels_in_plain_jax, kernel_calls):
+    """The ``shard_map`` round over four devices, clients under ``vmap`` or one at a time,
+    the local steps a scan: the names reach the layers' checkpoints through all of them
+    (one forward launch a layer in the round's jaxpr, two under a plain checkpoint), and a
+    round leaves the parameters where the plain checkpoint's round leaves them."""
+    name, build, plainly = decoder
+    model, params, _ = build()
+    layers = sum(LAUNCHES[name].values()) // 2
+    mesh = make_mesh(devices=jax.devices()[:4])
+    training = TrainingConfig(batch_size=1, local_epochs=1, learning_rate=0.05)
+    strategy = fedavg_strategy()
+    k = jax.random.split(jax.random.key(5), 2)
+    data = ClientData(x=jax.random.randint(k[0], (4, 2, 512), 0, 64),
+                      y=jax.random.randint(k[1], (4, 2), 0, 64), mask=jnp.ones((4, 2)))
+    args = (params, init_server_state(strategy, params), data, jnp.full((4,), 2.0),
+            jax.random.split(jax.random.key(6), 4))
+
+    def one_round():
+        step = build_round_step(build()[0].apply, training, mesh, strategy,
+                                client_chunk=client_chunk, params_like=params)
+        return kernel_calls(step, *args), step(*args).params
+
+    calls, kept = one_round()
+    assert calls == {"causal_attention_fwd": layers, "causal_attention_bwd": layers}
+    plainly()
+    calls, plain = one_round()
+    assert calls == {"causal_attention_fwd": 2 * layers, "causal_attention_bwd": layers}
+    moved = [float(jnp.abs(a - b).max()) > 0
+             for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(params))]
+    assert sum(moved) >= len(moved) - 1
+    jax.tree.map(np.testing.assert_array_equal, kept, plain)

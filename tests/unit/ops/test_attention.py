@@ -1,6 +1,9 @@
 """``ops.attention``: the blockwise causal-attention kernels (Pallas's interpreter on the
 CPU mesh; the same code compiles for the TPU, ``test_attention_aot.py``) against the dense
-spelling computed in float32."""
+spelling computed in float32; and what a ``jax.checkpoint`` around them keeps under
+``KEEP_KERNEL_OUTPUTS``."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -8,8 +11,11 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from nanofed_tpu.models import get_model
+from nanofed_tpu.ops import attention
 from nanofed_tpu.ops.attention import (
     BLOCKS,
+    KEEP_KERNEL_OUTPUTS,
     MAX_SEQ,
     MIN_SEQ,
     block_for,
@@ -18,6 +24,7 @@ from nanofed_tpu.ops.attention import (
     engages,
 )
 from nanofed_tpu.parallel.mesh import make_mesh
+from nanofed_tpu.trainer.local import make_grad_fn
 
 SHAPES = [(2, 3, 512, 64), (1, 2, 1024, 64), (1, 2, 768, 32)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -158,3 +165,97 @@ def test_interpreter_under_shard_map_answers_densely(devices):
                                 out_specs=spec))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(dense_causal_attention(q, k, v)), atol=2e-6)
+
+
+#: ``(heads, key/value heads, score head size, value head size, window)``: what the three
+#: language models on the kernels ask of them.
+LAYERS = {"full": (2, 2, 64, 64, None), "grouped": (4, 2, 64, 64, None),
+          "windowed": (2, 2, 64, 64, 200), "grouped-windowed": (4, 1, 32, 32, 300),
+          "24-over-16": (2, 2, 24, 16, None)}  # latent attention's 192 over 128, small
+
+
+def _layer_inputs(kind, dtype):
+    heads, kv_heads, hd, hd_v, _ = LAYERS[kind]
+    shapes = [(1, heads, 512, hd), (1, kv_heads, 512, hd), (1, kv_heads, 512, hd_v),
+              (1, heads, 512, hd_v)]
+    keys = jax.random.split(jax.random.key(11), 4)
+    return [jax.random.normal(k, s, jnp.float32).astype(dtype) for k, s in zip(keys, shapes)]
+
+
+def _rematerialized(kind, policy, w):
+    """Value and the three gradients of a rematerialized layer: the projections stand in
+    for what a decoder layer recomputes around the call."""
+    def layer(q, k, v):
+        return causal_attention(1.5 * q, k + k, -v, window=LAYERS[kind][4])
+
+    def loss(q, k, v):
+        out = jax.checkpoint(layer, policy=policy)(q, k, v)
+        return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+    return jax.value_and_grad(loss, (0, 1, 2))
+
+
+@pytest.mark.parametrize("kind", list(LAYERS))
+def test_a_checkpoint_with_the_policy_does_not_launch_the_forward_kernel_again(kind, kernel_calls):
+    """Partial evaluation keeps the two named outputs, nothing in the recomputation reads
+    the ``pallas_call`` any more, and it goes; a plain checkpoint launches it twice."""
+    q, k, v, w = _layer_inputs(kind, jnp.float32)
+    end = "_window" if LAYERS[kind][4] else ""
+    fwd, bwd = "causal_attention_fwd" + end, "causal_attention_bwd" + end
+    assert kernel_calls(_rematerialized(kind, KEEP_KERNEL_OUTPUTS, w), q, k, v) == {fwd: 1, bwd: 1}
+    assert kernel_calls(_rematerialized(kind, None, w), q, k, v) == {fwd: 2, bwd: 1}
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+@pytest.mark.parametrize("kind", list(LAYERS))
+def test_gradients_under_the_policy_are_the_plain_checkpoints_bit_for_bit(kind, dtype):
+    """The backward kernel reads the same arrays, kept instead of computed again."""
+    q, k, v, w = _layer_inputs(kind, dtype)
+    kept = jax.jit(_rematerialized(kind, KEEP_KERNEL_OUTPUTS, w))(q, k, v)
+    plain = jax.jit(_rematerialized(kind, None, w))(q, k, v)
+    assert float(jnp.abs(plain[1][0].astype(jnp.float32)).max()) > 0
+    jax.tree.map(np.testing.assert_array_equal, kept, plain)
+
+
+def test_the_policy_keeps_only_what_carries_a_name(capsys):
+    """Two residuals beside the checkpoint's inputs: the output and the log-sum-exp, not
+    ``q``, ``k``, ``v`` as the kernel was handed them; a plain checkpoint keeps none."""
+    q, k, v, _ = _layer_inputs("grouped", jnp.bfloat16)
+    layer = lambda q, k, v: causal_attention(1.5 * q, k + k, -v)
+
+    def kept(policy):
+        jax.ad_checkpoint.print_saved_residuals(jax.checkpoint(layer, policy=policy), q, k, v)
+        lines = capsys.readouterr().out.strip().splitlines()
+        assert all("from the argument" in line for line in lines[:3])
+        return [line.split()[0] for line in lines[3:]]
+
+    assert kept(KEEP_KERNEL_OUTPUTS) == ["bf16[4,512,64]", "f32[4,1,1,512]"]
+    assert kept(None) == []
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"], ids=["float32", "bfloat16"])
+def test_outside_a_checkpoint_the_names_change_nothing(compute_dtype, monkeypatch, kernel_calls):
+    """GPT-2's scanned stack calls the kernels with no layer checkpoint around them: its
+    training step lowers to the same StableHLO with the names and without, operation for
+    operation.  (A name is an equation of the jaxpr and lowers to nothing, but the lowering
+    numbers the private functions that come after it one further: ``@_where_87`` for
+    ``@_where_86``.  That number is all that differs.)"""
+    m = get_model("transformer_lm_scan", vocab=64, seq_len=512, width=64, depth=2, heads=2)
+    params = jax.eval_shape(m.init, jax.random.key(0))
+    batch = (jax.ShapeDtypeStruct((2, 512), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int32),
+             jax.ShapeDtypeStruct((2,), jnp.float32))
+
+    def step():  # a new function each time: a trace is cached by the function traced
+        grad_fn = make_grad_fn(m.apply, compute_dtype=compute_dtype)
+        return lambda p, x, y, mask: grad_fn(p, x, y, mask, jax.random.key(0))[0]
+
+    def lowered():
+        text = jax.jit(step()).lower(params, *batch).as_text()
+        return re.sub(r"(@[A-Za-z_]+)_\d+\b", r"\1", text)
+
+    named, names = lowered(), str(jax.make_jaxpr(step())(params, *batch)).count("name[name=")
+    assert kernel_calls(step(), params, *batch) == {"causal_attention_fwd": 1,
+                                                    "causal_attention_bwd": 1}  # the scan's body
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    assert names == 2 and "name[name=" not in str(jax.make_jaxpr(step())(params, *batch))
+    assert lowered() == named

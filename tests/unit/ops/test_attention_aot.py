@@ -4,8 +4,9 @@ transpose it cannot place) fails here, at no chip time.  Nothing runs: this says
 about values or times.  All such compiles live in this one file (one worker loads the
 TPU's library, inside the fixture): the cell's training step is here too, for what the
 compiler keeps of the MLP between its forward and its backward, the SmallThinker
-cell's embedding gradient, for where the compiler places its accumulators, and the
-moonlight cell's kernels with score and value heads of different sizes."""
+cell's embedding gradient, for where the compiler places its accumulators, the
+moonlight cell's kernels with score and value heads of different sizes, and a
+rematerialized layer of either decoder, for what it launches twice and what it keeps."""
 
 import re
 
@@ -15,7 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from nanofed_tpu import nn
-from nanofed_tpu.models import get_model, transformer
+from nanofed_tpu.models import get_model, latent_moe, moe_decoder, transformer
+from nanofed_tpu.ops import attention
 from nanofed_tpu.ops.attention import causal_attention
 from nanofed_tpu.trainer.local import make_grad_fn
 
@@ -188,3 +190,66 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
     results = _scatter_results(_embed_gradient_text(one_chip))
     whole = f"bf16[{EMBED[0]},{EMBED[1]}]"
     assert results and all(r.startswith(whole) and "S(1)" not in r for r in results), results
+
+
+#: The two cells' layers at their published widths and 8192 positions, a small vocabulary
+#: around them: ``(factory, module, kwargs, layers, bytes of the output and log-sum-exp a
+#: layer keeps)``.
+DECODERS = {
+    "smallthinker": ("moe_decoder_lm", moe_decoder, dict(
+        vocab=1024, seq_len=8192, width=2560, rope_layout=[1], window_layout=[1], window=4096,
+        rope_theta=1500000, attn_heads=28, kv_heads=4, head_dim=128, experts=64, first_expert=0,
+        experts_held=16, top_k=6, expert_width=768, eps=1e-6), 1, 28 * 8192 * (128 * 2 + 4)),
+    "moonlight": ("latent_moe_lm", latent_moe, dict(
+        vocab=1024, seq_len=8192, width=2048, heads=16, latent_rank=512, nope_dim=128,
+        rope_dim=64, value_dim=128, rope_theta=50000, dense_layers=1, dense_width=11264,
+        expert_layers=1, experts=64, first_expert=0, experts_held=8, top_k=6, expert_width=1408,
+        shared_width=2816, routed_scale=2.446, eps=1e-5), 2, 16 * 8192 * (128 * 2 + 4)),
+}
+#: What buffer assignment may move for reasons of its own when the schedule changes (read
+#: here: +11 MB on 60 MB kept and +0.5 MB on 68 MB kept).
+SLACK = 16 * 2**20
+
+
+@pytest.fixture(scope="module", params=list(DECODERS))
+def decoder_steps(request, one_chip):
+    """A decoder's gradient step (one client under ``vmap``, one sequence of 8192 tokens,
+    bfloat16 compute) compiled twice: as the model rematerializes its layers, and under a
+    plain ``jax.checkpoint``.  The kernels are compiled, not interpreted: this process
+    sees the CPU, so the test says so in ``auto_interpret``'s place."""
+    factory, module, kwargs, layers, kept_bytes = DECODERS[request.param]
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    batch = (shaped((1, 1, 8192), jnp.int32), shaped((1, 1), jnp.int32), shaped((1, 1), jnp.float32))
+
+    def compile_step():
+        m = get_model(factory, **kwargs)
+        params = jax.tree.map(lambda a: shaped(a.shape, a.dtype),
+                              jax.eval_shape(m.init, jax.random.key(0)))
+        grad_fn = make_grad_fn(m.apply, compute_dtype="bfloat16")
+        step = jax.vmap(lambda p, x, y, mask: grad_fn(p, x, y, mask, jax.random.key(0))[0],
+                        in_axes=(None, 0, 0, 0))
+        return jax.jit(step).lower(params, *batch).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "auto_interpret", lambda interpret: False)
+        kept = compile_step()
+        patch.setattr(module, "KEEP_KERNEL_OUTPUTS", None)
+        plain = compile_step()
+    return {"kept": kept, "plain": plain, "layers": layers, "kept_bytes": layers * kept_bytes}
+
+
+def _kernel_launches(compiled, kernel: str) -> int:
+    return sum("tpu_custom_call" in line and kernel in line.split(" = ")[0]
+               for line in compiled.as_text().splitlines())
+
+
+def test_a_rematerialized_layer_launches_the_forward_kernel_once(decoder_steps):
+    """... and its memory grows by no more than the two outputs it keeps."""
+    layers = decoder_steps["layers"]
+    launches = {which: [_kernel_launches(decoder_steps[which], kernel)
+                        for kernel in ("causal_attention_fwd", "causal_attention_bwd")]
+                for which in ("kept", "plain")}
+    assert launches == {"kept": [layers, layers], "plain": [2 * layers, layers]}
+    temp = {which: decoder_steps[which].memory_analysis().temp_size_in_bytes
+            for which in ("kept", "plain")}
+    assert temp["kept"] - temp["plain"] <= decoder_steps["kept_bytes"] + SLACK, temp
