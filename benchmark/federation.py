@@ -40,6 +40,21 @@ def load_named(root: Path, kind: str, name: str):
     return _LOADED[path]
 
 
+def scope_metrics(root: Path) -> tuple[dict[str, dict], frozenset[str]]:
+    """``({metric: its file's object}, every scope named)``.  A per-scope metric is
+    ``<root>/benchmark/scope_metrics/<metric>.json`` and needs no reader module: ``scopes``
+    (the ``jax.named_scope``s of the program it sums; absent: every operation), ``pass``
+    (forward, recomputed or backward; absent: all), ``innermost`` (only what ran directly
+    under a scope, inside no other that is named).  The files of
+    ``<root>/benchmark/scope_table/`` name scopes that make no metric: rows of their own in
+    the printed table, which an enclosing ``innermost`` reading then leaves out."""
+    directory = Path(root) / "benchmark"
+    specs = {p.stem: load_json(p) for p in sorted((directory / "scope_metrics").glob("*.json"))}
+    apart = [load_json(p) for p in sorted((directory / "scope_table").glob("*.json"))]
+    names = {n for spec in [*specs.values(), *apart] for n in spec.get("scopes") or ()}
+    return specs, frozenset(names)
+
+
 def program_seed(seed: int) -> int:
     """The driver's seeds pass 2**31; the program's key and NumPy generators take 31 bits."""
     return int(seed) % (2**31 - 1)

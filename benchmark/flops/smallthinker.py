@@ -17,14 +17,16 @@ recomputation of every layer in the backward pass is not counted.  Norms, the ro
 the softmax, the dispatch and the embedding lookup are left out.
 
 What the attention KERNELS execute is counted apart (:func:`attention_kernel_flops_per_round`,
-for their share of the roofline): there the recomputation does count, since the device
-spends the time.
+for their share of the roofline): every execution the program
+makes counts there, a rerun included (:data:`FORWARD_KERNEL_EXECUTIONS`), since the
+device spends the time.
 """
 
-#: Times the program runs the forward kernel a layer and a training step: once in the
-#: forward pass, once more when the backward pass rematerializes the layer
-#: (``jax.checkpoint`` around every layer).  A test counts the ``pallas_call``s of a step.
-FORWARD_KERNEL_EXECUTIONS = 2
+#: Times the program runs the forward kernel a layer and a training step: once, in the
+#: forward pass.  The layer's checkpoint keeps the kernel's output and log-sum-exp, so
+#: the backward pass's rematerialization does not rerun it (the program, since PR 34).
+#: A test counts the ``pallas_call``s of a step.
+FORWARD_KERNEL_EXECUTIONS = 1
 #: Matrix products a block pair: scores and values forward; scores, dP, dV, dK, dQ backward.
 FORWARD_PRODUCTS, BACKWARD_PRODUCTS = 2, 5
 

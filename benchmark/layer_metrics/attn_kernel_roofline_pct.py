@@ -6,10 +6,10 @@ head's ``K`` and ``V`` stay in VMEM), so the roofline is the matmul peak.
 Time: the entries of the trace's ten longest operations whose names begin with the
 kernels' names (``pallas_call``'s ``name``: ``causal_attention_fwd``,
 ``causal_attention_bwd``, ``..._window`` where a window cuts the keys).  Every layer's
-forward, its rematerialized forward and its backward are instructions of their own, so
-more of them can exist than the ten hold: operations are counted for the executions
-FOUND, each by its kind, so an entry that fell off the list takes its time and its
-operations with it and the share never reads high.  With every execution among the ten
+forward and its backward are instructions of their own (and a forward the backward pass
+reruns would be a third), so more of them can exist than the ten hold: operations are
+counted for the executions FOUND, each by its kind, so an entry that fell off the list
+takes its time and its operations with it and the share never reads high.  With every execution among the ten
 this is ``attention_kernel_flops_per_round`` over the kernels' time a round.
 
 Left out where none is among the ten, where the family's file counts no such

@@ -6,7 +6,8 @@ Builds the cell's federation from the seed, drives its first rounds (they compil
 read the cache, warm every shape, and are what ``correct`` compares), measures whole
 rounds for ``--seconds``, frees the system, runs the plain reference, and prints one
 JSON object as the last line.  ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics with the profiler on for a few rounds of the window.
+``--trace 1`` its per-layer metrics with the profiler on for a few rounds of the window,
+and prints the traced rounds' device time by the program's named scopes and by pass.
 Needs the chips the cell asks for: there is no fallback to another backend.
 
 Names of configurations, mixes and metrics come from ``BENCHMARK.json`` and select
@@ -33,6 +34,8 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
+from benchmark import trace  # noqa: E402  (the standard library alone until ``load`` runs)
+
 
 def say(*parts) -> None:
     print(*parts, flush=True)
@@ -46,6 +49,32 @@ def device_memory_peak(device) -> int:
     1 GiB argument reads 1.07e9 in use, 5.4e8 reserved)."""
     stats = device.memory_stats() or {}
     return int(stats.get("peak_bytes_in_use", 0)) + int(stats.get("peak_bytes_reserved", 0))
+
+
+def say_scopes(rows: list, rounds: int, busy_s: float) -> None:
+    """The whole table, ms a traced round: every scope the trace holds by pass, and what
+    ran in none; its sum beside the device's busy time, which it tiles."""
+    table, kinds = trace.scope_table(rows), trace.PASSES
+    per_round = lambda seconds: 1000.0 * seconds / max(rounds, 1)
+    say("# device time by scope, ms a traced round: " + " ".join(f"{k:>11}" for k in kinds))
+    for scope, row in sorted(table.items(), key=lambda kv: -sum(kv[1].values())):
+        say(f"#   {scope:<24}" + " ".join(f"{per_round(row.get(k, 0.0)):11.3f}" for k in kinds)
+            + f"   {per_round(sum(row.values())):11.3f}")
+    total = sum(seconds for _, _, seconds in rows)
+    say(f"#   the rows' sum {per_round(total):.3f}, busy {per_round(busy_s):.3f} "
+        f"({100.0 * (total / busy_s - 1):+.3f}%)")
+
+
+def scope_ms_per_round(ctx: dict, spec: dict) -> float | None:
+    """The reading every per-scope metric shares: milliseconds a traced round of the rows
+    of the scope table (``ctx["scopes"]``) that the metric's own file asks for.  ``None``
+    where the run was not traced or no operation ran under its scopes: nothing to read."""
+    rows, rounds = ctx.get("scopes"), ctx.get("traced_rounds")
+    if not rows or not rounds:
+        return None
+    seconds = trace.scope_seconds(rows, spec.get("scopes"), spec.get("pass"),
+                                  bool(spec.get("innermost")))
+    return None if seconds is None else 1000.0 * seconds / rounds
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -121,7 +150,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
              found, peaks: dict, keep_trace: str | None = None) -> dict:
     """Everything of a run but the look for a chip; returns the result line's object."""
     import jax
-    from benchmark import check, federation, trace
+    from benchmark import check, federation
 
     manifest, cell, config, traffic = load_cell(root, workload)
     family = federation.load_named(root, "reference", config["family"])
@@ -173,8 +202,11 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
             if m.status.name == "COMPLETED" and math.isfinite(m.agg_metrics.get("loss", math.nan))]
     client_samples = sum(m.num_clients for m in good) * fed["samples_per_client"] * fed["local_epochs"]
 
-    reduced = None
+    # Which named scopes make which metric is data: one file a metric.
+    scope_specs, scope_names = federation.scope_metrics(root)
+    reduced = scopes = None
     if trace_dir:
+        t_read = time.perf_counter()
         try:
             path = trace.find_xplane(trace_dir)
             events = trace.load(path, set(traffic["host_spans"])) if path else None
@@ -184,7 +216,14 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
             os.makedirs(keep_trace, exist_ok=True)
             with open(os.path.join(keep_trace, f"{workload}.trace.json"), "w") as f:
                 json.dump(events, f)
+        t_reduce = time.perf_counter()
         reduced = trace.reduce(events, traffic["window_span"]) if events else None
+        t_scopes = time.perf_counter()
+        if reduced:
+            scopes = trace.by_scope(events, scope_names)
+            say_scopes(scopes, run["traced_rounds"], reduced["busy_s"])
+        say(f"# trace read in {t_reduce - t_read:.2f} s, reduced in {t_scopes - t_reduce:.2f} s, "
+            f"by scope in {time.perf_counter() - t_scopes:.2f} s")
 
     # --- free the system, then the plain reference on one device.
     del coordinator, generator
@@ -206,7 +245,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
 
     # --- the metrics.
     ctx = {
-        "trace": reduced, "rounds": rounds, "samples": run["samples"],
+        "trace": reduced, "scopes": scopes, "rounds": rounds, "samples": run["samples"],
         "window_s": run["window_s"], "setup_seconds": setup_s,
         "traced_rounds": run["traced_rounds"], "client_samples": client_samples,
         "train_flops_per_sample": flops.train_flops_per_sample(config["model"]["kwargs"]),
@@ -217,7 +256,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     out: dict[str, dict] = {}
     for metric in manifest[section]:
         if applies(metric, workload):
-            value = federation.load_named(root, readers, metric["name"]).read(ctx)
+            if metric["name"] in scope_specs:
+                value = scope_ms_per_round(ctx, scope_specs[metric["name"]])
+            else:
+                value = federation.load_named(root, readers, metric["name"]).read(ctx)
             if value is not None:
                 out[metric["name"]] = {"value": value, "unit": metric["unit"]}
     device = {"platform": platform, "kind": kind, "count": len(found),
@@ -226,7 +268,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
               "metrics": out, "device": device}
     if traced and reduced:
         device["busy_s"], device["window_s"] = reduced["busy_s"], reduced["window_s"]
-        result["breakdown"] = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+        result["breakdown"] = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"],
+                               "device_scopes": trace.longest_scopes(scopes)}
     say(f"# {len(run['samples'])} samples of {traffic['rounds_per_sample']} round(s) in "
         f"{run['window_s']:.3f} s; {len(rounds)} rounds, {failed} failed")
     return result

@@ -33,6 +33,22 @@ TINY = {
 }
 
 
+def tiny_configurations() -> dict[str, dict]:
+    """``{a configuration of BENCHMARK.json: what its tiny copy changes}``, found and not
+    listed: ``TINY`` above, and every ``test_benchmark_*.py`` beside this file that gives a
+    ``NAME`` (the configuration) and a ``TINY`` (its changes), as each family's test
+    module does.  A later PR's configuration brings its own by adding such a file."""
+    import importlib
+    import re
+
+    found = {parent: over for parent, over in TINY.values()}
+    for path in sorted(Path(__file__).parent.glob("test_benchmark_*.py")):
+        if len(re.findall(r"^(?:NAME|TINY) = ", path.read_text(), flags=re.M)) == 2:
+            module = importlib.import_module(path.stem)
+            found[module.NAME] = module.TINY
+    return found
+
+
 def _merge(base: dict, over: dict) -> dict:
     out = copy.deepcopy(base)
     for k, v in over.items():
@@ -75,3 +91,43 @@ def make_tiny_root(root: Path) -> Path:
             metric["workloads"].append("tiny-cnn.sync-4chip")
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return root
+
+
+def compiled_round_step_paths(root: Path, workload: str, seed: int = 3) -> set[str]:
+    """The name paths (``op_name``) of the round program a cell's first round compiles,
+    here for the CPU: the cell's system built as a run builds it
+    (``federation.start_system``), the program's builder wrapped so that the step's first
+    call also compiles the step for its text."""
+    import re
+    import tempfile
+
+    import jax
+
+    from benchmark import federation, run
+    from nanofed_tpu.orchestration import coordinator as program
+
+    texts: list[str] = []
+    real = program.build_round_step
+
+    def recording(*args, **kwargs):
+        step = real(*args, **kwargs)
+
+        def call(*a, **kw):
+            if not texts:
+                texts.append(step.jit_program.lower(*a, **kw).compile().as_text())
+            return step(*a, **kw)
+
+        call.jit_program = step.jit_program
+        return call
+
+    program.build_round_step = recording
+    try:
+        _, cell, config, traffic = run.load_cell(root, workload)
+        family = federation.load_named(root, "reference", config["family"])
+        with tempfile.TemporaryDirectory() as work:
+            _, _, generator = federation.start_system(
+                config, traffic, family, seed, jax.devices()[: cell["chips"]], work)
+            next(generator)
+    finally:
+        program.build_round_step = real
+    return set(re.findall(r'op_name="([^"]+)"', texts[0]))

@@ -76,7 +76,9 @@ def test_per_layer_metric(metric):
     assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher") and metric["source"] in SOURCES
     assert 1 <= len(metric["layer"]) <= 200 and "\n" not in metric["layer"]
-    assert (REPO / "benchmark" / "layer_metrics" / f"{metric['name']}.py").is_file()
+    # Its reader: a module of its own, or — a per-scope metric — a data file alone.
+    assert ((REPO / "benchmark" / "layer_metrics" / f"{metric['name']}.py").is_file()
+            != (REPO / "benchmark" / "scope_metrics" / f"{metric['name']}.json").is_file())
     moved = next(m for m in MANIFEST["end_to_end"] if m["name"] == metric["moves"])
     assert _cells_of(metric) <= _cells_of(moved)
 

@@ -107,14 +107,16 @@ def test_the_kernels_share_from_a_recorded_list_counts_what_it_finds_and_never_r
     # rounds: 100%, and the operations are the round's.  (The trace names an instruction
     # ``<kernel name>.<n>_<shape>``.)
     at_peak = lambda name, backward: [name, 3 * one(backward) / 197e12]
-    every = ([at_peak(f"causal_attention_fwd.{i}_bf16_1_16_8192_128_", False) for i in range(12)]
-             + [at_peak(f"causal_attention_bwd.{i}_bf16_1_16_8192_192_", True) for i in range(12, 18)])
+    # One forward and one backward instruction a layer: the checkpoints keep the forward's
+    # output (PR 34).
+    every = ([at_peak(f"causal_attention_fwd.{i}_bf16_1_16_8192_128_", False) for i in range(6)]
+             + [at_peak(f"causal_attention_bwd.{i}_bf16_1_16_8192_192_", True) for i in range(6, 12)])
     assert share.read(_ctx(every)) == pytest.approx(100.0)
     total = sum(s for _, s in every) / 3 * 197e12
     assert total == pytest.approx(flops.attention_kernel_flops_per_round(kw, fed))
     # The ten longest hold the six backward executions and four forward: the others take
     # their time AND their operations with them, and the share stays.
-    ten = every[12:] + every[:4]
+    ten = every[6:] + every[:4]
     assert share.read(_ctx(ten)) == pytest.approx(100.0)
     assert share.read(_ctx([[n, 2 * s] for n, s in ten])) == pytest.approx(50.0)
     assert share.read(_ctx(ten + [["fusion.7_f32_", 1.0]])) == pytest.approx(100.0)
@@ -157,12 +159,12 @@ def test_flops_match_a_hand_count():
     # 4 silos x 2 sequences, three times the forward pass: 156.2 TFLOP a round.
     a_round = 8 * flops.train_flops_per_sample(kw)
     assert abs(a_round - 156.2e12) / 156.2e12 < 1e-3
-    # The kernels' own: 640 a pair forward, 2 x (3 x 192 + 2 x 128) = 1664 backward; two
-    # forward runs and one backward a layer.
+    # The kernels' own: 640 a pair forward, 2 x (3 x 192 + 2 x 128) = 1664 backward; one
+    # forward run and one backward a layer.
     assert flops.attention_kernel_flops(kw, backward=False) == 16 * 640 * 33_558_528
     assert flops.attention_kernel_flops(kw, backward=True) == 16 * 1664 * 33_558_528
     assert flops.attention_kernel_flops_per_round(kw, REAL["federation"]) == (
-        8 * 6 * 16 * (2 * 640 + 1664) * 33_558_528)
+        8 * 6 * 16 * (640 + 1664) * 33_558_528)
 
 
 def test_param_count_matches_the_zoo_tree():
@@ -185,9 +187,10 @@ def test_param_count_matches_the_zoo_tree():
 
 
 def test_forward_kernel_executions_are_the_pallas_calls_of_a_training_step():
-    """Every layer under ``jax.checkpoint``: the backward pass runs each layer's forward
-    kernel again.  Counted in the jaxpr of one gradient step at 512 positions (the
-    kernels engage), three layers: 6 forward calls, 3 backward."""
+    """Every layer under ``jax.checkpoint``, which keeps the forward kernel's output and
+    log-sum-exp (PR 34): the backward pass does not run it again.  Counted in the jaxpr of
+    one gradient step at 512 positions (the kernels engage), three layers: 3 forward
+    calls, 3 backward."""
     from nanofed_tpu.models import get_model
 
     model = get_model("latent_moe_lm", **{**TINY_KWARGS, "seq_len": 512})

@@ -61,6 +61,48 @@ def test_traced_run_reports_per_layer_metrics_and_finds_added_files(tiny_root):
         assert (tiny_root / "benchmark" / f).read_bytes() == (REPO / "benchmark" / f).read_bytes()
 
 
+def test_traced_run_hands_the_scope_table_to_its_readers_and_prints_it(tmp_path, monkeypatch):
+    """The CPU has no device plane, so the trace is put in by hand: the SmallThinker round
+    recorded on the chip (``tests/benchmark/data``) stands in for what ``trace.load``
+    reads.  The per-scope metrics of the cells listed appear, the table is printed whole
+    with its sum beside the busy time, and the ten longest rows go into ``breakdown``."""
+    import gzip
+
+    from benchlib import make_tiny_root
+    from benchmark import trace
+
+    root = make_tiny_root(tmp_path)
+    manifest = json.loads((root / "BENCHMARK.json").read_text())
+    listed = {"local_fit_ms_per_round", "attention_ms_per_round", "expert_loop_ms_per_round",
+              "recompute_ms_per_round", "ssm_mixer_ms_per_round"}
+    for metric in manifest["per_layer"]:
+        if metric["name"] in listed:
+            metric["workloads"].append("tiny-lm.sync")
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    data = REPO / "tests" / "benchmark" / "data" / "smallthinker-21b-4l-xsilo-4.sync.trace.json.gz"
+    recorded = json.loads(gzip.decompress(data.read_bytes()))
+    events = {"devices": {int(k): v for k, v in recorded["devices"].items()}, "host": recorded["host"]}
+    monkeypatch.setattr(trace, "find_xplane", lambda trace_dir: "recorded")
+    monkeypatch.setattr(trace, "load", lambda path, names: events)
+    said = []
+    monkeypatch.setattr(run, "say", lambda *parts: said.append(" ".join(map(str, parts))))
+    result = _run(root, "tiny-lm.sync", traced=True)
+    metrics, rounds = result["metrics"], 3  # sync.json traces three rounds
+    want = recorded["expected"]["scope_ms_per_round"]
+    for name in listed - {"ssm_mixer_ms_per_round"}:
+        assert metrics[name]["value"] == pytest.approx(want[name] * recorded["rounds"] / rounds)
+    # Listed, but nothing of this trace ran under its scope: left out, not 0.
+    assert "ssm_mixer_ms_per_round" not in metrics and "fit_unscoped_ms_per_round" not in metrics
+    scopes = result["breakdown"]["device_scopes"]
+    assert len(scopes) == 10 and scopes == sorted(scopes, key=lambda e: -e[1])
+    assert scopes[0][0] == "moe_experts.backward" and set(result["breakdown"]) == {
+        "device_ops", "idle_gaps", "device_scopes"}
+    out = "\n".join(said)
+    assert "# device time by scope, ms a traced round:" in out and "#   unscoped " in out
+    assert "the rows' sum" in out and "(+0.000%)" in out and "# trace read in " in out
+    json.dumps(result)
+
+
 def _first_rounds(root, workload, seed):
     _, cell, config, traffic = run.load_cell(root, workload)
     family = federation.load_named(root, "reference", config["family"])

@@ -105,8 +105,10 @@ def test_the_kernels_share_counts_what_it_finds_and_never_reads_high():
     # Every execution among the ten, each exactly at the peak: 100%, and the operations
     # are the round's.
     at_peak = lambda name, backward, windowed: [name, 3 * one(backward, windowed) / 197e12]
-    every = ([at_peak(f"causal_attention_fwd.{i}_bf16_", False, False) for i in (1, 2)]
-             + [at_peak(f"causal_attention_fwd_window.{i}_bf16_", False, True) for i in range(3, 9)]
+    # (one forward and one backward instruction a layer: the checkpoints keep the forward's
+    # output, PR 34.)
+    every = ([at_peak("causal_attention_fwd.1_bf16_", False, False)]
+             + [at_peak(f"causal_attention_fwd_window.{i}_bf16_", False, True) for i in (3, 4, 5)]
              + [at_peak("causal_attention_bwd.9_bf16_", True, False)]
              + [at_peak(f"causal_attention_bwd_window.{i}_bf16_", True, True) for i in (10, 11, 12)])
     assert share.read(_ctx(every)) == pytest.approx(100.0)
@@ -114,7 +116,7 @@ def test_the_kernels_share_counts_what_it_finds_and_never_reads_high():
     assert total == pytest.approx(flops.attention_kernel_flops_per_round(kw, fed))
     # Two executions fell off the list: their time AND their operations go, the share stays.
     assert share.read(_ctx(every[2:])) == pytest.approx(100.0)
-    assert share.read(_ctx([[n, 2 * s] for n, s in every[:10]])) == pytest.approx(50.0)
+    assert share.read(_ctx([[n, 2 * s] for n, s in every])) == pytest.approx(50.0)
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -154,9 +156,9 @@ def test_flops_match_a_hand_count():
     # 4 silos x 2 sequences, three times the forward pass: 84.7 TFLOP a round.
     a_round = 8 * flops.train_flops_per_sample(kw)
     assert abs(a_round - 84.7e12) / 84.7e12 < 1e-3
-    # The kernels' own: (2 forward runs x 2 products + 5) x 2 x 128 a pair = 2304, 28 heads.
+    # The kernels' own: (1 forward run x 2 products + 5) x 2 x 128 a pair = 1792, 28 heads.
     assert flops.attention_kernel_flops_per_round(kw, REAL["federation"]) == (
-        8 * 28 * 2304 * (33_558_528 + 3 * 25_167_872))
+        8 * 28 * 1792 * (33_558_528 + 3 * 25_167_872))
 
 
 def test_param_count_matches_the_zoo_tree():
@@ -175,9 +177,10 @@ def test_param_count_matches_the_zoo_tree():
 
 
 def test_forward_kernel_executions_are_the_pallas_calls_of_a_training_step():
-    """Every layer under ``jax.checkpoint``: the backward pass runs each layer's forward
-    kernel again.  Counted in the jaxpr of one gradient step at 512 positions (the
-    kernels engage), four layers: 8 forward calls, 4 backward."""
+    """Every layer under ``jax.checkpoint``, which keeps the forward kernel's output and
+    log-sum-exp (PR 34): the backward pass does not run it again.  Counted in the jaxpr of
+    one gradient step at 512 positions (the kernels engage), four layers: 4 forward
+    calls, 4 backward."""
     from nanofed_tpu.models import get_model
 
     model = get_model("moe_decoder_lm", **{**TINY_KWARGS, "seq_len": 512, "window": 256})
