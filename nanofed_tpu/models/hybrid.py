@@ -303,18 +303,19 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     seen = dict.fromkeys(_MIXERS, 0)
     counters = jnp.zeros((len(COUNTERS),), _F32)
-    for letter in cfg["pattern"]:
-        kind, mixer = _MIXERS[letter]
-        index = seen[letter]
-        seen[letter] += 1
+    with jax.named_scope("layer_scan"):
+        for letter in cfg["pattern"]:
+            kind, mixer = _MIXERS[letter]
+            index = seen[letter]
+            seen[letter] += 1
 
-        @jax.checkpoint
-        def layer(p, x, mixer=mixer):
-            mixed, counted = mixer(p, rms_norm(p["norm"], x, cfg["eps"]), cfg)
-            return x + mixed, counted
+            @jax.checkpoint
+            def layer(p, x, mixer=mixer):
+                mixed, counted = mixer(p, rms_norm(p["norm"], x, cfg["eps"]), cfg)
+                return x + mixed, counted
 
-        x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
-        counters = counters + counted
+            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
+            counters = counters + counted
     return x, counters
 
 
@@ -362,8 +363,9 @@ def hybrid_lm(
         if x.shape[1] % chunk:
             raise ValueError(f"sequence length {x.shape[1]} is not a multiple of chunk {chunk}")
         hidden, counters = hidden_states(params, x, cfg)
-        last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-        logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
+        with jax.named_scope("lm_head"):
+            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
+            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
         counters = lax.stop_gradient(counters) / max(n_e, 1)
         return logp, dict(zip(COUNTERS, counters))
 

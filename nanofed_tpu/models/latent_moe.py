@@ -147,7 +147,8 @@ def latent_attention(p: Params, u: jax.Array, cfg: dict) -> jax.Array:
     with jax.named_scope("mla_attention"):
         attend = causal_attention if engages(t) else dense_causal_attention
         out = attend(q, k, v)
-    return out.transpose(0, 2, 1, 3).reshape(n, t, h * value) @ p["wo"]
+    with jax.named_scope("attention_proj"):
+        return out.transpose(0, 2, 1, 3).reshape(n, t, h * value) @ p["wo"]
 
 
 def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, dense: bool):
@@ -174,12 +175,13 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the expert layers)."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     counters = jnp.zeros((len(COUNTERS),), _F32)
-    for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
-        layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"),
-                               policy=KEEP_KERNEL_OUTPUTS)
-        for index in range(count):
-            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
-            counters = counters + counted
+    with jax.named_scope("layer_scan"):
+        for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
+            layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"),
+                                   policy=KEEP_KERNEL_OUTPUTS)
+            for index in range(count):
+                x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
+                counters = counters + counted
     return x, counters
 
 
@@ -223,8 +225,9 @@ def latent_moe_lm(
         """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
         del train, rng  # no dropout
         hidden, counters = hidden_states(params, x, cfg)
-        last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-        logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
+        with jax.named_scope("lm_head"):
+            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
+            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
         return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / max(expert_layers, 1)))
 
     def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:

@@ -38,15 +38,20 @@ def apply(
         d1, d2 = jax.random.split(rng)
     else:
         d1 = d2 = None
-    x = nn.relu(nn.conv2d(params["conv1"], x))  # [N, 26, 26, 32]
-    x = nn.relu(nn.conv2d(params["conv2"], x))  # [N, 24, 24, 64]
-    x = nn.max_pool(x, 2)  # [N, 12, 12, 64]
-    x = nn.dropout(d1, x, 0.25, train)
-    x = nn.flatten(x)  # [N, 9216]
-    x = nn.relu(nn.dense(params["fc1"], x))
-    x = nn.dropout(d2, x, 0.5, train)
-    x = nn.dense(params["fc2"], x)
-    return nn.log_softmax(x)
+    with jax.named_scope("cnn_conv1"):
+        x = nn.relu(nn.conv2d(params["conv1"], x))  # [N, 26, 26, 32]
+    with jax.named_scope("cnn_conv2"):
+        x = nn.relu(nn.conv2d(params["conv2"], x))  # [N, 24, 24, 64]
+    with jax.named_scope("cnn_pool"):
+        x = nn.max_pool(x, 2)  # [N, 12, 12, 64]
+        x = nn.dropout(d1, x, 0.25, train)
+        x = nn.flatten(x)  # [N, 9216]
+    with jax.named_scope("cnn_fc1"):
+        x = nn.relu(nn.dense(params["fc1"], x))
+        x = nn.dropout(d2, x, 0.5, train)
+    with jax.named_scope("cnn_fc2"):
+        x = nn.dense(params["fc2"], x)
+        return nn.log_softmax(x)
 
 
 @register_model("mnist_cnn")

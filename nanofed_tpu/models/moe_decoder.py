@@ -120,9 +120,10 @@ def attention(p: Params, u: jax.Array, cfg: dict, *, rope: bool, window: int | N
     projection included."""
     n, t, _ = u.shape
     hq, hkv, hd = cfg["attn_heads"], cfg["kv_heads"], cfg["head_dim"]
-    q = (u @ p["wq"]).reshape(n, t, hq, hd)
-    k = (u @ p["wk"]).reshape(n, t, hkv, hd)
-    v = (u @ p["wv"]).reshape(n, t, hkv, hd)
+    with jax.named_scope("attention_proj"):
+        q = (u @ p["wq"]).reshape(n, t, hq, hd)
+        k = (u @ p["wk"]).reshape(n, t, hkv, hd)
+        v = (u @ p["wv"]).reshape(n, t, hkv, hd)
     if rope:
         with jax.named_scope("rope"):
             q, k = rotate(q, cfg["rope_theta"]), rotate(k, cfg["rope_theta"])
@@ -130,7 +131,8 @@ def attention(p: Params, u: jax.Array, cfg: dict, *, rope: bool, window: int | N
     with jax.named_scope("attention_full" if window is None else "attention_window"):
         attend = causal_attention if engages(t) else dense_causal_attention
         out = attend(q, k, v, window=window)
-    return out.transpose(0, 2, 1, 3).reshape(n, t, hq * hd) @ p["wo"]
+    with jax.named_scope("attention_proj"):
+        return out.transpose(0, 2, 1, 3).reshape(n, t, hq * hd) @ p["wo"]
 
 
 def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, rope: bool, window: int | None):
@@ -151,12 +153,13 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the layers)."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     counters = jnp.zeros((len(COUNTERS),), _F32)
-    for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
-        layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),
-                                       window=cfg["window"] if windowed else None),
-                               policy=KEEP_KERNEL_OUTPUTS)
-        x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x)
-        counters = counters + counted
+    with jax.named_scope("layer_scan"):
+        for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
+            layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),
+                                           window=cfg["window"] if windowed else None),
+                                   policy=KEEP_KERNEL_OUTPUTS)
+            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x)
+            counters = counters + counted
     return x, counters
 
 
@@ -196,8 +199,9 @@ def moe_decoder_lm(
         """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
         del train, rng  # no dropout
         hidden, counters = hidden_states(params, x, cfg)
-        last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-        logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
+        with jax.named_scope("lm_head"):
+            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
+            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
         return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / depth))
 
     def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:

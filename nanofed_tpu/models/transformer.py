@@ -190,15 +190,17 @@ def _attention(params: Params, x: jax.Array, heads: int) -> jax.Array:
     def split_heads(y):  # [N, T, D] -> [N, H, T, hd]
         return y.reshape(n, t, heads, hd).transpose(0, 2, 1, 3)
 
-    q = split_heads(nn.dense(params["wq"], x))
-    k = split_heads(nn.dense(params["wk"], x))
-    v = split_heads(nn.dense(params["wv"], x))
+    with jax.named_scope("attention_proj"):
+        q = split_heads(nn.dense(params["wq"], x))
+        k = split_heads(nn.dense(params["wk"], x))
+        v = split_heads(nn.dense(params["wv"], x))
     blockwise = attention_engages(t)
     _count_attention_trace("blockwise" if blockwise else "dense")
     with jax.named_scope("causal_attention"):
         out = (causal_attention if blockwise else dense_causal_attention)(q, k, v)
-    out = out.transpose(0, 2, 1, 3).reshape(n, t, d)
-    return nn.dense(params["wo"], out)
+    with jax.named_scope("attention_proj"):
+        out = out.transpose(0, 2, 1, 3).reshape(n, t, d)
+        return nn.dense(params["wo"], out)
 
 
 @functools.partial(jax.checkpoint, prevent_cse=False)
@@ -226,20 +228,22 @@ def _trunk(params: Params, tokens: jax.Array, heads: int) -> jax.Array:
 
     def block(x, blk):
         x = x + _attention(blk["attn"], _layer_norm(blk["ln1"], x), heads)
-        h = nn.dense(blk["mlp"]["fc1"], _layer_norm(blk["ln2"], x))
-        return x + _mlp_tail(blk["mlp"]["fc2"], h)
+        with jax.named_scope("mlp_block"):
+            h = nn.dense(blk["mlp"]["fc1"], _layer_norm(blk["ln2"], x))
+            return x + _mlp_tail(blk["mlp"]["fc2"], h)
 
-    if "blocks" in params:
-        # Scan layout: one traced block body, scanned over the stacked
-        # [depth, ...] leaves — XLA compiles O(1) block HLO in depth instead
-        # of O(depth) inlined copies (the compile-wall fix).
-        x, _ = jax.lax.scan(
-            lambda carry, blk: (block(carry, blk), None), x, params["blocks"]
-        )
-    else:
-        depth = sum(1 for k in params if k.startswith("block_"))
-        for i in range(depth):
-            x = block(x, params[f"block_{i}"])
+    with jax.named_scope("layer_scan"):
+        if "blocks" in params:
+            # Scan layout: one traced block body, scanned over the stacked
+            # [depth, ...] leaves — XLA compiles O(1) block HLO in depth instead
+            # of O(depth) inlined copies (the compile-wall fix).
+            x, _ = jax.lax.scan(
+                lambda carry, blk: (block(carry, blk), None), x, params["blocks"]
+            )
+        else:
+            depth = sum(1 for k in params if k.startswith("block_"))
+            for i in range(depth):
+                x = block(x, params[f"block_{i}"])
     return x
 
 
@@ -247,8 +251,9 @@ def _head(params: Params, hidden: jax.Array) -> jax.Array:
     """Final LayerNorm -> unembedding -> log-softmax over the LAST axis of
     ``hidden`` (``[..., D]`` -> ``[..., vocab]``).  Every step is row-wise, so
     the head of a slice of positions is that slice of the head."""
-    hidden = _layer_norm(params["ln_f"], hidden)
-    return nn.log_softmax(nn.dense(params["head"], hidden))
+    with jax.named_scope("lm_head"):
+        hidden = _layer_norm(params["ln_f"], hidden)
+        return nn.log_softmax(nn.dense(params["head"], hidden))
 
 
 def apply_sequence(

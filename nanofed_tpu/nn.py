@@ -103,7 +103,8 @@ def embed_rows(table: jax.Array, tokens: jax.Array) -> jax.Array:
     same gradient one column band at a time (:func:`_rows_in_bands`); the shape decides,
     as ``ops.attention.engages`` does by sequence length."""
     bands = embed_bands(*table.shape, table.dtype.itemsize)
-    return table[tokens] if bands == 1 else _rows_in_bands(table, tokens, bands)
+    with jax.named_scope("token_embed"):
+        return table[tokens] if bands == 1 else _rows_in_bands(table, tokens, bands)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))

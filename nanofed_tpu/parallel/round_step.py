@@ -266,7 +266,8 @@ def build_sharded_round(
                 sq_norms = jax.vmap(tree_sq_norm)(delta)
             return acc, (result.metrics, sq_norms)
 
-        acc, (metrics, sq_norms) = lax.scan(step_chunk, acc0, chunked)
+        with jax.named_scope("chunk_loop"):
+            acc, (metrics, sq_norms) = lax.scan(step_chunk, acc0, chunked)
         flat = lambda x: x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
         return acc, jax.tree.map(flat, metrics), flat(sq_norms)
 
@@ -377,10 +378,11 @@ def build_sharded_round(
                 lambda x: x.reshape(n_chunks, client_chunk, *x.shape[1:]), (data, rngs)
             )
             with jax.named_scope("local_fit"):
-                result = lax.map(
-                    lambda args: jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, *args),
-                    chunked,
-                )
+                with jax.named_scope("chunk_loop"):
+                    result = lax.map(
+                        lambda args: jax.vmap(fit, in_axes=(None, 0, 0))(gp_v, *args),
+                        chunked,
+                    )
             result = jax.tree.map(
                 lambda x: x.reshape(c_local, *x.shape[2:]), result
             )

@@ -75,21 +75,23 @@ def make_grad_fn(
 
     def loss_fn(params, xb, yb, mb, rng):
         if cdt is not None:
-            params = jax.tree.map(lambda p: p.astype(cdt), params)
-            # Integer inputs (token-id streams) must stay integer: they index an
-            # embedding table, and casting ids to bf16 would corrupt the lookup.
-            # fedlint: disable=FED002 (branches on xb.dtype — static trace-time metadata, not a traced value; both arms compile into one program)
-            if jnp.issubdtype(xb.dtype, jnp.floating):
-                xb = xb.astype(cdt)
+            with jax.named_scope("cast_params"):
+                params = jax.tree.map(lambda p: p.astype(cdt), params)
+                # Integer inputs (token-id streams) must stay integer: they index an
+                # embedding table, and casting ids to bf16 would corrupt the lookup.
+                # fedlint: disable=FED002 (branches on xb.dtype — static trace-time metadata, not a traced value; both arms compile into one program)
+                if jnp.issubdtype(xb.dtype, jnp.floating):
+                    xb = xb.astype(cdt)
         if counted_apply is None:
             logp, counters = apply_fn(params, xb, train=True, rng=rng), {}
         else:
             logp, counters = counted_apply(params, xb, train=True, rng=rng)
-        logp = logp.astype(jnp.float32)
-        nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
-        count = mb.sum()
-        loss = (nll * mb).sum() / jnp.maximum(count, 1.0)
-        correct = ((jnp.argmax(logp, -1) == yb) * mb).sum()
+        with jax.named_scope("nll_loss"):
+            logp = logp.astype(jnp.float32)
+            nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+            count = mb.sum()
+            loss = (nll * mb).sum() / jnp.maximum(count, 1.0)
+            correct = ((jnp.argmax(logp, -1) == yb) * mb).sum()
         return loss, (correct, count, counters)
 
     def grad_fn(params, xb, yb, mb, rng):
@@ -173,20 +175,22 @@ def make_local_fit(
             def step_body(carry, inp):
                 params, opt_state = carry
                 sidx, skey = inp
-                idx = lax.dynamic_slice(perm, (sidx * bsz,), (bsz,))
-                xb, yb, mb = data.x[idx], data.y[idx], data.mask[idx]
+                with jax.named_scope("batch_gather"):
+                    idx = lax.dynamic_slice(perm, (sidx * bsz,), (bsz,))
+                    xb, yb, mb = data.x[idx], data.y[idx], data.mask[idx]
                 grads, stats = grad_fn(params, xb, yb, mb, skey)
-                if config.prox_mu > 0:
-                    prox = tree_scale(tree_sub(params, global_params), config.prox_mu)
-                    grads = jax.tree.map(jnp.add, grads, prox)
-                updates, new_opt_state = tx.update(grads, opt_state, params)
-                if lr_scale is not None:
-                    updates = tree_scale(updates, lr_scale)
-                new_params = optax.apply_updates(params, updates)
-                # A batch of pure padding must be a no-op (both params and opt state).
-                nonempty = stats.count > 0
-                params = tree_where(nonempty, new_params, params)
-                opt_state = tree_where(nonempty, new_opt_state, opt_state)
+                with jax.named_scope("optimizer_step"):
+                    if config.prox_mu > 0:
+                        prox = tree_scale(tree_sub(params, global_params), config.prox_mu)
+                        grads = jax.tree.map(jnp.add, grads, prox)
+                    updates, new_opt_state = tx.update(grads, opt_state, params)
+                    if lr_scale is not None:
+                        updates = tree_scale(updates, lr_scale)
+                    new_params = optax.apply_updates(params, updates)
+                    # A batch of pure padding must be a no-op (both params and opt state).
+                    nonempty = stats.count > 0
+                    params = tree_where(nonempty, new_params, params)
+                    opt_state = tree_where(nonempty, new_opt_state, opt_state)
                 return (params, opt_state), stats
 
             step_keys = jax.random.split(step_key, steps)

@@ -250,10 +250,23 @@ def test_the_expert_layers_counters_reach_the_rounds_metrics():
     assert result.client_metrics.counters["moe_held_pick_share"].shape == (2,)
 
 
-def test_the_seven_scopes_are_in_the_lowered_program(seeded):
+@pytest.fixture(scope="module")
+def lowered_gradient(seeded):
     params, tokens = seeded
     model = get_model("hybrid_lm", **SMALL)
-    text = jax.jit(jax.grad(lambda p: model.apply(p, tokens).sum())).lower(params).as_text(debug_info=True)
+    return jax.jit(jax.grad(lambda p: model.apply(p, tokens).sum())).lower(params).as_text(debug_info=True)
+
+
+def test_the_seven_scopes_are_in_the_lowered_program(lowered_gradient):
     for scope in ("ssm_mixer", "ssm_scan", "moe_router", "moe_dispatch", "moe_experts",
                   "moe_shared", "gqa_attention"):
-        assert scope in text, scope
+        assert scope in lowered_gradient, scope
+
+
+@pytest.mark.parametrize("path", [
+    "jvp(token_embed)/", "transpose(jvp(token_embed))/",
+    "jvp(layer_scan)/squeeze", "transpose(jvp(layer_scan))/",  # the stacked leaves' slices
+    "jvp(lm_head)/dot_general", "transpose(jvp(lm_head))/dot_general",
+])
+def test_the_lookup_and_the_head_have_scopes(lowered_gradient, path):
+    assert path in lowered_gradient, path

@@ -367,10 +367,24 @@ def test_trains_through_the_round_program_with_its_counters():
     assert all(v > 0 for v in jax.tree.leaves(moved))  # every other leaf learns, the router too
 
 
-def test_the_scopes_are_in_the_lowered_program(reference):
+@pytest.fixture(scope="module")
+def lowered_gradient(reference):
     params, tokens = _seeded(reference, SMALL)
     model = get_model("latent_moe_lm", **SMALL)
-    text = jax.jit(jax.grad(lambda p: model.apply(p, tokens).sum())).lower(params).as_text(debug_info=True)
+    return jax.jit(jax.grad(lambda p: model.apply(p, tokens).sum())).lower(params).as_text(debug_info=True)
+
+
+def test_the_scopes_are_in_the_lowered_program(lowered_gradient):
     for scope in ("mla_q", "mla_kv_down", "mla_kv_up", "mla_rope", "mla_attention", "dense_mlp",
                   "moe_router", "moe_shared", "moe_dispatch", "moe_experts"):
-        assert scope in text, scope
+        assert scope in lowered_gradient, scope
+
+
+@pytest.mark.parametrize("path", [
+    "jvp(token_embed)/", "transpose(jvp(token_embed))/",
+    "jvp(layer_scan)/squeeze", "transpose(jvp(layer_scan))/",  # the stacked leaves' slices
+    "checkpoint/attention_proj/dot_general", "rematted_computation/attention_proj/dot_general",
+    "jvp(lm_head)/dot_general", "transpose(jvp(lm_head))/dot_general",
+])
+def test_the_output_projection_the_lookup_and_the_head_have_scopes(lowered_gradient, path):
+    assert path in lowered_gradient, path

@@ -77,3 +77,14 @@ def test_init_is_seed_deterministic():
     p2 = m.init(jax.random.key(42))
     for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("scope", ["cnn_conv1", "cnn_conv2", "cnn_pool", "cnn_fc1", "cnn_fc2"])
+def test_mnist_cnn_scopes_are_in_the_lowered_program(rng, scope):
+    """Forward and backward: the benchmark's per-scope metrics read both passes."""
+    m = get_model("mnist_cnn")
+    params = jax.eval_shape(m.init, rng)
+    x = jax.ShapeDtypeStruct((2, 28, 28, 1), jnp.float32)
+    loss = lambda p, x: m.apply(p, x, train=True, rng=jax.random.key(1)).sum()
+    text = jax.jit(jax.grad(loss)).lower(params, x).as_text(debug_info=True)
+    assert f"jvp({scope})/" in text and f"transpose(jvp({scope}))/" in text

@@ -275,6 +275,28 @@ class TestAttentionFormFollowsTheSequence:
         text = jax.jit(m.apply).lower(params, x).as_text(debug_info=True)
         assert "causal_attention" in text
 
+    @pytest.fixture(scope="class", params=["transformer_lm", "transformer_lm_scan"])
+    def lowered_gradient(self, request):
+        m = get_model(request.param, vocab=VOCAB, seq_len=SEQ, width=WIDTH, depth=DEPTH,
+                      heads=HEADS)
+        params = jax.eval_shape(m.init, jax.random.key(0))
+        x = jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
+        grad = jax.jit(jax.grad(lambda p, x: m.apply(p, x).sum()))
+        return request.param, grad.lower(params, x).as_text(debug_info=True)
+
+    @pytest.mark.parametrize("scope", ["token_embed", "layer_scan", "attention_proj",
+                                       "mlp_block", "lm_head"])
+    def test_the_block_and_the_head_have_scopes_forward_and_backward(self, lowered_gradient,
+                                                                     scope):
+        name, text = lowered_gradient
+        if scope not in ("attention_proj", "mlp_block"):
+            assert f"/jvp({scope})/" in text and f"/transpose(jvp({scope}))/" in text
+        elif name == "transformer_lm_scan":  # the scanned body's paths start at the body
+            assert f'"{scope}/' in text
+        else:  # a block's scopes nest in the trunk's
+            assert f"/jvp(layer_scan)/{scope}/" in text
+            assert f"/transpose(jvp(layer_scan))/{scope}/" in text
+
     @pytest.mark.parametrize("name", ["transformer_lm", "transformer_lm_scan"])
     def test_short_sequences_keep_their_values_bit_for_bit(self, name, monkeypatch):
         """At the file's ``SEQ`` the dense form runs and ``apply`` returns what it did
