@@ -12,6 +12,15 @@ work on blocks nobody fills).  What experts held elsewhere would add is left out
 chip the layer runs without its exchange, and a sum over all the shares is the uncut
 layer (tests).
 
+The dispatch is integer layout work with no gradient, and the loop's hand-written
+backward reads its three outputs (``src``, ``block_expert``, the trip count).  Under a
+layer's ``jax.checkpoint`` they would be rebuilt in the backward pass: the same sort of
+the same keys.  :func:`held_experts` names them (:data:`KEPT`,
+``jax.ad_checkpoint.checkpoint_name``), and a checkpoint given
+:data:`KEEP_NAMED_OUTPUTS` as its policy keeps them (``int32[rows]``, ``int32[rows //
+block]`` and a scalar a layer) and runs the dispatch once a step; the three models'
+rematerialized layers do.  Outside such a checkpoint a name is the identity.
+
 An expert is ``W_out act(W_in x)``; ``act`` is an :class:`Activation`, a parameter of
 the loop and of its hand-written backward: :data:`RELU2` on ``[rows, f]``, :data:`REGLU`
 and :data:`SWIGLU` on a fused ``[rows, 2f]`` product (``W_gate | W_up`` stored as one
@@ -26,6 +35,9 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from nanofed_tpu.ops import attention
 
 _F32 = jnp.float32
 
@@ -33,6 +45,14 @@ _F32 = jnp.float32
 #: all picks that landed on held experts; the held experts' largest token count over
 #: their mean (1.0 is even); rows taken over rows of the blocks the loop ran.
 COUNTERS = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_block_fill")
+#: What :func:`expert_blocks`' backward reads of the dispatch, ``src``, ``block_expert``
+#: and the trip count, by the names :func:`held_experts` gives them ...
+KEPT = ("moe_dispatch_src", "moe_dispatch_block_expert", "moe_dispatch_n_blocks")
+#: ... and the ONE ``jax.checkpoint`` policy of the zoo's rematerialized layers: it keeps
+#: exactly what carries a name, the attention kernels' output and log-sum-exp where they
+#: run and the dispatch's three where an expert layer runs, and everything else in a
+#: layer is recomputed as under a plain checkpoint.  A layer with neither keeps nothing.
+KEEP_NAMED_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*attention.KEPT, *KEPT)
 
 
 class Activation(NamedTuple):
@@ -210,9 +230,11 @@ def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int, block: in
         first_pick = jnp.cumsum(counts) - counts
         src = jnp.where(taken, order[jnp.clip(first_pick[expert] + rank, 0, n * top_k - 1)],
                         n * top_k)
+        src, block_expert, n_blocks = map(
+            checkpoint_name, (src, block_expert, ends[-1] // block), KEPT)
     with jax.named_scope("moe_experts"):
-        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert,
-                            ends[-1] // block, w_in, w_out, activation, block)
+        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert, n_blocks,
+                            w_in, w_out, activation, block)
     landed = counts.sum().astype(_F32)
     even = jnp.where(landed > 0, counts.max() * held / jnp.maximum(landed, 1.0), 1.0)
     fill = jnp.where(landed > 0, landed / jnp.maximum(ends[-1], 1).astype(_F32), 1.0)

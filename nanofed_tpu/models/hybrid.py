@@ -12,7 +12,10 @@ that position's hidden state alone.  Layers of one kind are stacked on a leading
 ``[E layers, experts held, width, expert width]``), so the tree has the same few leaves
 at any depth; the forward pass walks the pattern and takes each layer's slice.  Every
 layer is rematerialized (``jax.checkpoint``): the backward pass keeps one ``[N, T,
-width]`` activation a layer and recomputes inside it.
+width]`` activation a layer and recomputes inside it, but for what carries a name
+(``models.experts.KEEP_NAMED_OUTPUTS``): an ``E`` layer's integer dispatch layout
+(``src``, ``block_expert``, the trip count: under 0.3 MB), so its picks are sorted once
+a step; a mixer or an attention layer names nothing and keeps nothing.
 
 **Mamba-2** (``ssm_mixer``): ``[z | xBC | dt] = u W_in``; a causal depthwise convolution
 and SiLU on ``xBC``; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t +
@@ -49,7 +52,7 @@ from jax import lax
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
-from nanofed_tpu.models.experts import RELU2, held_experts, sigmoid_route
+from nanofed_tpu.models.experts import KEEP_NAMED_OUTPUTS, RELU2, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
 
 #: Rows a block of the expert loop holds: a held expert's picks are padded to whole
@@ -309,7 +312,7 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
             index = seen[letter]
             seen[letter] += 1
 
-            @jax.checkpoint
+            @partial(jax.checkpoint, policy=KEEP_NAMED_OUTPUTS)
             def layer(p, x, mixer=mixer):
                 mixed, counted = mixer(p, rms_norm(p["norm"], x, cfg["eps"]), cfg)
                 return x + mixed, counted

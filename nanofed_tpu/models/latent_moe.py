@@ -25,10 +25,12 @@ auxiliary loss, are not built.  Like ``moe_decoder_lm`` it drops into the standa
 federated pipeline: ``apply`` returns next-token log-probabilities at the LAST position
 (``[N, vocab]``); the dense layers' leaves are stacked on a leading axis under
 ``params["dense"]``, the expert layers' under ``params["moe"]``, and every layer is
-rematerialized (``jax.checkpoint``) but for the attention kernels' output and
-log-sum-exp (``ops.attention.KEEP_KERNEL_OUTPUTS``: one ``[N, heads, T, value]`` array a
-layer is kept beside the layer's input, and the backward pass does not launch the
-forward kernel again).
+rematerialized (``jax.checkpoint``) but for what carries a name
+(``models.experts.KEEP_NAMED_OUTPUTS``): the attention kernels' output and log-sum-exp
+(one ``[N, heads, T, value]`` array a layer beside the layer's input) and, in an expert
+layer, the dispatch's integer layout (``src``, ``block_expert``, the trip count: under
+0.3 MB), so the backward pass neither launches the forward kernel nor sorts the picks
+again.
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions, with score heads of ``nope + rope``
@@ -55,12 +57,12 @@ from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
-from nanofed_tpu.models.experts import COUNTERS, SWIGLU, held_experts, sigmoid_route
+from nanofed_tpu.models.experts import (
+    COUNTERS, KEEP_NAMED_OUTPUTS, SWIGLU, held_experts, sigmoid_route)
 from nanofed_tpu.models.hybrid import rms_norm
 from nanofed_tpu.models.moe_decoder import rotate
 from nanofed_tpu.nn import embed_rows
-from nanofed_tpu.ops.attention import (
-    KEEP_KERNEL_OUTPUTS, causal_attention, dense_causal_attention, engages)
+from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
 #: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
 #: block costs).  This model's own number, measured at its cell (8192 tokens a step, 6 of
@@ -178,7 +180,7 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     with jax.named_scope("layer_scan"):
         for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
             layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"),
-                                   policy=KEEP_KERNEL_OUTPUTS)
+                                   policy=KEEP_NAMED_OUTPUTS)
             for index in range(count):
                 x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
                 counters = counters + counted

@@ -18,9 +18,11 @@ feed-forward.  Like ``hybrid_lm`` it drops into the standard federated pipeline:
 the layers are stacked on a leading axis (``params["layers"]["wq"]`` is ``[layers, d,
 heads * head_dim]``, the experts ``[layers, experts held, d, 2 f]`` and ``[layers,
 experts held, f, d]``), and every layer is rematerialized (``jax.checkpoint``) but for
-the attention kernels' output and log-sum-exp (``ops.attention.KEEP_KERNEL_OUTPUTS``: one
-``[N, heads, T, head_dim]`` array a layer is kept beside the layer's input, and the
-backward pass does not launch the forward kernel again).
+what carries a name (``models.experts.KEEP_NAMED_OUTPUTS``): the attention kernels'
+output and log-sum-exp (one ``[N, heads, T, head_dim]`` array a layer beside the layer's
+input) and the expert dispatch's integer layout (``src``, ``block_expert``, the trip
+count: under 0.3 MB), so the backward pass neither launches the forward kernel nor
+sorts the picks again.
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions — grouped heads read their key/value
@@ -46,11 +48,10 @@ from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
-from nanofed_tpu.models.experts import COUNTERS, REGLU, held_experts
+from nanofed_tpu.models.experts import COUNTERS, KEEP_NAMED_OUTPUTS, REGLU, held_experts
 from nanofed_tpu.models.hybrid import rms_norm
 from nanofed_tpu.nn import embed_rows
-from nanofed_tpu.ops.attention import (
-    KEEP_KERNEL_OUTPUTS, causal_attention, dense_causal_attention, engages)
+from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
 #: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
 #: block costs).  A model's own number, measured at its cell (8192 tokens a step, 6 of
@@ -157,7 +158,7 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
         for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
             layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),
                                            window=cfg["window"] if windowed else None),
-                                   policy=KEEP_KERNEL_OUTPUTS)
+                                   policy=KEEP_NAMED_OUTPUTS)
             x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x)
             counters = counters + counted
     return x, counters

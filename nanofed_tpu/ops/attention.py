@@ -29,8 +29,8 @@ products a block pair, against the forward's two.  Under a ``jax.checkpoint`` th
 are rebuilt in the backward pass, the last two by launching the forward kernel again;
 the forward rule names those two (``jax.ad_checkpoint.checkpoint_name``), and a
 checkpoint given :data:`KEEP_KERNEL_OUTPUTS` as its policy keeps them and rebuilds
-``q``, ``k``, ``v`` alone: one forward launch a layer (the two decoders with
-rematerialized layers do).  Outside such a checkpoint a name is the identity.
+``q``, ``k``, ``v`` alone: one forward launch a layer (``models.experts.KEEP_NAMED_OUTPUTS``
+keeps :data:`KEPT` so).  Outside such a checkpoint a name is the identity.
 
 Grouped queries (``k``, ``v`` of ``[N, H_kv, T, hd]``, ``H`` a multiple of ``H_kv``): query
 head ``h`` reads key/value head ``h // (H / H_kv)`` through the block index alone, so no
@@ -87,14 +87,14 @@ MAX_SEQ = 8192
 #: What the backward kernel reads of the forward kernel's own work, the output and the
 #: log-sum-exp, by the names :func:`_attend_fwd` gives them (the identity wherever no
 #: checkpoint asks for a name) ...
-_KEPT = ("causal_attention_out", "causal_attention_lse")
+KEPT = ("causal_attention_out", "causal_attention_lse")
 #: ... and the ``jax.checkpoint`` policy that keeps exactly those two: a rematerialized
 #: layer that calls :func:`causal_attention` and passes this as ``policy`` recomputes
 #: ``q``, ``k``, ``v`` and everything around them as a plain checkpoint does, and does
 #: not launch the forward kernel a second time.  It costs one ``[N, H, T, hd_v]`` array in
 #: the inputs' dtype (and ``N * H * T`` float32) a layer; where the dense spelling
 #: answers nothing carries a name and nothing is kept.
-KEEP_KERNEL_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*_KEPT)
+KEEP_KERNEL_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*KEPT)
 #: Scoped VMEM the backward kernel may take where heads are grouped (a v5e has 128 MiB).
 GROUPED_BWD_VMEM = 32 * 1024 * 1024
 #: ... and both kernels where a score head is wider than 128.
@@ -339,7 +339,7 @@ def _attend(q, k, v, block, interpret, window):
 
 def _attend_fwd(q, k, v, block, interpret, window):
     o_t, lse = _forward(q, k, v, block, interpret, window)
-    o, lse = map(checkpoint_name, (jnp.swapaxes(o_t, 1, 2), lse), _KEPT)
+    o, lse = map(checkpoint_name, (jnp.swapaxes(o_t, 1, 2), lse), KEPT)
     return o, (q, k, v, o, lse)
 
 

@@ -45,21 +45,32 @@ def np_rng():
 
 
 @pytest.fixture(scope="session")
-def kernel_calls():
-    """``kernel_calls(fn, *args)``: every launch of an attention kernel in ``fn``'s jaxpr by
-    the kernel's name (a ``pallas_call``, or a ``jit`` equation that stands in for one),
-    counted equation by equation through every nested jaxpr: two layers that share one
-    traced function are printed once and launched twice."""
+def equations():
+    """``equations(fn, *args)``: every equation of ``fn``'s jaxpr, those of every nested
+    jaxpr (a rematerialized body, a loop's, a ``custom_vjp``'s rules) included, each where
+    it stands: two layers that share one traced function are printed once and run twice."""
 
-    def count(jaxpr, calls):
+    def walk(jaxpr):
         for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return lambda fn, *args: list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.fixture(scope="session")
+def kernel_calls(equations):
+    """``kernel_calls(fn, *args)``: every launch of an attention kernel in ``fn``'s jaxpr by
+    the kernel's name (a ``pallas_call``, or a ``jit`` equation that stands in for one)."""
+
+    def count(fn, *args):
+        calls = {}
+        for eqn in equations(fn, *args):
             name = eqn.params.get("name")
             if (eqn.primitive.name in ("pallas_call", "jit", "pjit") and isinstance(name, str)
                     and name.startswith("causal_attention_")):
                 calls[name] = calls.get(name, 0) + 1
-            else:
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    count(sub, calls)
         return calls
 
-    return lambda fn, *args: count(jax.make_jaxpr(fn)(*args).jaxpr, {})
+    return count

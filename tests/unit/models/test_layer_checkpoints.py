@@ -1,8 +1,10 @@
-"""What the two decoders with rematerialized layers keep for the backward pass
-(``models.moe_decoder``, ``models.latent_moe``): every layer under ``jax.checkpoint`` with
-``ops.attention.KEEP_KERNEL_OUTPUTS``, so the forward kernel's output and log-sum-exp stay
-and the kernel is launched once a layer; a plain checkpoint (the policy taken away, as
-each test does for its other side) launches it twice and computes the same bits."""
+"""What the three models with rematerialized layers keep for the backward pass
+(``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``): every layer under
+``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS``, so the attention kernel's
+output and log-sum-exp stay and the kernel is launched once a layer, and the expert
+dispatch's three integer outputs stay and its sort runs once a layer; a plain checkpoint
+(the policy taken away, as each test does for its other side) launches and sorts twice
+and computes the same bits."""
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +13,7 @@ import pytest
 
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.core.types import ClientData
-from nanofed_tpu.models import get_model, latent_moe, moe_decoder
+from nanofed_tpu.models import experts, get_model, hybrid, latent_moe, moe_decoder
 from nanofed_tpu.ops import attention
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
@@ -20,7 +22,8 @@ from nanofed_tpu.trainer import TrainingConfig
 #: ``(factory, its module, a tiny configuration)``; at 512 positions the kernels engage
 #: (Pallas's interpreter here), at 32 the dense spelling answers.  One full and three
 #: windowed layers with seven query heads a key/value head; one dense and two expert
-#: layers with 24-wide scores over 16-wide values.
+#: layers with 24-wide scores over 16-wide values; two mixers, one attention layer that
+#: never runs the kernels and two expert layers.
 DECODERS = {
     "moe_decoder": ("moe_decoder_lm", moe_decoder, {
         "vocab": 64, "seq_len": 512, "width": 64, "rope_layout": [0, 1, 1, 1],
@@ -30,13 +33,20 @@ DECODERS = {
         "vocab": 64, "seq_len": 512, "width": 64, "heads": 4, "latent_rank": 32, "nope_dim": 16,
         "rope_dim": 8, "value_dim": 16, "dense_layers": 1, "dense_width": 160, "expert_layers": 2,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 48}),
+    "hybrid": ("hybrid_lm", hybrid, {"vocab": 64, "seq_len": 32, "pattern": "MEM*E"}),
 }
 #: Launches a training step of each holds, forward kernels under the policy first.
 LAUNCHES = {
     "moe_decoder": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
                     "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
     "latent_moe": {"causal_attention_fwd": 3, "causal_attention_bwd": 3},
+    "hybrid": {},
 }
+#: Expert layers of each: a dispatch, and so a sort, apiece.
+EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2}
+#: Fewer layers of each for the tests that run a step operation by operation.
+SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
+           "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "ME"}}
 
 
 @pytest.fixture(params=list(DECODERS))
@@ -50,7 +60,7 @@ def decoder(request, monkeypatch):
         tokens = jax.random.randint(jax.random.key(1), (1, model.input_shape[0]), 0, kwargs["vocab"])
         return model, model.init(jax.random.key(0)), tokens
 
-    return request.param, build, lambda: monkeypatch.setattr(module, "KEEP_KERNEL_OUTPUTS", None)
+    return request.param, build, lambda: monkeypatch.setattr(module, "KEEP_NAMED_OUTPUTS", None)
 
 
 def _training_step(model, tokens, cast=lambda p: p):
@@ -60,6 +70,13 @@ def _training_step(model, tokens, cast=lambda p: p):
         return -logp[:, 7].mean()
 
     return jax.value_and_grad(loss)
+
+
+@pytest.fixture
+def sorts(equations):
+    """``sorts(fn, *args)``: the ``sort`` equations of ``fn``'s jaxpr, the rematerialized
+    bodies included."""
+    return lambda fn, *args: sum(eqn.primitive.name == "sort" for eqn in equations(fn, *args))
 
 
 def test_a_training_step_launches_each_attention_kernel_once_a_layer(decoder, kernel_calls):
@@ -72,17 +89,28 @@ def test_a_training_step_launches_each_attention_kernel_once_a_layer(decoder, ke
                        for kernel, n in LAUNCHES[name].items()}
 
 
+def test_a_training_step_sorts_the_picks_once_an_expert_layer(decoder, sorts):
+    """The dispatch's ``argsort`` is the step's only sort: one an expert layer where the
+    checkpoint keeps the layout, a second in every rematerialized body where it does not."""
+    name, build, plainly = decoder
+    _, params, tokens = build()
+    count = lambda: sorts(_training_step(build()[0], tokens), params)
+    assert count() == EXPERT_LAYERS[name]
+    plainly()
+    assert count() == 2 * EXPERT_LAYERS[name]
+
+
 @pytest.mark.parametrize("cast,whole", [(lambda p: p, True), (lambda p: p.astype(jnp.bfloat16), False)],
                          ids=["float32", "bfloat16"])
 def test_what_the_checkpoint_keeps_changes_no_bit_of_a_training_step(decoder, cast, whole):
     """Loss and every leaf's gradient equal the plain checkpoint's exactly: the backward
-    kernel reads the same two arrays, kept instead of computed again.  In bfloat16 the
+    kernel reads the same two arrays and the expert loop's backward the same three
+    integers, kept instead of computed again.  In bfloat16 the
     step runs operation by operation: compiled whole, XLA keeps a bfloat16 value unrounded
     inside a fusion (its excess precision), and the two programs, one forward kernel
     apart, fuse differently around the kernels."""
-    _, build, plainly = decoder
-    layers = {"rope_layout": [0, 1], "window_layout": [0, 1]} if decoder[0] == "moe_decoder" else {
-        "expert_layers": 1}
+    name, build, plainly = decoder
+    layers = SHALLOW[name]
     _, params, tokens = build(**layers)
 
     def step():
@@ -95,22 +123,29 @@ def test_what_the_checkpoint_keeps_changes_no_bit_of_a_training_step(decoder, ca
     kept = step()
     plainly()
     plain = step()
-    moved = [float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(plain[1])]
-    assert sum(moved) > len(moved) // 2  # one label: the last layer's experts may get none
+    moved = [float(jnp.abs(g).sum()) > 0 for g in jax.tree.leaves(plain[1])]
+    # One label: the last layer's experts may get none; a kind with no layer has empty leaves.
+    assert sum(moved) > len(moved) // 2
     jax.tree.map(np.testing.assert_array_equal, kept, plain)
 
 
-def test_under_512_positions_the_checkpoint_keeps_nothing(decoder):
-    """The dense spelling answers, nothing carries a name, and the step is the plain
-    checkpoint's program."""
-    _, build, plainly = decoder
+def test_under_512_positions_the_checkpoint_keeps_the_dispatch_alone(decoder, monkeypatch, equations,
+                                                                     sorts):
+    """The dense spelling answers and the only names left are the dispatch's: a
+    checkpoint that keeps the kernels' names alone finds nothing to keep and its step is
+    the plain checkpoint's program, two sorts an expert layer; the models' own keeps one."""
+    name, build, plainly = decoder
     _, params, tokens = build(seq_len=32)
     step = lambda: _training_step(build(seq_len=32)[0], tokens)
     lowered = lambda: jax.jit(step()).lower(params).as_text()
-    assert "name[name=" not in str(jax.make_jaxpr(step())(params))
-    kept = lowered()
+    named = {eqn.params["name"] for eqn in equations(step(), params) if eqn.primitive.name == "name"}
+    assert named == set(experts.KEPT)
+    assert sorts(step(), params) == EXPERT_LAYERS[name]
+    monkeypatch.setattr(DECODERS[name][1], "KEEP_NAMED_OUTPUTS", attention.KEEP_KERNEL_OUTPUTS)
+    kernels_alone = lowered()
+    assert sorts(step(), params) == 2 * EXPERT_LAYERS[name]
     plainly()
-    assert lowered() == kept
+    assert lowered() == kernels_alone
 
 
 @pytest.fixture
@@ -161,20 +196,24 @@ def kernels_in_plain_jax(monkeypatch):
 
 
 @pytest.mark.parametrize("client_chunk", [None, 1], ids=["vmap", "chunks-of-1"])
-def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chunk,
+def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chunk, sorts,
                                                              kernels_in_plain_jax, kernel_calls):
     """The ``shard_map`` round over four devices, clients under ``vmap`` or one at a time,
     the local steps a scan: the names reach the layers' checkpoints through all of them
-    (one forward launch a layer in the round's jaxpr, two under a plain checkpoint), and a
-    round leaves the parameters where the plain checkpoint's round leaves them."""
+    (one forward launch a layer and one sort an expert layer in the round's jaxpr, two
+    under a plain checkpoint), and a round leaves the parameters where the plain
+    checkpoint's round leaves them."""
     name, build, plainly = decoder
     model, params, _ = build()
     layers = sum(LAUNCHES[name].values()) // 2
+    launches = lambda forward: {f"causal_attention_{kind}": n for kind, n in (
+        ("fwd", forward * layers), ("bwd", layers)) if n}
+    shuffle = 1  # the local fit's own sort: an epoch's permutation of a client's rows
     mesh = make_mesh(devices=jax.devices()[:4])
     training = TrainingConfig(batch_size=1, local_epochs=1, learning_rate=0.05)
     strategy = fedavg_strategy()
     k = jax.random.split(jax.random.key(5), 2)
-    data = ClientData(x=jax.random.randint(k[0], (4, 2, 512), 0, 64),
+    data = ClientData(x=jax.random.randint(k[0], (4, 2, *model.input_shape), 0, 64),
                       y=jax.random.randint(k[1], (4, 2), 0, 64), mask=jnp.ones((4, 2)))
     args = (params, init_server_state(strategy, params), data, jnp.full((4,), 2.0),
             jax.random.split(jax.random.key(6), 4))
@@ -182,13 +221,13 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     def one_round():
         step = build_round_step(build()[0].apply, training, mesh, strategy,
                                 client_chunk=client_chunk, params_like=params)
-        return kernel_calls(step, *args), step(*args).params
+        return kernel_calls(step, *args), sorts(step, *args), step(*args).params
 
-    calls, kept = one_round()
-    assert calls == {"causal_attention_fwd": layers, "causal_attention_bwd": layers}
+    calls, sorted_, kept = one_round()
+    assert (calls, sorted_) == (launches(1), shuffle + EXPERT_LAYERS[name])
     plainly()
-    calls, plain = one_round()
-    assert calls == {"causal_attention_fwd": 2 * layers, "causal_attention_bwd": layers}
+    calls, sorted_, plain = one_round()
+    assert (calls, sorted_) == (launches(2), shuffle + 2 * EXPERT_LAYERS[name])
     moved = [float(jnp.abs(a - b).max()) > 0
              for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(params))]
     assert sum(moved) >= len(moved) - 1

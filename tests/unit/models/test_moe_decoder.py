@@ -11,6 +11,7 @@ window ignored, rotation off, the router reading the wrong tensor) passes severa
 over — each of those is also tested on its own below."""
 
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -22,6 +23,7 @@ from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.models import experts, get_model, hybrid, moe_decoder
+from nanofed_tpu.ops import attention
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
 from nanofed_tpu.trainer import TrainingConfig
@@ -314,6 +316,54 @@ def test_block_fill_by_hand():
     _, counted = experts.held_experts(x, picks, weights, w_in, w_out, first_expert=0, block=4,
                                       activation=experts.REGLU)
     np.testing.assert_allclose(counted, [1.0, 5 * 2 / 6, 0.5], rtol=1e-6)
+
+
+def _routed_layer(block):
+    """``(layer(x, router, w_in, w_out) -> [40, 32], its arguments)``: three picks of
+    eight experts, the first four held, in blocks of ``block`` rows."""
+    k = jax.random.split(jax.random.key(11), 4)
+    args = (jax.random.normal(k[0], (40, 32)), jax.random.normal(k[1], (32, 8)),
+            0.2 * jax.random.normal(k[2], (4, 32, 24)), 0.2 * jax.random.normal(k[3], (4, 12, 32)))
+
+    def layer(x, router, w_in, w_out):
+        picks, weights = experts.sigmoid_route(router, 2 * x, 3, 1.0)
+        return experts.held_experts(2 * x, picks, weights, w_in, w_out, first_expert=0,
+                                    block=block, activation=experts.REGLU)[0]
+
+    return layer, args
+
+
+def test_a_checkpoint_keeps_the_dispatchs_three_outputs_and_nothing_else(capsys):
+    """Beside the checkpoint's inputs: ``src`` (one int32 a row of the layout: 40 tokens'
+    3 picks and a block to spare an expert, in whole blocks), ``block_expert`` (one a
+    block) and the trip count; not the tokens, the picks or their weights.  A plain
+    checkpoint, or one that keeps the attention kernels' names alone, keeps none."""
+    layer, args = _routed_layer(block=8)
+    rows = 40 * 3 + 4 * 8
+
+    def kept(policy):
+        jax.ad_checkpoint.print_saved_residuals(jax.checkpoint(layer, policy=policy), *args)
+        lines = capsys.readouterr().out.strip().splitlines()
+        assert all("from the argument" in line for line in lines[:len(args)])
+        return [line.split()[0] for line in lines[len(args):]]
+
+    assert kept(experts.KEEP_NAMED_OUTPUTS) == [f"i32[{rows}]", f"i32[{rows // 8}]", "i32[]"]
+    assert kept(None) == kept(attention.KEEP_KERNEL_OUTPUTS) == []
+
+
+def test_outside_a_checkpoint_the_dispatchs_names_change_nothing(monkeypatch, equations):
+    """``held_experts`` under no checkpoint: value and gradients lower to the same
+    StableHLO with the names and without (a name is an equation of the jaxpr and lowers
+    to nothing; ``tests/unit/ops/test_attention.py`` says why the symbols' numbers go)."""
+    layer, args = _routed_layer(block=8)
+    step = lambda: jax.value_and_grad(lambda *a: layer(*a).sum(), argnums=(0, 1, 2, 3))
+    lowered = lambda: re.sub(r"(@[A-Za-z_]+)_\d+\b", r"\1", jax.jit(step()).lower(*args).as_text())
+    names = lambda: [eqn.params["name"] for eqn in equations(step(), *args)
+                     if eqn.primitive.name == "name"]
+    named = lowered()
+    assert tuple(names()) == experts.KEPT
+    monkeypatch.setattr(experts, "checkpoint_name", lambda x, name: x)
+    assert names() == [] and lowered() == named
 
 
 @pytest.mark.parametrize("activation,width", [(experts.RELU2, 12), (experts.REGLU, 24)],

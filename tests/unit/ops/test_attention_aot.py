@@ -194,7 +194,7 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
 
 #: The two cells' layers at their published widths and 8192 positions, a small vocabulary
 #: around them: ``(factory, module, kwargs, layers, bytes of the output and log-sum-exp a
-#: layer keeps)``.
+#: layer keeps)``.  An expert layer keeps its dispatch's layout too: under 0.3 MB.
 DECODERS = {
     "smallthinker": ("moe_decoder_lm", moe_decoder, dict(
         vocab=1024, seq_len=8192, width=2560, rope_layout=[1], window_layout=[1], window=4096,
@@ -233,7 +233,7 @@ def decoder_steps(request, one_chip):
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(attention, "auto_interpret", lambda interpret: False)
         kept = compile_step()
-        patch.setattr(module, "KEEP_KERNEL_OUTPUTS", None)
+        patch.setattr(module, "KEEP_NAMED_OUTPUTS", None)
         plain = compile_step()
     return {"kept": kept, "plain": plain, "layers": layers, "kept_bytes": layers * kept_bytes}
 
@@ -253,3 +253,12 @@ def test_a_rematerialized_layer_launches_the_forward_kernel_once(decoder_steps):
     temp = {which: decoder_steps[which].memory_analysis().temp_size_in_bytes
             for which in ("kept", "plain")}
     assert temp["kept"] - temp["plain"] <= decoder_steps["kept_bytes"] + SLACK, temp
+
+
+def test_a_rematerialized_expert_layer_sorts_its_picks_once(decoder_steps):
+    """What the TPU's compiler leaves of the dispatch's rerun once its three outputs are
+    kept: each cell has one expert layer here, and the plain checkpoint's program one
+    ``sort`` more than the kept one's."""
+    sorts = {which: len(re.findall(r" sort\(", decoder_steps[which].as_text()))
+             for which in ("kept", "plain")}
+    assert sorts["kept"] >= 1 and sorts["plain"] == sorts["kept"] + 1, sorts
