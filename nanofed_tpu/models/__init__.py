@@ -3,6 +3,7 @@ the ResNets serve the BASELINE.json benchmark configs)."""
 
 from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
     hybrid,
+    indexed_moe,
     latent_moe,
     linear,
     mnist,
@@ -12,6 +13,7 @@ from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
 )
 from nanofed_tpu.models.base import Model, get_model, list_models, register_model
 from nanofed_tpu.models.hybrid import hybrid_lm
+from nanofed_tpu.models.indexed_moe import indexed_moe_lm
 from nanofed_tpu.models.latent_moe import latent_moe_lm
 from nanofed_tpu.models.mnist import mnist_cnn
 from nanofed_tpu.models.moe_decoder import moe_decoder_lm
@@ -29,6 +31,7 @@ __all__ = [
     "list_models",
     "register_model",
     "hybrid_lm",
+    "indexed_moe_lm",
     "latent_moe_lm",
     "mnist_cnn",
     "moe_decoder_lm",

@@ -48,11 +48,17 @@ COUNTERS = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_block_fill")
 #: What :func:`expert_blocks`' backward reads of the dispatch, ``src``, ``block_expert``
 #: and the trip count, by the names :func:`held_experts` gives them ...
 KEPT = ("moe_dispatch_src", "moe_dispatch_block_expert", "moe_dispatch_n_blocks")
+#: The name ``models.indexed_moe`` gives a layer's pick of keys, the mask its attention
+#: runs under: integer work with no gradient, like the dispatch (spelled here because that
+#: module imports this one).
+INDEXER_KEPT = ("indexer_keep",)
 #: ... and the ONE ``jax.checkpoint`` policy of the zoo's rematerialized layers: it keeps
 #: exactly what carries a name, the attention kernels' output and log-sum-exp where they
-#: run and the dispatch's three where an expert layer runs, and everything else in a
-#: layer is recomputed as under a plain checkpoint.  A layer with neither keeps nothing.
-KEEP_NAMED_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*attention.KEPT, *KEPT)
+#: run, the dispatch's three where an expert layer runs and the pick where an indexer
+#: runs, and everything else in a layer is recomputed as under a plain checkpoint.  A
+#: layer with none of them keeps nothing.
+KEEP_NAMED_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
+    *attention.KEPT, *KEPT, *INDEXER_KEPT)
 
 
 class Activation(NamedTuple):

@@ -41,9 +41,20 @@ wholly behind the window are never visited, the one or two blocks its trailing e
 are masked, forward and backward (:func:`_window_steps`).  A value head of another size
 (``hd_v != hd``): the forward's ``V`` scratch, accumulator and output are ``hd_v`` wide,
 ``q`` and ``K`` ``hd``; of the backward's five products three run over ``hd`` (scores,
-``dK``, ``dQ``) and two over ``hd_v`` (``dP``, ``dV``); no column is padded.  All three
-are static Python branches: with full heads, no window and one head size the kernels
-trace to the program they were.
+``dK``, ``dQ``) and two over ``hd_v`` (``dP``, ``dV``); no column is padded.  A mask the
+program computed (``keep=``: one ``int8`` a (key, query) pair of a sequence, shared by
+all its heads, laid out as the kernels' score blocks are, keys down and queries along:
+``keep[n, s, t]``): pair ``(t, s)`` is seen iff ``s <= t`` and ``keep[n, s, t] != 0``.
+Every block pair on or under the diagonal is visited and each score block takes its
+``[block, block]`` tile of the mask.  The forward's grid then puts a group's query
+heads innermost, (key/value head, query block, head of the group), so the block's strip
+of the mask (``[T, block]``, 4 MiB at 8192) is fetched once a key/value head and all the
+group's heads use it; the backward keeps its (head, key block) order, whose resident
+operands are a head's own (``q``, ``dO``, the float32 ``dQ``), and fetches the key
+block's strip (``[block, T]``) a step, under the step's products.  A row that keeps no
+key at all is the caller's error (its output is no softmax of anything).  All four
+are static Python branches: with full heads, no window, one head size and no mask the
+kernels trace to the program they were.
 
 Precision: scores, softmax statistics and every accumulator are float32; the
 probabilities (and ``dS``) are cast to the inputs' dtype for the products that consume
@@ -138,15 +149,22 @@ def _window_steps(window: int, block: int) -> tuple[int, int]:
     return window // block, (window + block - 2) // block + 1
 
 
-def _scores(k, q, *, scale, fold, masked, behind=None, window=None):
+def _scores(k, q, *, scale, fold, masked, behind=None, window=None, keep=None):
     """A score block transposed, ``[keys, queries]`` float32, from a key block and a query
     block.  ``masked`` is for the diagonal block, whose first rows share a position:
     inside it a key past its query gets ``_MASKED``.  ``behind`` (with ``window``) is how
     many positions the key block starts behind the query block: a key the window's
-    length or more behind its query gets ``_MASKED`` too."""
+    length or more behind its query gets ``_MASKED`` too.  ``keep`` is the block's tile of a
+    computed mask, ``[keys, queries]`` int8: a pair whose entry is 0 gets ``_MASKED``."""
     s = lax.dot_general(k, q, _NT, preferred_element_type=_F32)
     if not fold:
         s = s * scale
+    if keep is not None:
+        kept = keep.astype(jnp.int32) != 0
+        if masked:
+            kept &= (lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                     <= lax.broadcasted_iota(jnp.int32, s.shape, 1))
+        return jnp.where(kept, s, _MASKED)
     if masked or behind is not None:
         ki = lax.broadcasted_iota(jnp.int32, s.shape, 0)
         qi = lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -160,12 +178,14 @@ def _scores(k, q, *, scale, fold, masked, behind=None, window=None):
     return s
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fold,
-                window=None):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, keep=False):
+    keep_ref, (o_ref, lse_ref, vt_ref) = (rest[0], rest[1:]) if keep else (None, rest)
     i = pl.program_id(1)
 
-    @pl.when(i == 0)
-    def _():  # once a head: V with the sequence along the lanes
+    # Once a key/value head: V with the sequence along the lanes.  Under a computed mask
+    # the grid's innermost axis walks the group's query heads, which share the scratch.
+    @pl.when((i == 0) & (pl.program_id(2) == 0) if keep else i == 0)
+    def _():
         vt_ref[...] = v_ref[...].T
 
     q = q_ref[...]
@@ -179,7 +199,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
         # Key down the sublanes, query along the lanes: the softmax's reductions run
         # down the sublanes, vreg against vreg.
         s = _scores(k_ref[rows, :], q, scale=scale, fold=fold, masked=masked,
-                    behind=behind, window=window)
+                    behind=behind, window=window,
+                    keep=None if keep_ref is None else keep_ref[rows, :])
         m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -207,8 +228,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, *, block, scale, fo
     lse_ref[...] = m + jnp.log(l)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, kt_ref, *, block, scale, fold, window=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, scale, fold,
+                window=None, keep=False):
+    keep_ref, rest = (rest[0], rest[1:]) if keep else (None, rest)
+    dq_ref, dk_ref, dv_ref, dq_acc, kt_ref = rest
     j = pl.program_id(1)
     n_blocks = pl.num_programs(1)
 
@@ -227,7 +250,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if fold:
             q = q * scale
         do = do_ref[rows, :]
-        s = _scores(k, q, scale=scale, fold=fold, masked=masked, behind=behind, window=window)
+        s = _scores(k, q, scale=scale, fold=fold, masked=masked, behind=behind, window=window,
+                    keep=None if keep_ref is None else keep_ref[:, rows])
         p = jnp.exp(s - lse_ref[i])
         dp = lax.dot_general(v, do, _NT, preferred_element_type=_F32)
         ds = (p * (dp - delta_ref[i])).astype(q.dtype)
@@ -258,110 +282,138 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _kernel_options(q, k, window):
-    """``(query heads a key/value head, the kernels' window argument, what a windowed
-    kernel's name ends in)``: static, and ``(1, {}, "")`` for full heads and no window,
-    which leaves the calls as they were."""
+def _kernel_options(q, k, window, keep=None):
+    """``(query heads a key/value head, the kernels' window or mask argument, what such a
+    kernel's name ends in)``: static, and ``(1, {}, "")`` for full heads, no window and
+    no computed mask, which leaves the calls as they were."""
     group = q.shape[0] // k.shape[0]
+    if keep is not None:
+        return group, {"keep": True}, "_keep"
     return group, ({} if window is None else {"window": window}), "" if window is None else "_window"
 
 
-def _vmem(hd: int, group: int, backward: bool) -> dict:
+def _vmem(hd: int, group: int, backward: bool, keep: bool = False) -> dict:
     """The kernels' scoped-VMEM limit where the default 16 MiB does not hold a head: a
     head's ``q``, ``dO`` and float32 ``dQ`` fill it at 8192 positions of 128, and a
     group's float32 ``dK``, ``dV`` blocks pass it by 1.25 MiB; score heads wider than a
-    lane tile (192: rows padded to 256 lanes) pass it on both passes."""
-    if hd > 128:
+    lane tile (192: rows padded to 256 lanes) pass it on both passes, and so does a
+    computed mask's strip (4 MiB at 8192 positions, twice for the pipeline)."""
+    if hd > 128 or keep:
         return {"vmem_limit_bytes": WIDE_VMEM}
     return {"vmem_limit_bytes": GROUPED_BWD_VMEM} if backward and group != 1 else {}
 
 
-def _forward(q, k, v, block, interpret, window=None):
-    """``q`` [B, T, hd], ``k`` [B / group, T, hd], ``v`` [B / group, T, hd_v] -> output
-    *transposed* [B, hd_v, T], log-sum-exp [B, T/block, 1, block]."""
+def _forward(q, k, v, block, interpret, window=None, keep=None):
+    """``q`` [B, T, hd], ``k`` [B / group, T, hd], ``v`` [B / group, T, hd_v], ``keep``
+    [N, T, T] int8 or None -> output *transposed* [B, hd_v, T], log-sum-exp
+    [B, T/block, 1, block]."""
     b, t, hd = q.shape
     hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
-    group, windowed, kind = _kernel_options(q, k, window)
-    head = lambda width: pl.BlockSpec((None, t, width), (lambda h, i: (h, 0, 0)) if group == 1
-                                      else (lambda h, i: (h // group, 0, 0)))
+    group, masking, kind = _kernel_options(q, k, window, keep)
+    if keep is None:
+        grid, semantics = (b, n_blocks), ("parallel", "arbitrary")
+        head_of = lambda h, i: h  # the query head of a grid step
+        kv_of = (lambda h, i: h) if group == 1 else (lambda h, i: h // group)
+        operands, extra = (q, k, v), []
+    else:
+        # (key/value head, query block, head of the group): the mask's strip follows the
+        # first two, so the pipeline fetches it once for the group's heads.
+        grid, semantics = (b // group, n_blocks, group), ("parallel", "arbitrary", "arbitrary")
+        head_of = lambda h, i, g: h * group + g
+        kv_of = lambda h, i, g: h
+        kv_a_sequence = (b // group) // keep.shape[0]
+        operands = (q, k, v, keep)
+        extra = [pl.BlockSpec((None, t, block), lambda h, i, g: (h // kv_a_sequence, 0, i))]
+    at = lambda *ids: ids[1]  # the query block of a grid step
+    head = lambda width: pl.BlockSpec((None, t, width), lambda *ids: (kv_of(*ids), 0, 0))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold, **windowed),
-        grid=(b, n_blocks),
-        in_specs=[pl.BlockSpec((None, block, hd), lambda h, i: (h, i, 0)), head(hd), head(hd_v)],
-        out_specs=[pl.BlockSpec((None, hd_v, block), lambda h, i: (h, 0, i)),
-                   pl.BlockSpec((None, None, 1, block), lambda h, i: (h, i, 0, 0))],
-        out_shape=[_struct((b, hd_v, t), q.dtype, q, k, v),
-                   _struct((b, n_blocks, 1, block), _F32, q, k, v)],
+        functools.partial(_fwd_kernel, block=block, scale=scale, fold=fold, **masking),
+        grid=grid,
+        in_specs=[pl.BlockSpec((None, block, hd), lambda *ids: (head_of(*ids), at(*ids), 0)),
+                  head(hd), head(hd_v), *extra],
+        out_specs=[pl.BlockSpec((None, hd_v, block), lambda *ids: (head_of(*ids), 0, at(*ids))),
+                   pl.BlockSpec((None, None, 1, block), lambda *ids: (head_of(*ids), at(*ids), 0, 0))],
+        out_shape=[_struct((b, hd_v, t), q.dtype, *operands),
+                   _struct((b, n_blocks, 1, block), _F32, *operands)],
         scratch_shapes=[pltpu.VMEM((hd_v, t), v.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), **_vmem(hd, group, backward=False)),
+            dimension_semantics=semantics, **_vmem(hd, group, backward=False, keep=keep is not None)),
         interpret=interpret,
         name="causal_attention_fwd" + kind,
-    )(q, k, v)
+    )(*operands)
 
 
-def _backward(q, k, v, do, lse, delta, block, interpret, window=None):
+def _backward(q, k, v, do, lse, delta, block, interpret, window=None, keep=None):
     """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk`` [B, T, hd], ``dv`` [B, T, hd_v] —
     one a QUERY head, in float32, where heads are grouped: the caller sums a group's."""
     b, t, hd = q.shape
     hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
-    group, windowed, kind = _kernel_options(q, k, window)
+    group, masking, kind = _kernel_options(q, k, window, keep)
     head = lambda width: pl.BlockSpec((None, t, width), lambda h, j: (h, 0, 0))
     rows = lambda width: pl.BlockSpec((None, block, width), lambda h, j: (h, j, 0))
     kv_rows = rows if group == 1 else lambda width: pl.BlockSpec(
         (None, block, width), lambda h, j: (h // group, j, 0))
     stats = pl.BlockSpec((None, n_blocks, 1, block), lambda h, j: (h, 0, 0, 0))
-    like = (q, k, v, do, lse, delta)
+    operands = (q, k, v, do, lse, delta)
+    extra = []
+    if keep is not None:  # the key block's strip of the mask, its sequence's
+        heads = b // keep.shape[0]
+        operands = (*operands, keep)
+        extra = [pl.BlockSpec((None, block, t), lambda h, j: (h // heads, j, 0))]
     dk_dtype, dv_dtype = (k.dtype, v.dtype) if group == 1 else (_F32, _F32)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold, **windowed),
+        functools.partial(_bwd_kernel, block=block, scale=scale, fold=fold, **masking),
         grid=(b, n_blocks),
-        in_specs=[head(hd), kv_rows(hd), kv_rows(hd_v), head(hd_v), stats, stats],
+        in_specs=[head(hd), kv_rows(hd), kv_rows(hd_v), head(hd_v), stats, stats, *extra],
         out_specs=[pl.BlockSpec((None, hd, t), lambda h, j: (h, 0, 0)), rows(hd), rows(hd_v)],
-        out_shape=[_struct((b, hd, t), q.dtype, *like), _struct(q.shape, dk_dtype, *like),
-                   _struct(do.shape, dv_dtype, *like)],
+        out_shape=[_struct((b, hd, t), q.dtype, *operands), _struct(q.shape, dk_dtype, *operands),
+                   _struct(do.shape, dv_dtype, *operands)],
         scratch_shapes=[pltpu.VMEM((hd, t), _F32), pltpu.VMEM((hd, block), k.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), **_vmem(hd, group, backward=True)),
+            dimension_semantics=("parallel", "arbitrary"),
+            **_vmem(hd, group, backward=True, keep=keep is not None)),
         interpret=interpret,
         name="causal_attention_bwd" + kind,
-    )(q, k, v, do, lse, delta)
+    )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attend(q, k, v, block, interpret, window):
-    return _attend_fwd(q, k, v, block, interpret, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _attend(q, k, v, keep, block, interpret, window):
+    return _attend_fwd(q, k, v, keep, block, interpret, window)[0]
 
 
-def _attend_fwd(q, k, v, block, interpret, window):
-    o_t, lse = _forward(q, k, v, block, interpret, window)
+def _attend_fwd(q, k, v, keep, block, interpret, window):
+    o_t, lse = _forward(q, k, v, block, interpret, window, keep)
     o, lse = map(checkpoint_name, (jnp.swapaxes(o_t, 1, 2), lse), KEPT)
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, keep, o, lse)
 
 
 def _attend_bwd(block, interpret, window, saved, do):
-    q, k, v, o, lse = saved
+    q, k, v, keep, o, lse = saved
     delta = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1).reshape(lse.shape)
-    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret, window)
+    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret, window, keep)
     if k.shape != q.shape:  # a group's query heads each wrote their own share
         shared = lambda d, like: d.reshape(like.shape[0], -1, *like.shape[1:]).sum(1).astype(like.dtype)
         dk, dv = shared(dk, k), shared(dv, v)
-    return jnp.swapaxes(dq_t, 1, 2), dk, dv
+    return jnp.swapaxes(dq_t, 1, 2), dk, dv, None  # a mask takes no gradient
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                           window: int | None = None) -> jax.Array:
+                           window: int | None = None,
+                           keep: jax.Array | None = None) -> jax.Array:
     """The same function spelled densely, in the inputs' dtype throughout: ``[N, H, T, T]``
     scores, mask, softmax.  What short sequences run, and what the kernels are tested
     against.  Grouped ``k``/``v`` (``[N, H_kv, T, hd]``) are repeated to the query heads;
-    ``v``'s head size may differ from ``q``'s and ``k``'s (the output takes it)."""
+    ``v``'s head size may differ from ``q``'s and ``k``'s (the output takes it); ``keep``
+    ``[N, T, T]`` (keys down, queries along, as :func:`causal_attention` takes it) masks
+    every head of its sequence alike."""
     t, hd = q.shape[-2:]
     if k.shape[1] != q.shape[1]:
         k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
@@ -371,7 +423,10 @@ def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal = jnp.tril(jnp.ones((t, t), bool))
     if window is not None:  # ... and to keys less than ``window`` positions behind it
         causal &= ~jnp.tril(jnp.ones((t, t), bool), -window)
-    scores = jnp.where(causal[None, None], scores, jnp.finfo(scores.dtype).min)
+    seen = causal[None, None]
+    if keep is not None:  # ... and to the keys the program's own mask lets through
+        seen = seen & (jnp.swapaxes(keep, 1, 2) != 0)[:, None]
+    scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
     att = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("nhqk,nhkd->nhqd", att, v)
 
@@ -382,6 +437,7 @@ def causal_attention(
     v: jax.Array,
     *,
     window: int | None = None,
+    keep: jax.Array | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -390,7 +446,10 @@ def causal_attention(
     :func:`block_for`).  ``k`` and ``v`` may hold fewer heads, ``[N, H_kv, T, hd]`` with
     ``H`` a multiple of ``H_kv``: query head ``h`` reads head ``h // (H / H_kv)``.  ``v``
     may have another head size, ``[N, H_kv, T, hd_v]``: the output is ``[N, H, T, hd_v]``.
-    With ``window``, position ``t`` attends to keys ``t - window < s <= t`` only.
+    With ``window``, position ``t`` attends to keys ``t - window < s <= t`` only.  With
+    ``keep`` ``[N, T, T]`` (``int8``; keys down, queries along: ``keep[n, s, t]``), position
+    ``t`` of sequence ``n`` attends, in every head, to the keys ``s <= t`` with ``keep[n,
+    s, t] != 0`` only; each ``t`` has to keep a key.  It is a constant of the backward pass.
 
     Off the TPU the kernels run in Pallas's interpreter, which cannot evaluate a kernel
     on values that vary over a ``shard_map`` axis under its varying-axes check (the
@@ -404,14 +463,19 @@ def causal_attention(
                          f"v's own head size: {q.shape}, {k.shape}, {v.shape}")
     if window is not None and window < 1:
         raise ValueError(f"window={window}: a position sees itself at least")
+    if keep is not None and (window is not None or keep.shape != (n, t, t)):
+        raise ValueError(f"keep is one [N, T, T] mask a sequence, {(n, t, t)} here, and takes "
+                         f"the window's place: {keep.shape}, window={window}")
     block = block_for(t) if block is None else block
     if block is None or t % block or block % 128:
         raise ValueError(f"T={t} is not whole blocks of {block or BLOCKS} (multiples of 128)")
     if window is not None and window >= t:
         window = None  # no key is that far behind
+    if keep is not None:
+        keep = lax.stop_gradient(keep.astype(jnp.int8))
     interpret = auto_interpret(interpret)
     if interpret and any(jax.typeof(a).vma for a in (q, k, v)):
-        return dense_causal_attention(q, k, v, window=window)
+        return dense_causal_attention(q, k, v, window=window, keep=keep)
     flat = lambda a: a.reshape(-1, t, a.shape[-1])
-    out = _attend(flat(q), flat(k), flat(v), block, interpret, window)
+    out = _attend(flat(q), flat(k), flat(v), keep, block, interpret, window)
     return out.reshape(n, h, t, hd_v)

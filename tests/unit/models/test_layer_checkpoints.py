@@ -1,5 +1,6 @@
-"""What the three models with rematerialized layers keep for the backward pass
-(``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``): every layer under
+"""What the four models with rematerialized layers keep for the backward pass
+(``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``, ``models.indexed_moe``,
+whose pick of keys is a third kind of kept output): every layer under
 ``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS``, so the attention kernel's
 output and log-sum-exp stay and the kernel is launched once a layer, and the expert
 dispatch's three integer outputs stay and its sort runs once a layer; a plain checkpoint
@@ -13,7 +14,7 @@ import pytest
 
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.core.types import ClientData
-from nanofed_tpu.models import experts, get_model, hybrid, latent_moe, moe_decoder
+from nanofed_tpu.models import experts, get_model, hybrid, indexed_moe, latent_moe, moe_decoder
 from nanofed_tpu.ops import attention
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
@@ -34,6 +35,12 @@ DECODERS = {
         "rope_dim": 8, "value_dim": 16, "dense_layers": 1, "dense_width": 160, "expert_layers": 2,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 48}),
     "hybrid": ("hybrid_lm", hybrid, {"vocab": 64, "seq_len": 32, "pattern": "MEM*E"}),
+    # Two layers whose attention runs under a pick of 96 keys, eight query heads a
+    # key/value head (at 32 positions: a pick of 8, densely).
+    "indexed_moe": ("indexed_moe_lm", indexed_moe, {
+        "vocab": 64, "seq_len": 512, "width": 64, "layers": 2, "attn_heads": 8, "kv_heads": 1,
+        "head_dim": 16, "rope_sections": [2, 3, 3], "index_heads": 4, "index_dim": 8,
+        "index_topk": 96, "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 48}),
 }
 #: Launches a training step of each holds, forward kernels under the policy first.
 LAUNCHES = {
@@ -41,12 +48,18 @@ LAUNCHES = {
                     "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
     "latent_moe": {"causal_attention_fwd": 3, "causal_attention_bwd": 3},
     "hybrid": {},
+    "indexed_moe": {"causal_attention_fwd_keep": 2, "causal_attention_bwd_keep": 2},
 }
 #: Expert layers of each: a dispatch, and so a sort, apiece.
-EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2}
+EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2}
+#: Leaves the loss reads and no step moves: the indexer's three matrices (its pick is a
+#: constant of the backward pass).
+NEVER_MOVED = {"indexed_moe": 3}
+
 #: Fewer layers of each for the tests that run a step operation by operation.
 SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
-           "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "ME"}}
+           "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "ME"},
+           "indexed_moe": {"layers": 1}}
 
 
 @pytest.fixture(params=list(DECODERS))
@@ -139,7 +152,7 @@ def test_under_512_positions_the_checkpoint_keeps_the_dispatch_alone(decoder, mo
     step = lambda: _training_step(build(seq_len=32)[0], tokens)
     lowered = lambda: jax.jit(step()).lower(params).as_text()
     named = {eqn.params["name"] for eqn in equations(step(), params) if eqn.primitive.name == "name"}
-    assert named == set(experts.KEPT)
+    assert named == set(experts.KEPT)  # (32 positions under a pick of 96 keys: none is made)
     assert sorts(step(), params) == EXPERT_LAYERS[name]
     monkeypatch.setattr(DECODERS[name][1], "KEEP_NAMED_OUTPUTS", attention.KEEP_KERNEL_OUTPUTS)
     kernels_alone = lowered()
@@ -157,27 +170,29 @@ def kernels_in_plain_jax(monkeypatch):
     ``custom_vjp`` is traced; with the stand-ins it is: the forward rule and its names,
     the residuals, the backward rule around them."""
 
-    def probabilities(q, k, lse, window):
+    def probabilities(q, k, lse, window, keep=None):
         t, hd = q.shape[1:]
         s = jnp.einsum("bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32) / hd ** 0.5
         behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
         seen = (behind >= 0) & (behind < (t if window is None else window))
+        if keep is not None:  # [N, keys, queries], one mask for all a sequence's heads
+            seen = seen & jnp.repeat(jnp.swapaxes(keep, 1, 2) != 0, q.shape[0] // keep.shape[0], 0)
         s = jnp.where(seen, s, -jnp.inf)
         lse = jax.nn.logsumexp(s, axis=-1) if lse is None else lse.reshape(q.shape[:2])
         return jnp.exp(s - lse[..., None]), lse
 
-    def causal_attention_fwd(q, k, v, block, window):
+    def causal_attention_fwd(q, k, v, block, window, keep=None):
         group = q.shape[0] // k.shape[0]
-        p, lse = probabilities(q, jnp.repeat(k, group, 0), None, window)
+        p, lse = probabilities(q, jnp.repeat(k, group, 0), None, window, keep)
         o = jnp.einsum("bqk,bkd->bqd", p, jnp.repeat(v, group, 0).astype(jnp.float32))
         return (jnp.swapaxes(o, 1, 2).astype(q.dtype),
                 lse.reshape(q.shape[0], q.shape[1] // block, 1, block))
 
-    def causal_attention_bwd(q, k, v, do, lse, delta, block, window):
+    def causal_attention_bwd(q, k, v, do, lse, delta, block, window, keep=None):
         group = q.shape[0] // k.shape[0]
         k, v = jnp.repeat(k, group, 0), jnp.repeat(v, group, 0)
         f32 = lambda a: a.astype(jnp.float32)
-        p, _ = probabilities(q, k, lse, window)
+        p, _ = probabilities(q, k, lse, window, keep)
         dp = jnp.einsum("bqd,bkd->bqk", f32(do), f32(v))
         ds = p * (dp - delta.reshape(q.shape[0], -1, 1)) / q.shape[-1] ** 0.5
         dq = jnp.einsum("bqk,bkd->bqd", ds, f32(k))
@@ -188,10 +203,16 @@ def kernels_in_plain_jax(monkeypatch):
 
     fwd = jax.jit(causal_attention_fwd, static_argnums=(3, 4))
     bwd = jax.jit(causal_attention_bwd, static_argnums=(6, 7))
-    monkeypatch.setattr(attention, "_forward",
-                        lambda q, k, v, block, interpret, window=None: fwd(q, k, v, block, window))
+    # A stand-in under a mask takes the masked kernel's name.
+    fwd_keep = jax.jit(lambda *a: causal_attention_fwd(*a), static_argnums=(3, 4))
+    bwd_keep = jax.jit(lambda *a: causal_attention_bwd(*a), static_argnums=(6, 7))
+    fwd_keep.__wrapped__.__name__ = "causal_attention_fwd_keep"
+    bwd_keep.__wrapped__.__name__ = "causal_attention_bwd_keep"
+    monkeypatch.setattr(attention, "_forward", lambda q, k, v, block, interpret, window=None, keep=None:
+                        fwd(q, k, v, block, window) if keep is None else fwd_keep(q, k, v, block, window, keep))
     monkeypatch.setattr(attention, "_backward", lambda q, k, v, do, lse, delta, block, interpret,
-                        window=None: bwd(q, k, v, do, lse, delta, block, window))
+                        window=None, keep=None: bwd(q, k, v, do, lse, delta, block, window)
+                        if keep is None else bwd_keep(q, k, v, do, lse, delta, block, window, keep))
     monkeypatch.setattr(attention, "auto_interpret", lambda interpret: False)
 
 
@@ -206,7 +227,8 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     name, build, plainly = decoder
     model, params, _ = build()
     layers = sum(LAUNCHES[name].values()) // 2
-    launches = lambda forward: {f"causal_attention_{kind}": n for kind, n in (
+    suffix = "_keep" if name == "indexed_moe" else ""
+    launches = lambda forward: {f"causal_attention_{kind}{suffix}": n for kind, n in (
         ("fwd", forward * layers), ("bwd", layers)) if n}
     shuffle = 1  # the local fit's own sort: an epoch's permutation of a client's rows
     mesh = make_mesh(devices=jax.devices()[:4])
@@ -230,5 +252,5 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     assert (calls, sorted_) == (launches(2), shuffle + 2 * EXPERT_LAYERS[name])
     moved = [float(jnp.abs(a - b).max()) > 0
              for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(params))]
-    assert sum(moved) >= len(moved) - 1
+    assert sum(moved) >= len(moved) - 1 - NEVER_MOVED.get(name, 0)
     jax.tree.map(np.testing.assert_array_equal, kept, plain)

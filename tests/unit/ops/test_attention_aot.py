@@ -5,8 +5,9 @@ about values or times.  All such compiles live in this one file (one worker load
 TPU's library, inside the fixture): the cell's training step is here too, for what the
 compiler keeps of the MLP between its forward and its backward, the SmallThinker
 cell's embedding gradient, for where the compiler places its accumulators, the
-moonlight cell's kernels with score and value heads of different sizes, and a
-rematerialized layer of either decoder, for what it launches twice and what it keeps."""
+moonlight cell's kernels with score and value heads of different sizes, the keye cell's
+kernels under a computed mask, and a rematerialized layer of each of the three decoders,
+for what it launches twice and what it keeps."""
 
 import re
 
@@ -16,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from nanofed_tpu import nn
-from nanofed_tpu.models import get_model, latent_moe, moe_decoder, transformer
+from nanofed_tpu.models import get_model, indexed_moe, latent_moe, moe_decoder, transformer
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops.attention import causal_attention
 from nanofed_tpu.trainer.local import make_grad_fn
@@ -78,6 +79,22 @@ def test_latent_head_sizes_compile_at_8192_positions(one_chip, backward):
     fn = jax.grad(_loss, (0, 1, 2)) if backward else _attend
     text = jax.jit(fn).lower(shaped(192), shaped(192), shaped(128)).compile().as_text()
     assert "causal_attention_bwd" in text if backward else "causal_attention_fwd" in text
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_masked_kernels_compile_at_8192_positions(one_chip, backward):
+    """The keye cell's shapes: 32 query heads over 4 key/value heads of 128, 8192
+    positions, one ``int8 [8192, 8192]`` mask a sequence: a 4 MiB strip of it a grid step
+    beside a head's ``K`` and ``V`` (forward) or ``q``, ``dO`` and float32 ``dQ``
+    (backward) passes the default scoped VMEM; both compile under ``WIDE_VMEM``."""
+    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    masked = lambda q, k, v, keep: causal_attention(q, k, v, keep=keep, interpret=False)
+    loss = lambda q, k, v, keep: masked(q, k, v, keep).astype(jnp.float32).sum()
+    fn = jax.grad(loss, (0, 1, 2)) if backward else masked
+    text = jax.jit(fn).lower(shaped(1, 32, 8192, 128), shaped(1, 4, 8192, 128), shaped(1, 4, 8192, 128),
+                             shaped(1, 8192, 8192, dtype=jnp.int8)).compile().as_text()
+    assert ("causal_attention_bwd_keep" if backward else "causal_attention_fwd_keep") in text
     assert "tpu_custom_call" in text
 
 
@@ -192,7 +209,7 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
     assert results and all(r.startswith(whole) and "S(1)" not in r for r in results), results
 
 
-#: The two cells' layers at their published widths and 8192 positions, a small vocabulary
+#: The three cells' layers at their published widths and 8192 positions, a small vocabulary
 #: around them: ``(factory, module, kwargs, layers, bytes of the output and log-sum-exp a
 #: layer keeps)``.  An expert layer keeps its dispatch's layout too: under 0.3 MB.
 DECODERS = {
@@ -205,6 +222,12 @@ DECODERS = {
         rope_dim=64, value_dim=128, rope_theta=50000, dense_layers=1, dense_width=11264,
         expert_layers=1, experts=64, first_expert=0, experts_held=8, top_k=6, expert_width=1408,
         shared_width=2816, routed_scale=2.446, eps=1e-5), 2, 16 * 8192 * (128 * 2 + 4)),
+    # ... and the pick: one int8 [8192, 8192] mask a layer.
+    "keye": ("indexed_moe_lm", indexed_moe, dict(
+        vocab=1024, seq_len=8192, width=2048, layers=1, attn_heads=32, kv_heads=4, head_dim=128,
+        rope_theta=1e7, rope_sections=[16, 24, 24], index_heads=16, index_dim=64,
+        index_topk=2048, experts=128, first_expert=0, experts_held=16, top_k=8, expert_width=768,
+        eps=1e-6), 1, 32 * 8192 * (128 * 2 + 4) + 8192 * 8192),
 }
 #: What buffer assignment may move for reasons of its own when the schedule changes (read
 #: here: +11 MB on 60 MB kept and +0.5 MB on 68 MB kept).
@@ -253,6 +276,24 @@ def test_a_rematerialized_layer_launches_the_forward_kernel_once(decoder_steps):
     temp = {which: decoder_steps[which].memory_analysis().temp_size_in_bytes
             for which in ("kept", "plain")}
     assert temp["kept"] - temp["plain"] <= decoder_steps["kept_bytes"] + SLACK, temp
+
+
+def test_a_rematerialized_indexer_picks_once_and_never_sorts(decoder_steps):
+    """Under the models' policy no operation of an indexer's scores or selection stands in
+    a rematerialized computation (none exists in two of the three decoders); in the keye
+    cell's layer a plain checkpoint reruns both, the program's sorts are the router's
+    ``top_k`` and the expert dispatch's, none the pick's, and a band's keys stay in the
+    chip's fast memory through the selection's passes."""
+    kept, plain = (decoder_steps[which].as_text() for which in ("kept", "plain"))
+    rerun = lambda text: len(re.findall(
+        r'op_name="[^"]*rematted_computation[^"]*indexer_(?:scores|select)', text))
+    assert rerun(kept) == 0
+    if "indexer_select" in kept:
+        assert rerun(plain) > 0
+        sorts = [line for line in kept.splitlines() if " sort(" in line]
+        assert sorts and not any("indexer_" in line for line in sorts)
+        # The selection's keys, a band at a time: u32 [keys, 512] in memory space 1.
+        assert re.search(r"u32\[1,1,8192,512\]\{[^}]*S\(1\)\}", kept)
 
 
 def test_a_rematerialized_expert_layer_sorts_its_picks_once(decoder_steps):
