@@ -5,7 +5,8 @@ by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``
 A layer is TOLD which experts it holds (``first_expert``, ``held``) of the ``experts``
 its router scores.  The model routes — its own scores, its own normalisation — and hands
 the picks and their weights to :func:`held_experts`: picks that land on held experts are
-laid out by expert in whole blocks of ``block`` rows (``moe_dispatch``) and the held
+laid out by expert in whole blocks of ``block`` rows (``moe_dispatch``, :func:`dispatch`:
+a one-hot of the picks, a prefix count along them, one scatter of ``src``) and the held
 experts' MLPs run over the blocks in use (``moe_experts``, :func:`expert_blocks`: a loop
 whose trip count follows the routing, so no capacity limit and no dropped token, and no
 work on blocks nobody fills).  What experts held elsewhere would add is left out: on one
@@ -14,8 +15,8 @@ layer (tests).
 
 The dispatch is integer layout work with no gradient, and the loop's hand-written
 backward reads its three outputs (``src``, ``block_expert``, the trip count).  Under a
-layer's ``jax.checkpoint`` they would be rebuilt in the backward pass: the same sort of
-the same keys.  :func:`held_experts` names them (:data:`KEPT`,
+layer's ``jax.checkpoint`` they would be rebuilt in the backward pass: the same prefix
+count and scatter of the same picks.  :func:`held_experts` names them (:data:`KEPT`,
 ``jax.ad_checkpoint.checkpoint_name``), and a checkpoint given
 :data:`KEEP_NAMED_OUTPUTS` as its policy keeps them (``int32[rows]``, ``int32[rows //
 block]`` and a scalar a layer) and runs the dispatch once a step; the three models'
@@ -206,6 +207,41 @@ def _expert_blocks_bwd(activation, block, saved, d_out):
 expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
 
 
+def dispatch(picks, *, first_expert: int, held: int, block: int):
+    """Where each pick that lands on experts ``first_expert .. first_expert + held`` goes
+    in a layout by expert in whole blocks of ``block`` rows: ``(src [rows] int32,
+    block_expert [rows // block] int32, n_blocks, counts [held], ends [held])``.
+    ``counts[e]`` picks landed on expert ``e``; its rows, the count rounded up to whole
+    blocks, end at ``ends[e]``, and the ``k``-th of them names its ``k``-th pick in pick
+    order (pick ``i`` of ``picks`` [n, top_k] flattened); every other row holds ``n *
+    top_k``, and only the first ``n_blocks`` blocks hold a pick.  ``rows`` is static, room
+    for every token's ``min(top_k, held)`` picks (a token picks an expert once) and a block
+    to spare an expert.
+
+    A pick's place inside its expert is how many earlier picks chose that expert: a prefix
+    count along the picks, dense and exact, where a stable sort of the keys would give the
+    same order; then ONE scatter writes every pick's index to its row."""
+    n, top_k = picks.shape
+    local = (picks - first_expert).reshape(n * top_k)
+    # [held, n * top_k], the picks along the lanes; one that lands elsewhere is in no row.
+    lands = local[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
+    ones = lands.astype(jnp.int32)
+    counts = ones.sum(axis=1)
+    padded = -(-counts // block) * block
+    ends = jnp.cumsum(padded)
+    rows = n * min(top_k, held) + held * block
+    rows = -(-rows // block) * block
+    block_expert = jnp.clip(jnp.searchsorted(
+        ends, jnp.arange(rows // block, dtype=jnp.int32) * block, side="right"),
+        0, held - 1).astype(jnp.int32)
+    before = jnp.cumsum(ones, axis=1) - ones
+    # ``rows``, one past the end, where a pick lands elsewhere: the scatter drops it.
+    dest = jnp.where(lands, (ends - padded)[:, None] + before, rows).min(axis=0)
+    src = jnp.full(rows, n * top_k, jnp.int32).at[dest].set(
+        jnp.arange(n * top_k, dtype=jnp.int32), mode="drop")
+    return src, block_expert, ends[-1] // block, counts, ends
+
+
 def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int, block: int,
                  activation: Activation):
     """The held experts' part of the routed output for tokens ``x`` [n, d], and the
@@ -216,28 +252,10 @@ def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int, block: in
     n, top_k = picks.shape
     held = w_in.shape[0]
     with jax.named_scope("moe_dispatch"):
-        local = (picks - first_expert).reshape(n * top_k)
-        key = jnp.where((local >= 0) & (local < held), local, held)  # held: lands elsewhere
-        counts = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0, dtype=jnp.int32)
-        # Picks by expert, in pick order within an expert; then every expert's picks
-        # padded to whole blocks: row r of the layout is the rank-th pick of its expert.
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        padded = -(-counts // block) * block
-        ends = jnp.cumsum(padded)
-        rows = n * min(top_k, held) + held * block
-        rows = -(-rows // block) * block
-        block_expert = jnp.clip(jnp.searchsorted(
-            ends, jnp.arange(rows // block, dtype=jnp.int32) * block, side="right"),
-            0, held - 1).astype(jnp.int32)
-        r = jnp.arange(rows, dtype=jnp.int32)
-        expert = block_expert[r // block]
-        rank = r - (ends - padded)[expert]
-        taken = (rank < counts[expert]) & (r < ends[-1])
-        first_pick = jnp.cumsum(counts) - counts
-        src = jnp.where(taken, order[jnp.clip(first_pick[expert] + rank, 0, n * top_k - 1)],
-                        n * top_k)
+        src, block_expert, n_blocks, counts, ends = dispatch(
+            picks, first_expert=first_expert, held=held, block=block)
         src, block_expert, n_blocks = map(
-            checkpoint_name, (src, block_expert, ends[-1] // block), KEPT)
+            checkpoint_name, (src, block_expert, n_blocks), KEPT)
     with jax.named_scope("moe_experts"):
         out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert, n_blocks,
                             w_in, w_out, activation, block)

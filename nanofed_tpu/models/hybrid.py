@@ -14,8 +14,8 @@ at any depth; the forward pass walks the pattern and takes each layer's slice.  
 layer is rematerialized (``jax.checkpoint``): the backward pass keeps one ``[N, T,
 width]`` activation a layer and recomputes inside it, but for what carries a name
 (``models.experts.KEEP_NAMED_OUTPUTS``): an ``E`` layer's integer dispatch layout
-(``src``, ``block_expert``, the trip count: under 0.3 MB), so its picks are sorted once
-a step; a mixer or an attention layer names nothing and keeps nothing.
+(``src``, ``block_expert``, the trip count: under 0.3 MB), so its picks are laid out
+once a step; a mixer or an attention layer names nothing and keeps nothing.
 
 **Mamba-2** (``ssm_mixer``): ``[z | xBC | dt] = u W_in``; a causal depthwise convolution
 and SiLU on ``xBC``; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t +

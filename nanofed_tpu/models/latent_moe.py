@@ -29,8 +29,8 @@ rematerialized (``jax.checkpoint``) but for what carries a name
 (``models.experts.KEEP_NAMED_OUTPUTS``): the attention kernels' output and log-sum-exp
 (one ``[N, heads, T, value]`` array a layer beside the layer's input) and, in an expert
 layer, the dispatch's integer layout (``src``, ``block_expert``, the trip count: under
-0.3 MB), so the backward pass neither launches the forward kernel nor sorts the picks
-again.
+0.3 MB), so the backward pass neither launches the forward kernel nor lays the picks
+out again.
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions, with score heads of ``nope + rope``

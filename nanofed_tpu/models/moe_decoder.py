@@ -22,7 +22,7 @@ what carries a name (``models.experts.KEEP_NAMED_OUTPUTS``): the attention kerne
 output and log-sum-exp (one ``[N, heads, T, head_dim]`` array a layer beside the layer's
 input) and the expert dispatch's integer layout (``src``, ``block_expert``, the trip
 count: under 0.3 MB), so the backward pass neither launches the forward kernel nor
-sorts the picks again.
+lays the picks out again.
 
 **Attention** runs block by block in ``ops.attention``'s kernels wherever the sequence
 is whole blocks of at least ``MIN_SEQ`` positions — grouped heads read their key/value

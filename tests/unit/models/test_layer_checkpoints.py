@@ -3,9 +3,9 @@
 whose pick of keys is a third kind of kept output): every layer under
 ``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS``, so the attention kernel's
 output and log-sum-exp stay and the kernel is launched once a layer, and the expert
-dispatch's three integer outputs stay and its sort runs once a layer; a plain checkpoint
-(the policy taken away, as each test does for its other side) launches and sorts twice
-and computes the same bits."""
+dispatch's three integer outputs stay and it runs once a layer; a plain checkpoint
+(the policy taken away, as each test does for its other side) launches and dispatches
+twice and computes the same bits."""
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +50,7 @@ LAUNCHES = {
     "hybrid": {},
     "indexed_moe": {"causal_attention_fwd_keep": 2, "causal_attention_bwd_keep": 2},
 }
-#: Expert layers of each: a dispatch, and so a sort, apiece.
+#: Expert layers of each: a dispatch, and so one scatter of ``src``, apiece.
 EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2}
 #: Leaves the loss reads and no step moves: the indexer's three matrices (its pick is a
 #: constant of the backward pass).
@@ -86,10 +86,13 @@ def _training_step(model, tokens, cast=lambda p: p):
 
 
 @pytest.fixture
-def sorts(equations):
-    """``sorts(fn, *args)``: the ``sort`` equations of ``fn``'s jaxpr, the rematerialized
-    bodies included."""
-    return lambda fn, *args: sum(eqn.primitive.name == "sort" for eqn in equations(fn, *args))
+def dispatches(equations):
+    """``dispatches(fn, *args)``: the dispatches ``fn``'s jaxpr runs, the rematerialized
+    bodies included, by the one scatter each holds (the write of ``src``, the only
+    ``scatter`` equation under ``moe_dispatch``)."""
+    return lambda fn, *args: sum(
+        eqn.primitive.name == "scatter" and "moe_dispatch" in str(eqn.source_info.name_stack)
+        for eqn in equations(fn, *args))
 
 
 def test_a_training_step_launches_each_attention_kernel_once_a_layer(decoder, kernel_calls):
@@ -102,12 +105,12 @@ def test_a_training_step_launches_each_attention_kernel_once_a_layer(decoder, ke
                        for kernel, n in LAUNCHES[name].items()}
 
 
-def test_a_training_step_sorts_the_picks_once_an_expert_layer(decoder, sorts):
-    """The dispatch's ``argsort`` is the step's only sort: one an expert layer where the
-    checkpoint keeps the layout, a second in every rematerialized body where it does not."""
+def test_a_training_step_lays_the_picks_out_once_an_expert_layer(decoder, dispatches):
+    """One dispatch an expert layer where the checkpoint keeps the layout, a second in
+    every rematerialized body where it does not."""
     name, build, plainly = decoder
     _, params, tokens = build()
-    count = lambda: sorts(_training_step(build()[0], tokens), params)
+    count = lambda: dispatches(_training_step(build()[0], tokens), params)
     assert count() == EXPERT_LAYERS[name]
     plainly()
     assert count() == 2 * EXPERT_LAYERS[name]
@@ -143,20 +146,20 @@ def test_what_the_checkpoint_keeps_changes_no_bit_of_a_training_step(decoder, ca
 
 
 def test_under_512_positions_the_checkpoint_keeps_the_dispatch_alone(decoder, monkeypatch, equations,
-                                                                     sorts):
+                                                                     dispatches):
     """The dense spelling answers and the only names left are the dispatch's: a
     checkpoint that keeps the kernels' names alone finds nothing to keep and its step is
-    the plain checkpoint's program, two sorts an expert layer; the models' own keeps one."""
+    the plain checkpoint's program, two dispatches an expert layer; the models' own keeps one."""
     name, build, plainly = decoder
     _, params, tokens = build(seq_len=32)
     step = lambda: _training_step(build(seq_len=32)[0], tokens)
     lowered = lambda: jax.jit(step()).lower(params).as_text()
     named = {eqn.params["name"] for eqn in equations(step(), params) if eqn.primitive.name == "name"}
     assert named == set(experts.KEPT)  # (32 positions under a pick of 96 keys: none is made)
-    assert sorts(step(), params) == EXPERT_LAYERS[name]
+    assert dispatches(step(), params) == EXPERT_LAYERS[name]
     monkeypatch.setattr(DECODERS[name][1], "KEEP_NAMED_OUTPUTS", attention.KEEP_KERNEL_OUTPUTS)
     kernels_alone = lowered()
-    assert sorts(step(), params) == 2 * EXPERT_LAYERS[name]
+    assert dispatches(step(), params) == 2 * EXPERT_LAYERS[name]
     plainly()
     assert lowered() == kernels_alone
 
@@ -217,11 +220,11 @@ def kernels_in_plain_jax(monkeypatch):
 
 
 @pytest.mark.parametrize("client_chunk", [None, 1], ids=["vmap", "chunks-of-1"])
-def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chunk, sorts,
+def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chunk, dispatches,
                                                              kernels_in_plain_jax, kernel_calls):
     """The ``shard_map`` round over four devices, clients under ``vmap`` or one at a time,
     the local steps a scan: the names reach the layers' checkpoints through all of them
-    (one forward launch a layer and one sort an expert layer in the round's jaxpr, two
+    (one forward launch a layer and one dispatch an expert layer in the round's jaxpr, two
     under a plain checkpoint), and a round leaves the parameters where the plain
     checkpoint's round leaves them."""
     name, build, plainly = decoder
@@ -230,7 +233,6 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     suffix = "_keep" if name == "indexed_moe" else ""
     launches = lambda forward: {f"causal_attention_{kind}{suffix}": n for kind, n in (
         ("fwd", forward * layers), ("bwd", layers)) if n}
-    shuffle = 1  # the local fit's own sort: an epoch's permutation of a client's rows
     mesh = make_mesh(devices=jax.devices()[:4])
     training = TrainingConfig(batch_size=1, local_epochs=1, learning_rate=0.05)
     strategy = fedavg_strategy()
@@ -243,13 +245,13 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     def one_round():
         step = build_round_step(build()[0].apply, training, mesh, strategy,
                                 client_chunk=client_chunk, params_like=params)
-        return kernel_calls(step, *args), sorts(step, *args), step(*args).params
+        return kernel_calls(step, *args), dispatches(step, *args), step(*args).params
 
-    calls, sorted_, kept = one_round()
-    assert (calls, sorted_) == (launches(1), shuffle + EXPERT_LAYERS[name])
+    calls, dispatched, kept = one_round()
+    assert (calls, dispatched) == (launches(1), EXPERT_LAYERS[name])
     plainly()
-    calls, sorted_, plain = one_round()
-    assert (calls, sorted_) == (launches(2), shuffle + 2 * EXPERT_LAYERS[name])
+    calls, dispatched, plain = one_round()
+    assert (calls, dispatched) == (launches(2), 2 * EXPERT_LAYERS[name])
     moved = [float(jnp.abs(a - b).max()) > 0
              for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(params))]
     assert sum(moved) >= len(moved) - 1 - NEVER_MOVED.get(name, 0)
