@@ -1,6 +1,7 @@
-"""What the four models with rematerialized layers keep for the backward pass
+"""What the five models with rematerialized layers keep for the backward pass
 (``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``, ``models.indexed_moe``,
-whose pick of keys is a third kind of kept output): every layer under
+whose pick of keys is a third kind of kept output, ``models.gated_moe``, whose gate comes
+after the kept output and is computed again): every layer under
 ``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS``, so the attention kernel's
 output and log-sum-exp stay and the kernel is launched once a layer, and the expert
 dispatch's three integer outputs stay and it runs once a layer; a plain checkpoint
@@ -14,7 +15,8 @@ import pytest
 
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.core.types import ClientData
-from nanofed_tpu.models import experts, get_model, hybrid, indexed_moe, latent_moe, moe_decoder
+from nanofed_tpu.models import (
+    experts, gated_moe, get_model, hybrid, indexed_moe, latent_moe, moe_decoder)
 from nanofed_tpu.ops import attention
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
@@ -41,6 +43,12 @@ DECODERS = {
         "vocab": 64, "seq_len": 512, "width": 64, "layers": 2, "attn_heads": 8, "kv_heads": 1,
         "head_dim": 16, "rope_sections": [2, 3, 3], "index_heads": 4, "index_dim": 8,
         "index_topk": 96, "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 48}),
+    # A dense and three expert layers, three of them sliding under a window of 200, eight
+    # query heads a key/value head, an output gate after the kernels' kept output.
+    "gated_moe": ("gated_moe_lm", gated_moe, {
+        "vocab": 64, "seq_len": 512, "width": 64, "sliding_layout": [1, 1, 0, 1], "window": 200,
+        "attn_heads": 8, "kv_heads": 1, "head_dim": 16, "dense_layers": 1, "dense_width": 160,
+        "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 24}),
 }
 #: Launches a training step of each holds, forward kernels under the policy first.
 LAUNCHES = {
@@ -49,9 +57,11 @@ LAUNCHES = {
     "latent_moe": {"causal_attention_fwd": 3, "causal_attention_bwd": 3},
     "hybrid": {},
     "indexed_moe": {"causal_attention_fwd_keep": 2, "causal_attention_bwd_keep": 2},
+    "gated_moe": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
+                  "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
 }
 #: Expert layers of each: a dispatch, and so one scatter of ``src``, apiece.
-EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2}
+EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2, "gated_moe": 3}
 #: Leaves the loss reads and no step moves: the indexer's three matrices (its pick is a
 #: constant of the backward pass).
 NEVER_MOVED = {"indexed_moe": 3}
@@ -59,7 +69,7 @@ NEVER_MOVED = {"indexed_moe": 3}
 #: Fewer layers of each for the tests that run a step operation by operation.
 SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
            "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "ME"},
-           "indexed_moe": {"layers": 1}}
+           "indexed_moe": {"layers": 1}, "gated_moe": {"sliding_layout": [1, 0]}}
 
 
 @pytest.fixture(params=list(DECODERS))

@@ -6,8 +6,9 @@ TPU's library, inside the fixture): the cell's training step is here too, for wh
 compiler keeps of the MLP between its forward and its backward, the SmallThinker
 cell's embedding gradient, for where the compiler places its accumulators, the
 moonlight cell's kernels with score and value heads of different sizes, the keye cell's
-kernels under a computed mask, and a rematerialized layer of each of the three decoders,
-for what it launches twice and what it keeps."""
+kernels under a computed mask, and a rematerialized layer of each of the four decoders,
+for what it launches twice and what it keeps (the trinity cell's: the windowed kernels with
+eight query heads a key/value head, a 2048 window at 8192 positions)."""
 
 import re
 
@@ -17,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from nanofed_tpu import nn
-from nanofed_tpu.models import get_model, indexed_moe, latent_moe, moe_decoder, transformer
+from nanofed_tpu.models import (
+    gated_moe, get_model, indexed_moe, latent_moe, moe_decoder, transformer)
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops.attention import causal_attention
 from nanofed_tpu.trainer.local import make_grad_fn
@@ -209,7 +211,7 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
     assert results and all(r.startswith(whole) and "S(1)" not in r for r in results), results
 
 
-#: The three cells' layers at their published widths and 8192 positions, a small vocabulary
+#: The four cells' layers at their published widths and 8192 positions, a small vocabulary
 #: around them: ``(factory, module, kwargs, layers, bytes of the output and log-sum-exp a
 #: layer keeps)``.  An expert layer keeps its dispatch's layout too: under 0.3 MB.
 DECODERS = {
@@ -228,6 +230,13 @@ DECODERS = {
         rope_theta=1e7, rope_sections=[16, 24, 24], index_heads=16, index_dim=64,
         index_topk=2048, experts=128, first_expert=0, experts_held=16, top_k=8, expert_width=768,
         eps=1e-6), 1, 32 * 8192 * (128 * 2 + 4) + 8192 * 8192),
+    # A dense sliding layer and a full expert layer: the window's kernels with 8 query heads
+    # a key/value head, and the gate between the kept output and ``W_o``.
+    "trinity": ("gated_moe_lm", gated_moe, dict(
+        vocab=1024, seq_len=8192, width=2048, sliding_layout=[1, 0], window=2048, rope_theta=10000,
+        attn_heads=32, kv_heads=4, head_dim=128, dense_layers=1, dense_width=6144, experts=128,
+        first_expert=0, experts_held=8, top_k=8, expert_width=1024, shared_width=1024,
+        routed_scale=2.826, eps=1e-5), 2, 32 * 8192 * (128 * 2 + 4)),
 }
 #: What buffer assignment may move for reasons of its own when the schedule changes (read
 #: here: +11 MB on 60 MB kept and +0.5 MB on 68 MB kept).
