@@ -64,6 +64,9 @@ class SmokeSize:
     kernel_params: int
     kernel_cohorts: tuple[int, ...]
     attention_shape: tuple[int, int, int, int] = (4, 12, 1024, 64)  # GPT-2's [N, H, T, hd]
+    # SmallThinker's expert layer: tokens, picks a token, experts, held, width, expert width;
+    # then the layout's block (None: from the shapes, as the models have it).
+    experts_shape: tuple = (8192, 6, 64, 16, 2560, 768, None)
     rounds: int = 3
     interpret: bool = False  # Pallas interpreter: CPU rehearsal only
     loss_tolerance: float = 1e-3  # single-step vs fused vs other meshes, absolute
@@ -439,6 +442,34 @@ def phase_kernels(size: SmokeSize) -> dict[str, Any]:
     for name, err in errs.items():
         _check(err < 1.5e-2, f"causal_attention {name}: off its dense form by {err:.3g}")
     out["causal_attention"] = errs
+
+    # --- the held experts' grouped matmul, value and four gradients, against the loop it
+    # stands in for, both on the same bfloat16 operands.
+    from nanofed_tpu.models import experts
+    from nanofed_tpu.ops.experts import tile_rows
+
+    n, top_k, routed, held, d, f, block = size.experts_shape
+    block = block or tile_rows(d, 2 * f)
+    bf16 = lambda k, shape, scale=1.0: (scale * jax.random.normal(key(k), shape)).astype(jnp.bfloat16)
+    x, w_in, w_out, d_out = (bf16(9, (n, d)), bf16(10, (held, d, 2 * f), 0.02),
+                             bf16(11, (held, f, d), 0.02), bf16(12, (n, d)))
+    _, picks = jax.lax.top_k(jax.random.normal(key(13), (n, routed)), top_k)
+    gate = jax.random.uniform(key(14), (n * top_k,), jnp.float32)
+
+    def experts_and_grads(spelling, *extra):
+        def run(x, gate, w_in, w_out):
+            layout = experts.dispatch(picks, first_expert=0, held=held, block=block)[:3]
+            value, pull = jax.vjp(lambda *a: spelling(
+                a[0], a[1], *layout, a[2], a[3], experts.SWIGLU, block, *extra), x, gate, w_in, w_out)
+            return (value, *pull(d_out))
+        return jax.jit(run)(x, gate, w_in, w_out)
+
+    got, want = experts_and_grads(experts.expert_tiles, interp), experts_and_grads(experts.expert_blocks)
+    errs = {name: _rel_err(g.astype(jnp.float32), r.astype(jnp.float32))
+            for name, g, r in zip(("out", "dx", "d_gate", "d_w_in", "d_w_out"), got, want)}
+    for name, err in errs.items():
+        _check(err < 3e-2, f"expert_tiles {name}: off the loop by {err:.3g}")
+    out["expert_tiles"] = errs
     return out
 
 

@@ -1,29 +1,35 @@
-"""The held experts of a mixture-of-experts layer: dispatch and the block loop, shared
+"""The held experts of a mixture-of-experts layer: dispatch and the grouped matmul, shared
 by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``,
-``models.latent_moe``), and the sigmoid router two of them share (:func:`sigmoid_route`).
+``models.latent_moe``, ``models.indexed_moe``, ``models.gated_moe``), and the sigmoid
+router three of them share (:func:`sigmoid_route`).
 
 A layer is TOLD which experts it holds (``first_expert``, ``held``) of the ``experts``
 its router scores.  The model routes — its own scores, its own normalisation — and hands
 the picks and their weights to :func:`held_experts`: picks that land on held experts are
 laid out by expert in whole blocks of ``block`` rows (``moe_dispatch``, :func:`dispatch`:
 a one-hot of the picks, a prefix count along them, one scatter of ``src``) and the held
-experts' MLPs run over the blocks in use (``moe_experts``, :func:`expert_blocks`: a loop
-whose trip count follows the routing, so no capacity limit and no dropped token, and no
-work on blocks nobody fills).  What experts held elsewhere would add is left out: on one
-chip the layer runs without its exchange, and a sum over all the shares is the uncut
-layer (tests).
+experts' MLPs run over the blocks in use (``moe_experts``): the work follows the routing,
+so no capacity limit and no dropped token, and no work on blocks nobody fills.  Two
+spellings of it, and :func:`kernels_run` says which runs from shapes and platform alone:
+on the TPU :func:`expert_tiles`, two Pallas kernels (``ops.experts``) that keep an
+expert's matrices, and in the backward pass its float32 weight gradients, in VMEM while
+its blocks run; everywhere else :func:`expert_blocks`, a loop over the blocks with a
+hand-written backward, which is also what the kernels are tested against.  A block's rows
+follow from the experts' shape (``ops.experts.tile_rows``).  What experts held elsewhere
+would add is left out: on one chip the layer runs without its exchange, and a sum over
+all the shares is the uncut layer (tests).
 
-The dispatch is integer layout work with no gradient, and the loop's hand-written
-backward reads its three outputs (``src``, ``block_expert``, the trip count).  Under a
-layer's ``jax.checkpoint`` they would be rebuilt in the backward pass: the same prefix
-count and scatter of the same picks.  :func:`held_experts` names them (:data:`KEPT`,
+The dispatch is integer layout work with no gradient, and the written backward of either
+spelling reads its three outputs (``src``, ``block_expert``, the count of blocks in use).
+Under a layer's ``jax.checkpoint`` they would be rebuilt in the backward pass: the same
+prefix count and scatter of the same picks.  :func:`held_experts` names them (:data:`KEPT`,
 ``jax.ad_checkpoint.checkpoint_name``), and a checkpoint given
 :data:`KEEP_NAMED_OUTPUTS` as its policy keeps them (``int32[rows]``, ``int32[rows //
-block]`` and a scalar a layer) and runs the dispatch once a step; the three models'
+block]`` and a scalar a layer) and runs the dispatch once a step; the models'
 rematerialized layers do.  Outside such a checkpoint a name is the identity.
 
-An expert is ``W_out act(W_in x)``; ``act`` is an :class:`Activation`, a parameter of
-the loop and of its hand-written backward: :data:`RELU2` on ``[rows, f]``, :data:`REGLU`
+An expert is ``W_out act(W_in x)``; ``act`` is an :class:`Activation`, a static parameter
+of both spellings and of their written backwards: :data:`RELU2` on ``[rows, f]``, :data:`REGLU`
 and :data:`SWIGLU` on a fused ``[rows, 2f]`` product (``W_gate | W_up`` stored as one
 ``[d, 2f]`` leaf).
 """
@@ -39,6 +45,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from nanofed_tpu.ops import attention
+from nanofed_tpu.ops import experts as kernels
+from nanofed_tpu.ops._common import auto_interpret
 
 _F32 = jnp.float32
 
@@ -127,12 +135,16 @@ def sigmoid_route(router, x, top_k: int, scale: float, bias=None):
     return picks, scale * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
 
 
-def _zeros_varying_like(shape, *like):
-    """Float32 zeros that vary over every mesh axis one of ``like`` varies over: inside
+def _varying_like(array, *like):
+    """``array`` varying over every mesh axis one of ``like`` varies over: inside
     ``shard_map`` a loop's carry has to start with the type its update will have."""
     axes = set().union(*(jax.typeof(a).vma for a in like))
-    zeros = jnp.zeros(shape, _F32)
-    return lax.pcast(zeros, tuple(axes), to="varying") if axes else zeros
+    return lax.pcast(array, tuple(axes), to="varying") if axes else array
+
+
+def _zeros_varying_like(shape, *like):
+    """Float32 zeros so typed."""
+    return _varying_like(jnp.zeros(shape, _F32), *like)
 
 
 def _block_operands(b, block, x, gate, src, block_expert, w_in, w_out):
@@ -207,6 +219,125 @@ def _expert_blocks_bwd(activation, block, saved, d_out):
 expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
 
 
+#: Rows a step of the gathers and scatters around the kernels moves.  The layout has room
+#: for every token's picks (its static ``rows``) and the routing fills a fraction of it (a
+#: held expert sees 1/4 to 1/16 of its deployment load in the cells), so the rows are
+#: moved in a loop over the chunks IN USE, as the kernels run the blocks in use: 1024 rows
+#: is the largest block the loop's own gather and scatter-add were measured at on a v5e
+#: before they fall off XLA's fast path (from 1280 rows: 2.7 times the round, PERF.md §6,
+#: PR 31).
+CHUNK = 1024
+
+
+def _chunks_in_use(src, used, body, start):
+    """``body(at, fresh, carry)`` over the chunks of the layout's first ``used`` rows:
+    ``at`` is where a chunk's rows begin, moved back where the last chunk would pass the
+    layout's end, and ``fresh`` [chunk] says which of its rows no earlier chunk held."""
+    rows = src.shape[0]
+    chunk = min(CHUNK, rows)
+
+    def step(c, carry):
+        at = jnp.minimum(c * chunk, rows - chunk)
+        return body(at, at + jnp.arange(chunk, dtype=jnp.int32) >= c * chunk, carry)
+
+    return lax.fori_loop(0, -(-used // chunk), step, start)
+
+
+def _laid_out(x, gate, src, used, *by_token):
+    """The layout's rows in use: ``(x's rows [rows, d], their gates [rows, 1], ...)`` and
+    the rows of each further ``by_token`` array, gathered a chunk at a time.  An empty
+    row's pick is ``n * top_k`` and its token ``n``, one past the end: it reads zeros, a
+    gate of zero among them.  Rows past ``used`` are left as they were allocated."""
+    top_k = gate.shape[0] // x.shape[0]
+
+    def body(at, fresh, made):
+        picks = lax.dynamic_slice_in_dim(src, at, fresh.shape[0])
+        tokens = picks // top_k
+        by = lambda a: a.at[tokens].get(mode="fill", fill_value=0)
+        got = (by(x), gate.at[picks].get(mode="fill", fill_value=0)[:, None], *map(by, by_token))
+        return tuple(lax.dynamic_update_slice_in_dim(m, g, at, 0) for m, g in zip(made, got))
+
+    empty = lambda width, dtype: _varying_like(
+        lax.empty((src.shape[0], width), dtype), x, gate, src, *by_token)
+    start = (empty(x.shape[1], x.dtype), empty(1, gate.dtype),
+             *(empty(a.shape[1], a.dtype) for a in by_token))
+    return _chunks_in_use(src, used, body, start)
+
+
+def _to_tokens(like, src, used, rows, top_k):
+    """``rows`` [layout's rows, d] of the layout added to their tokens, ``[n, d]`` zeros
+    ``like``-typed elsewhere; an empty row's is dropped."""
+    def body(at, fresh, out):
+        tokens = jnp.where(fresh, lax.dynamic_slice_in_dim(src, at, fresh.shape[0]) // top_k,
+                           out.shape[0])
+        return out.at[tokens].add(lax.dynamic_slice_in_dim(rows, at, fresh.shape[0]), mode="drop")
+
+    return _chunks_in_use(src, used, body, like)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def expert_tiles(x, gate, src, block_expert, n_blocks, w_in, w_out, activation, block,
+                 interpret=False):
+    """:func:`expert_blocks`, its arguments and its result, as a grouped matmul over the
+    layout's blocks (``ops.experts``): the rows in use are gathered by token (a loop over
+    chunks of :data:`CHUNK` rows, no product in it), ONE kernel walks the blocks in use
+    with an expert's two matrices resident in VMEM while its blocks run, and the gated
+    results are added back to their tokens (a second such loop).  The backward pass
+    gathers the rows and the cotangent's rows again and runs the second kernel, which
+    keeps an expert's two float32 weight gradients in VMEM over its blocks and writes them
+    once.  Every held expert needs a block (:func:`dispatch` gives an idle one an empty
+    block)."""
+    return _expert_tiles_fwd(x, gate, src, block_expert, n_blocks, w_in, w_out, activation,
+                             block, interpret)[0]
+
+
+def _expert_tiles_fwd(x, gate, src, block_expert, n_blocks, w_in, w_out, activation, block,
+                      interpret):
+    used = n_blocks * block
+    rows, gates = _laid_out(x, gate, src, used)
+    y = kernels.expert_tiles(rows, gates, block_expert, n_blocks.reshape(1), w_in, w_out,
+                             activation=activation, tile=block, interpret=interpret)
+    zeros = _zeros_varying_like(x.shape, x, gate, src, w_in, w_out).astype(x.dtype)
+    return (_to_tokens(zeros, src, used, y, gate.shape[0] // x.shape[0]),
+            (x, gate, src, block_expert, n_blocks, w_in, w_out))
+
+
+def _expert_tiles_bwd(activation, block, interpret, saved, d_out):
+    x, gate, src, block_expert, n_blocks, w_in, w_out = saved
+    used = n_blocks * block
+    # The written backward is traced outside the forward's ``with``: it names its scope.
+    with jax.named_scope("moe_experts"):
+        rows, gates, dy = _laid_out(x, gate, src, used, d_out)
+        d_rows, d_gates, d_in, d_o = kernels.expert_tiles_grads(
+            rows, gates, dy, block_expert, n_blocks.reshape(1), w_in, w_out,
+            activation=activation, tile=block, interpret=interpret)
+        zeros = lambda like: _zeros_varying_like(like.shape, x, gate, src, d_out, w_in, w_out)
+        dx = _to_tokens(zeros(x).astype(x.dtype), src, used, d_rows, gate.shape[0] // x.shape[0])
+
+        def a_chunk_of_d_gate(at, fresh, d_gate):
+            picks = jnp.where(fresh, lax.dynamic_slice_in_dim(src, at, fresh.shape[0]), gate.shape[0])
+            return d_gate.at[picks].set(
+                lax.dynamic_slice_in_dim(d_gates[:, 0], at, fresh.shape[0]), mode="drop")
+
+        d_gate = _chunks_in_use(src, used, a_chunk_of_d_gate, zeros(gate))
+    return dx, d_gate.astype(gate.dtype), None, None, None, d_in, d_o
+
+
+expert_tiles.defvjp(_expert_tiles_fwd, _expert_tiles_bwd)
+
+
+def kernels_run(x, w_in, w_out, block: int) -> bool:
+    """Which of the two spellings :func:`held_experts` runs, from shapes and platform
+    alone: the kernels (:func:`expert_tiles`) on the TPU where tokens and experts have one
+    dtype, blocks and matrices are whole sublane tiles and an expert's matrices fit VMEM
+    with their accumulators (``ops.experts.engages``); the loop (:func:`expert_blocks`)
+    everywhere else, off the TPU above all, where it is also what the kernels are tested
+    against."""
+    (d, f_in), f = w_in.shape[1:], w_out.shape[1]
+    return (not auto_interpret(None) and x.dtype == w_in.dtype == w_out.dtype
+            and kernels.engages(block, d, f_in, f, x.dtype))
+
+
 def dispatch(picks, *, first_expert: int, held: int, block: int):
     """Where each pick that lands on experts ``first_expert .. first_expert + held`` goes
     in a layout by expert in whole blocks of ``block`` rows: ``(src [rows] int32,
@@ -227,7 +358,7 @@ def dispatch(picks, *, first_expert: int, held: int, block: int):
     lands = local[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
     ones = lands.astype(jnp.int32)
     counts = ones.sum(axis=1)
-    padded = -(-counts // block) * block
+    padded = jnp.maximum(-(-counts // block), 1) * block  # an idle expert: one empty block
     ends = jnp.cumsum(padded)
     rows = n * min(top_k, held) + held * block
     rows = -(-rows // block) * block
@@ -242,23 +373,28 @@ def dispatch(picks, *, first_expert: int, held: int, block: int):
     return src, block_expert, ends[-1] // block, counts, ends
 
 
-def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int, block: int,
-                 activation: Activation):
+def held_experts(x, picks, weights, w_in, w_out, *, first_expert: int,
+                 activation: Activation, block: int | None = None):
     """The held experts' part of the routed output for tokens ``x`` [n, d], and the
     layer's :data:`COUNTERS` (float32 ``[3]``).  ``picks`` [n, top_k] int32 name experts
     among ALL the router scores, ``weights`` [n, top_k] float32 what each pick's output
     is scaled by; ``w_in`` / ``w_out`` hold experts ``first_expert .. first_expert +
-    w_in.shape[0]``."""
+    w_in.shape[0]``.  ``block``, the rows an expert's picks are padded to a multiple of and
+    the kernels' row tile, follows from the experts' shape (``ops.experts.tile_rows``)
+    unless a test gives one."""
     n, top_k = picks.shape
     held = w_in.shape[0]
+    if block is None:
+        block = kernels.tile_rows(*w_in.shape[1:])
     with jax.named_scope("moe_dispatch"):
         src, block_expert, n_blocks, counts, ends = dispatch(
             picks, first_expert=first_expert, held=held, block=block)
         src, block_expert, n_blocks = map(
             checkpoint_name, (src, block_expert, n_blocks), KEPT)
     with jax.named_scope("moe_experts"):
-        out = expert_blocks(x, weights.reshape(n * top_k), src, block_expert, n_blocks,
-                            w_in, w_out, activation, block)
+        experts_of = expert_tiles if kernels_run(x, w_in, w_out, block) else expert_blocks
+        out = experts_of(x, weights.reshape(n * top_k), src, block_expert, n_blocks,
+                         w_in, w_out, activation, block)
     landed = counts.sum().astype(_F32)
     even = jnp.where(landed > 0, counts.max() * held / jnp.maximum(landed, 1.0), 1.0)
     fill = jnp.where(landed > 0, landed / jnp.maximum(ends[-1], 1).astype(_F32), 1.0)

@@ -70,15 +70,6 @@ from nanofed_tpu.models.moe_decoder import rotate
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
-#: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
-#: block costs).  This model's own number, measured at its cell (8192 tokens a step, 8 of
-#: 128, 8 held: 512 rows an expert at the mean, 575-590 at the fullest): at 640 every
-#: expert fits ONE block, four fifths of its rows taken, and a round takes 1.784 s on
-#: either of two seeds; 768 pads a fifth more rows (1.804-1.805 s); 512 splits the fuller
-#: experts over two blocks and the round follows the seed (1.836, 1.863 s) (PERF.md
-#: section 6, PR 43).
-EXPERT_BLOCK = 640
-
 _F32 = jnp.float32
 
 
@@ -176,7 +167,7 @@ def feed_forward(p: Params, h: jax.Array, cfg: dict, *, dense: bool):
                                        bias=p["router_bias"])
     routed, counted = held_experts(
         tokens, picks, weights, p["w_gate_up"], p["w_down"],
-        first_expert=cfg["first_expert"], block=EXPERT_BLOCK, activation=SWIGLU)
+        first_expert=cfg["first_expert"], activation=SWIGLU)
     with jax.named_scope("moe_shared"):
         shared = gated_mlp(p["shared_gate_up"], p["shared_down"], tokens)
     return (routed + shared).reshape(n, t, d), counted

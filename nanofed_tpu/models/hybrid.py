@@ -27,11 +27,12 @@ Step sizes, decays and their cumulative sums stay float32 under mixed precision.
 **Experts** (:func:`expert_layer`): the layer is TOLD which experts it holds
 (``first_expert``, ``experts_held``).  The router (``moe_router``, float32) scores all
 ``experts`` with a sigmoid, picks ``top_k`` and normalises over all picks; picks that
-land on held experts are laid out by expert in whole blocks of ``EXPERT_BLOCK`` rows
-(``moe_dispatch``) and the held experts' squared-ReLU MLPs run over the blocks in use
-(``moe_experts``: ``models.experts.expert_blocks``, the loop every routing model of the
-zoo shares; its trip count follows the routing, so no capacity limit and no dropped
-token, and no work on blocks nobody fills); the shared
+land on held experts are laid out by expert in whole blocks of rows (``moe_dispatch``; a
+block's rows follow from the experts' shape) and the held experts' squared-ReLU MLPs run
+over the blocks in use (``moe_experts``: ``models.experts.held_experts``, the grouped
+matmul every routing model of the zoo shares, kernels on the TPU and a loop elsewhere;
+the blocks it runs follow the routing, so no capacity limit and no dropped token, and no
+work on blocks nobody fills); the shared
 expert (``moe_shared``) sees every token.  What experts held elsewhere would add is
 left out — on one chip the layer runs without its exchange, and a sum over all the
 shares, the shared expert counted once, is the uncut layer (tests).  The layer reports
@@ -54,18 +55,6 @@ from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
 from nanofed_tpu.models.experts import KEEP_NAMED_OUTPUTS, RELU2, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
-
-#: Rows a block of the expert loop holds: a held expert's picks are padded to whole
-#: blocks, so a block multiplies one expert's matrices.  A block's cost is mostly fixed
-#: (its expert's two matrices read, two float32 gradient accumulators read and written),
-#: so the round's time follows the loop's trip count.  At 1536 an expert with up to eight
-#: times the mean load of a 4096-token step at 6 of 128 (192 rows) fits ONE block: the loop
-#: runs once an expert whatever the routing, and a round's time does not move with the
-#: seed.  At 256 the trip count followed the router's imbalance (9 to 13 blocks a layer)
-#: and round times spread by 2.4% across seeds; at 1024 an expert still crossed a block on
-#: two seeds in six (PERF.md section 6).  A fuller expert takes more blocks: nothing is
-#: dropped.
-EXPERT_BLOCK = 1536
 
 #: What ``apply.with_counters`` reports beside the log-probabilities, each the mean over
 #: the ``E`` layers of one batch: the share of all picks that landed on held experts, and
@@ -272,7 +261,7 @@ def routed_experts(p: Params, x: jax.Array, cfg: dict):
     with jax.named_scope("moe_router"):
         picks, weights = route(p["router"], x, cfg)
     out, counted = held_experts(x, picks, weights, p["w_up"], p["w_down"],
-                                first_expert=cfg["first_expert"], block=EXPERT_BLOCK,
+                                first_expert=cfg["first_expert"],
                                 activation=RELU2)
     return out, counted[:len(COUNTERS)]
 

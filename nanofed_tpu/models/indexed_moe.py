@@ -74,13 +74,6 @@ from nanofed_tpu.models.moe_decoder import route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
-#: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
-#: block costs).  This model's own number, measured at its cell (8192 tokens a step, 8 of
-#: 128, 16 held: 512 rows an expert at the mean, 580 at the fullest): at 768 every expert
-#: fits ONE block, two thirds of its rows taken, and a round takes 2.724 s; 1024 pads a
-#: third more rows (2.832 s); 512 splits most experts over two blocks (2.781 s)
-#: (PERF.md section 6, PR 40).
-EXPERT_BLOCK = 768
 #: Queries a band of the indexer holds: the configuration's ``q_chunk_size``, and the
 #: attention kernels' block at the cell's length, so that :data:`SPARSE_COUNTERS`' live
 #: blocks are the kernels' own.  A band's scores are ``[J, keys, band]`` float32 before
@@ -300,7 +293,7 @@ def decoder_layer(p: Params, x: jax.Array, pos: jax.Array, cfg: dict):
         picks, weights = route(p["router"], h.reshape(n * t, d), cfg["top_k"])
     routed, counted = held_experts(
         h.reshape(n * t, d), picks, weights, p["w_gate_up"], p["w_down"],
-        first_expert=cfg["first_expert"], block=EXPERT_BLOCK, activation=SWIGLU)
+        first_expert=cfg["first_expert"], activation=SWIGLU)
     return x + routed.reshape(n, t, d), jnp.concatenate([counted, picked])
 
 

@@ -64,11 +64,6 @@ from nanofed_tpu.models.moe_decoder import rotate
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
-#: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
-#: block costs).  This model's own number, measured at its cell (8192 tokens a step, 6 of
-#: 64, 8 held: 768 rows an expert at the mean; PERF.md section 6, PR 33).
-EXPERT_BLOCK = 1024
-
 _F32 = jnp.float32
 
 
@@ -167,7 +162,7 @@ def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, dense: bool):
                                        bias=p["router_bias"])
     routed, counted = held_experts(
         tokens, picks, weights, p["w_gate_up"], p["w_down"],
-        first_expert=cfg["first_expert"], block=EXPERT_BLOCK, activation=SWIGLU)
+        first_expert=cfg["first_expert"], activation=SWIGLU)
     with jax.named_scope("moe_shared"):
         shared = gated_mlp(p["shared_gate_up"], p["shared_down"], tokens)
     return x + (routed + shared).reshape(n, t, d), counted

@@ -53,16 +53,6 @@ from nanofed_tpu.models.hybrid import rms_norm
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
-#: Rows a block of the expert loop holds (``models.hybrid.EXPERT_BLOCK`` says what a
-#: block costs).  A model's own number, measured at its cell (8192 tokens a step, 6 of
-#: 64, 16 held: 768 rows an expert at the mean, 850 at the fullest): at 1024 every
-#: expert fits ONE block, so the loop runs 16 times a layer whatever the seed, three
-#: quarters of its rows taken; 768 and 512 split an expert over two blocks and cost 6%
-#: and 11% of the round; from 1280 rows on the block's gather and scatter-add of
-#: ``[rows, 2560]`` fall off a cliff on a v5e and the round takes 2.7 times as long
-#: (PERF.md section 6, PR 31).
-EXPERT_BLOCK = 1024
-
 _F32 = jnp.float32
 
 
@@ -146,7 +136,7 @@ def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, rope: bool, window: int
     h = rms_norm(p["norm_post"], x, cfg["eps"])
     routed, counted = held_experts(
         h.reshape(n * t, d), picks, weights, p["w_gate_up"], p["w_down"],
-        first_expert=cfg["first_expert"], block=EXPERT_BLOCK, activation=REGLU)
+        first_expert=cfg["first_expert"], activation=REGLU)
     return x + routed.reshape(n, t, d), counted
 
 

@@ -21,8 +21,14 @@ hardware PRNG buys something XLA's pattern library doesn't express:
                       array on either pass; ``models.transformer`` takes it for long
                       sequences.
 
-Every op takes ``interpret=None`` (auto: real kernels on TPU, interpreter elsewhere) so
-the same code paths are exercised by the CPU-mesh test suite.
+* ``ops.experts``   — the held experts' MLPs of a mixture-of-experts layer as a grouped
+                      matmul over row tiles, forward and backward, an expert's matrices
+                      and its float32 weight gradients resident in VMEM while its tiles
+                      run; ``models.experts.held_experts`` takes it on the TPU.
+
+Every exported op takes ``interpret=None`` (auto: real kernels on TPU, interpreter elsewhere) so
+the same code paths are exercised by the CPU-mesh test suite; ``ops.experts``' entry points
+are jitted with ``interpret`` static, and their caller decides (``models.experts.kernels_run``).
 """
 
 from nanofed_tpu.ops.attention import causal_attention

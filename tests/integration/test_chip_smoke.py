@@ -31,6 +31,7 @@ TINY = chip_smoke.SmokeSize(
     kernel_params=1300,  # not a lane multiple: the padding paths run
     kernel_cohorts=(8, 40),
     attention_shape=(1, 2, 512, 32),
+    experts_shape=(64, 3, 16, 4, 32, 16, 8),
     interpret=True,
 )
 
@@ -49,7 +50,7 @@ def test_every_phase_passes_tiny_on_the_cpu_mesh(tmp_path, devices):
     assert wire["accepted"] == 12 and wire["aggregations_completed"] == 3
     assert wire["flat_size"] == 4810  # digits_mlp
     assert set(records["kernels"]) >= {
-        "u32", "C=8", "C=40", "weighted_mean_tree", "causal_attention"}
+        "u32", "C=8", "C=40", "weighted_mean_tree", "causal_attention", "expert_tiles"}
     multi = records["multichip"]
     assert multi["4"]["client_rows_per_device"] == 4
     assert multi["2x2"]["client_rows_per_device"] == 8

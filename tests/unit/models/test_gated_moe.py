@@ -22,9 +22,13 @@ import pytest
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.core.types import ClientData
 from nanofed_tpu.models import experts, gated_moe, get_model
+from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
 from nanofed_tpu.trainer import TrainingConfig
+
+#: The layout's block for experts as small as the tests': the largest row tile.
+DEFAULT_BLOCK = ops_experts.TILES[0]
 
 REPO = Path(__file__).resolve().parents[3]
 SMALL = {
@@ -70,11 +74,12 @@ def _one_layer(params, kind, index=0):
     return jax.tree.map(lambda leaf: leaf[index], params[kind])
 
 
-@pytest.fixture(params=[8, gated_moe.EXPERT_BLOCK], ids=["blocks-of-8", "one-block-an-expert"])
+@pytest.fixture(params=[8, None], ids=["blocks-of-8", "one-block-an-expert"])
 def expert_block(request, monkeypatch):
-    """At 8 rows a block an expert's ~18 picks span several blocks; at the default every
+    """At 8 rows a block an expert's ~18 picks span several blocks; at the block the experts' shape gives every
     expert fits one."""
-    monkeypatch.setattr(gated_moe, "EXPERT_BLOCK", request.param)
+    if request.param:
+        monkeypatch.setattr(ops_experts, "tile_rows", lambda d, f_in: request.param)
     return request.param
 
 
@@ -332,7 +337,7 @@ def test_counters_are_the_mean_over_the_expert_layers(reference):
     assert tuple(counters) == experts.COUNTERS == gated_moe.COUNTERS
     assert 0.1 < float(counters["moe_held_pick_share"]) < 0.5  # 4 of 16 held: 0.25 if uniform
     assert 1.0 <= float(counters["moe_load_max_over_mean"]) <= SMALL["experts_held"]
-    one_block_each = float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * gated_moe.EXPERT_BLOCK)
+    one_block_each = float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * DEFAULT_BLOCK)
     assert one_block_each * 0.999 <= float(counters["moe_block_fill"]) <= 4 * one_block_each
     all_dense = get_model("gated_moe_lm", **{**SMALL, "sliding_layout": [1, 0], "dense_layers": 2})
     assert not hasattr(all_dense.apply, "with_counters")

@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData, ClientMetrics
 from nanofed_tpu.models import get_model, hybrid
+from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import MODEL_AXIS, make_mesh, param_partition_spec
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
 from nanofed_tpu.aggregation.base import fedavg_strategy
@@ -49,11 +50,12 @@ def seeded(reference):
     return params, tokens
 
 
-@pytest.fixture(params=[8, hybrid.EXPERT_BLOCK], ids=["blocks-of-8", "one-block-an-expert"])
+@pytest.fixture(params=[8, None], ids=["blocks-of-8", "one-block-an-expert"])
 def expert_block(request, monkeypatch):
     """At 8 rows a block an expert's ~12 picks span two blocks, so the expert loop runs
-    several blocks an expert; at the default every expert fits one."""
-    monkeypatch.setattr(hybrid, "EXPERT_BLOCK", request.param)
+    several blocks an expert; at the block the experts' shape gives every expert fits one."""
+    if request.param:
+        monkeypatch.setattr(ops_experts, "tile_rows", lambda d, f_in: request.param)
     return request.param
 
 

@@ -25,6 +25,10 @@ import pytest
 
 from nanofed_tpu.models import experts, get_model, indexed_moe, moe_decoder
 from nanofed_tpu.ops import attention
+from nanofed_tpu.ops import experts as ops_experts
+
+#: The layout's block for experts as small as the tests': the largest row tile.
+DEFAULT_BLOCK = ops_experts.TILES[0]
 
 REPO = Path(__file__).resolve().parents[3]
 SMALL = {
@@ -368,7 +372,7 @@ def test_counters_count_the_pick_and_the_routing(reference, index_band):
     assert live == pytest.approx(round(live), abs=1e-4) and bands <= round(live) <= bands * (bands + 1) / 2
     assert 0.1 < float(counters["moe_held_pick_share"]) < 0.5  # 4 of 16 held: 0.25 if uniform
     assert float(counters["moe_block_fill"]) == pytest.approx(
-        float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * indexed_moe.EXPERT_BLOCK), rel=1e-5)
+        float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * DEFAULT_BLOCK), rel=1e-5)
 
 
 def test_live_blocks_by_hand(monkeypatch):

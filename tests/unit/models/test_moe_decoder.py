@@ -24,9 +24,13 @@ from nanofed_tpu.core.types import ClientData
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.models import experts, get_model, hybrid, moe_decoder
 from nanofed_tpu.ops import attention
+from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
 from nanofed_tpu.trainer import TrainingConfig
+
+#: The layout's block for experts as small as the tests': the largest row tile.
+DEFAULT_BLOCK = ops_experts.TILES[0]
 
 REPO = Path(__file__).resolve().parents[3]
 SMALL = {
@@ -66,11 +70,12 @@ def _seeded(reference, kw, batch=3):
     return params, tokens
 
 
-@pytest.fixture(params=[8, moe_decoder.EXPERT_BLOCK], ids=["blocks-of-8", "one-block-an-expert"])
+@pytest.fixture(params=[8, None], ids=["blocks-of-8", "one-block-an-expert"])
 def expert_block(request, monkeypatch):
-    """At 8 rows a block an expert's ~18 picks span several blocks; at the default every
+    """At 8 rows a block an expert's ~18 picks span several blocks; at the block the experts' shape gives every
     expert fits one."""
-    monkeypatch.setattr(moe_decoder, "EXPERT_BLOCK", request.param)
+    if request.param:
+        monkeypatch.setattr(ops_experts, "tile_rows", lambda d, f_in: request.param)
     return request.param
 
 
@@ -303,7 +308,7 @@ def test_counters_count_this_models_routing(reference):
     assert 1.0 <= float(counters["moe_load_max_over_mean"]) <= SMALL["experts_held"]
     # 96 tokens x 3 picks x ~1/4 land here, four experts, one block each.
     assert float(counters["moe_block_fill"]) == pytest.approx(
-        float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * moe_decoder.EXPERT_BLOCK), rel=1e-5)
+        float(counters["moe_held_pick_share"]) * 96 * 3 / (4 * DEFAULT_BLOCK), rel=1e-5)
 
 
 def test_block_fill_by_hand():
@@ -388,7 +393,7 @@ def test_the_hybrid_runs_the_shared_loop():
     out, counted = hybrid.routed_experts(p, x, kw)
     picks, weights = hybrid.route(p["router"], x, kw)
     want, all_counted = experts.held_experts(x, picks, weights, p["w_up"], p["w_down"], first_expert=4,
-                                             block=hybrid.EXPERT_BLOCK, activation=experts.RELU2)
+                                             activation=experts.RELU2)
     np.testing.assert_array_equal(out, want)
     np.testing.assert_array_equal(counted, all_counted[:2])
     assert hybrid.COUNTERS == experts.COUNTERS[:2]

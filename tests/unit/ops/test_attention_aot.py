@@ -8,7 +8,9 @@ cell's embedding gradient, for where the compiler places its accumulators, the
 moonlight cell's kernels with score and value heads of different sizes, the keye cell's
 kernels under a computed mask, and a rematerialized layer of each of the four decoders,
 for what it launches twice and what it keeps (the trinity cell's: the windowed kernels with
-eight query heads a key/value head, a 2048 window at 8192 positions)."""
+eight query heads a key/value head, a 2048 window at 8192 positions); in those layers the
+held experts' kernels (``ops.experts``) at the four decoders' widths, and alone at the
+hybrid's, whose experts are 1856 wide: no whole lanes."""
 
 import re
 
@@ -19,8 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from nanofed_tpu import nn
 from nanofed_tpu.models import (
-    gated_moe, get_model, indexed_moe, latent_moe, moe_decoder, transformer)
+    experts, gated_moe, get_model, indexed_moe, latent_moe, moe_decoder, transformer)
 from nanofed_tpu.ops import attention
+from nanofed_tpu.ops import experts as expert_kernels
 from nanofed_tpu.ops.attention import causal_attention
 from nanofed_tpu.trainer.local import make_grad_fn
 
@@ -264,10 +267,12 @@ def decoder_steps(request, one_chip):
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(attention, "auto_interpret", lambda interpret: False)
+        patch.setattr(experts, "auto_interpret", lambda interpret: False)
         kept = compile_step()
         patch.setattr(module, "KEEP_NAMED_OUTPUTS", None)
         plain = compile_step()
-    return {"kept": kept, "plain": plain, "layers": layers, "kept_bytes": layers * kept_bytes}
+    return {"kept": kept, "plain": plain, "layers": layers, "kept_bytes": layers * kept_bytes,
+            "cell": request.param}
 
 
 def _kernel_launches(compiled, kernel: str) -> int:
@@ -317,3 +322,28 @@ def test_a_rematerialized_expert_layer_lays_its_picks_out_once(decoder_steps):
                 for which, text in texts.items()}
     assert scatters == {"kept": 1, "plain": 2}
     assert not re.search(r' sort\([^\n]*op_name="[^"]*moe_dispatch', texts["plain"])
+
+
+def test_an_expert_layer_runs_the_two_expert_kernels(decoder_steps):
+    """Each cell has one expert layer here: its step launches the forward kernel once
+    (twice in the trinity cell's, whose sandwich norms keep the rerun alive) and the
+    backward kernel once, compiled at the cell's widths under the client ``vmap``."""
+    forward = 2 if decoder_steps["cell"] == "trinity" else 1
+    assert [_kernel_launches(decoder_steps["kept"], kernel)
+            for kernel in ("expert_tiles_fwd", "expert_tiles_bwd")] == [forward, 1]
+
+
+def test_the_expert_kernels_compile_at_the_hybrids_widths(one_chip):
+    """``[2688, 1856]`` and ``[1856, 2688]``, squared ReLU: rows that are not whole lanes,
+    and the largest accumulators of the five cells (single-buffered matrices in the
+    backward kernel)."""
+    tile = expert_kernels.tile_rows(2688, 1856)
+    assert expert_kernels.engages(tile, 2688, 1856, 1856, jnp.bfloat16)
+    rows = 4096 * 6 + 8 * tile
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    operands = (shaped((rows, 2688)), shaped((rows, 1), jnp.float32), shaped((rows // tile,), jnp.int32),
+                shaped((1,), jnp.int32), shaped((8, 2688, 1856)), shaped((8, 1856, 2688)))
+    static = dict(activation=experts.RELU2, tile=tile)
+    for kernel, args in ((expert_kernels.expert_tiles, operands),
+                         (expert_kernels.expert_tiles_grads, (*operands[:2], operands[0], *operands[2:]))):
+        assert "tpu_custom_call" in kernel.lower(*args, **static).compile().as_text()
