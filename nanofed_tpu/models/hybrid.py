@@ -15,7 +15,9 @@ layer is rematerialized (``jax.checkpoint``): the backward pass keeps one ``[N, 
 width]`` activation a layer and recomputes inside it, but for what carries a name
 (``models.experts.KEEP_NAMED_OUTPUTS``): an ``E`` layer's integer dispatch layout
 (``src``, ``block_expert``, the trip count: under 0.3 MB), so its picks are laid out
-once a step; a mixer or an attention layer names nothing and keeps nothing.
+once a step; an attention layer's kernel output and log-sum-exp (``ops.attention.KEPT``:
+33.5 MB a step at the cell's shape), so its forward kernel runs once a step; a mixer
+names nothing and keeps nothing.
 
 **Mamba-2** (``ssm_mixer``): ``[z | xBC | dt] = u W_in``; a causal depthwise convolution
 and SiLU on ``xBC``; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t +
@@ -38,7 +40,11 @@ left out — on one chip the layer runs without its exchange, and a sum over all
 shares, the shared expert counted once, is the uncut layer (tests).  The layer reports
 two counters (:data:`COUNTERS`) through ``apply.with_counters``.
 
-**Attention** (``gqa_attention``): grouped-query, causal, full-square scores, no rotary.
+**Attention** (``gqa_attention``): grouped-query, causal, no rotary.  From 512 positions
+in whole blocks (``ops.attention.engages``) the attention proper is ``ops.attention``'s
+two kernels, as in the zoo's four other decoders: the causal block pairs alone, float32
+scores and softmax statistics in VMEM, no copy of a group's keys and values; elsewhere
+the dense full-square spelling (``dense_causal_attention``).
 """
 
 from __future__ import annotations
@@ -55,6 +61,7 @@ from nanofed_tpu.models.base import Model, register_model
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
 from nanofed_tpu.models.experts import KEEP_NAMED_OUTPUTS, RELU2, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
+from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
 #: What ``apply.with_counters`` reports beside the log-probabilities, each the mean over
 #: the ``E`` layers of one batch: the share of all picks that landed on held experts, and
@@ -210,34 +217,20 @@ def mamba_mixer(p: Params, u: jax.Array, cfg: dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-#: Queries a block of the attention loop holds.  The scores are the full square, one
-#: ``[block, T]`` band at a time, each band recomputed in the backward pass: at ``T`` =
-#: 2048 a whole float32 ``[N, heads, T, T]`` would not fit beside the round's parameters.
-QUERY_BLOCK = 512
-
-
 def gqa_attention(p: Params, x: jax.Array, cfg: dict) -> jax.Array:
     """Causal grouped-query attention with no positional term: ``attn_heads`` query
-    heads share ``kv_heads`` keys and values; every query block against all keys
-    (masked), softmax in float32."""
+    heads share ``kv_heads`` keys and values, query head ``h`` reading head
+    ``h // (attn_heads / kv_heads)``; ``ops.attention``'s kernels where they take the
+    length, the dense spelling elsewhere."""
     n, t, _ = x.shape
     hq, hkv, hd = cfg["attn_heads"], cfg["kv_heads"], cfg["head_dim"]
-    block = QUERY_BLOCK if t % QUERY_BLOCK == 0 else t
     with jax.named_scope("gqa_attention"):
-        q = (x @ p["wq"]).reshape(n, t // block, block, hkv, hq // hkv, hd)
+        q = (x @ p["wq"]).reshape(n, t, hq, hd)
         k = (x @ p["wk"]).reshape(n, t, hkv, hd)
         v = (x @ p["wv"]).reshape(n, t, hkv, hd)
-
-        @jax.checkpoint
-        def band(args):
-            q_block, first = args
-            scores = jnp.einsum("nqkgd,nskd->nkgqs", q_block, k, preferred_element_type=_F32)
-            seen = jnp.arange(t)[None, :] <= first + jnp.arange(block)[:, None]
-            att = jax.nn.softmax(jnp.where(seen, scores / math.sqrt(hd), -jnp.inf), axis=-1)
-            return jnp.einsum("nkgqs,nskd->nqkgd", att.astype(x.dtype), v)
-
-        out = lax.map(band, (jnp.moveaxis(q, 1, 0), jnp.arange(t // block) * block))
-        return jnp.moveaxis(out, 0, 1).reshape(n, t, hq * hd) @ p["wo"]
+        attend = causal_attention if engages(t) else dense_causal_attention
+        out = attend(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)))
+        return out.transpose(0, 2, 1, 3).reshape(n, t, hq * hd) @ p["wo"]
 
 
 # ---------------------------------------------------------------------------

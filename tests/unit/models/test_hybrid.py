@@ -6,6 +6,7 @@ amplified through nine layers — hence tolerances of 1e-5 on log-probabilities 
 relative on a leaf's gradient, far under anything a missing term would give."""
 
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -17,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData, ClientMetrics
 from nanofed_tpu.models import get_model, hybrid
+from nanofed_tpu.ops import attention
 from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import MODEL_AXIS, make_mesh, param_partition_spec
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
@@ -175,13 +177,91 @@ def test_the_whole_stack_is_causal(seeded):
     assert float(jnp.abs(before[:, -1] - after[:, -1]).max()) > 1e-6  # the order is carried
 
 
-def test_attention_in_query_blocks_is_the_full_square(seeded, monkeypatch):
-    params, _ = seeded
+#: The cell's attention layer with fewer heads: four query heads a key/value head, 128 wide.
+ATTENTION = {"width": 64, "attn_heads": 8, "kv_heads": 2, "head_dim": 128}
+
+
+def _attention_layer(reference, t):
+    """``(leaves of one attention layer, x [1, t, width], weights of a scalar loss)``."""
+    params = reference.init_params(jax.random.key(0), {**SMALL, **ATTENTION, "pattern": "*"})
     p = jax.tree.map(lambda leaf: leaf[0], params["attn"])
-    x = jax.random.normal(jax.random.key(4), (2, 32, SMALL["width"]))
-    whole = hybrid.gqa_attention(p, x, SMALL)
-    monkeypatch.setattr(hybrid, "QUERY_BLOCK", 8)
-    np.testing.assert_allclose(hybrid.gqa_attention(p, x, SMALL), whole, atol=1e-6)
+    x = jax.random.normal(jax.random.key(4), (1, t, ATTENTION["width"]))
+    return p, x, jax.random.normal(jax.random.key(5), x.shape)
+
+
+def _own_layer(p, x):
+    return hybrid.gqa_attention(p, x, ATTENTION)
+
+
+def _value_and_gradients(layer, p, x, weigh):
+    """The layer's output, and the gradients of a weighted sum of it in ``wq``, ``wk``,
+    ``wv``, ``wo`` (and the norm's leaf, which the layer does not read) and in ``x``."""
+    weighed = lambda p, x: (layer(p, x) * weigh).sum()
+    return jax.jit(layer)(p, x), jax.jit(jax.grad(weighed, (0, 1)))(p, x)
+
+
+def _the_references(reference, p, x, weigh):
+    """The same of the float32 reference's banded layer."""
+    return _value_and_gradients(
+        lambda p, x: reference._attention(p, x, ATTENTION, IDENTITY), p, x, weigh)
+
+
+def _assert_same(got, want, rtol=1e-4):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.linalg.norm(g - w)) <= rtol * float(jnp.linalg.norm(w)), g.shape
+
+
+@pytest.mark.parametrize("oracle", ["dense", "reference"])
+def test_attention_on_the_kernels_is_the_dense_layer(reference, monkeypatch, kernel_calls, oracle):
+    """At 512 positions the layer's attention proper is ``ops.attention``'s two kernels
+    (Pallas's interpreter here), grouped: the output and the gradients of the four
+    projections and of ``x`` are what ``dense_causal_attention`` gives on the same
+    ``q``/``k``/``v``, and what the float32 reference's banded layer gives."""
+    p, x, weigh = _attention_layer(reference, 512)
+    launches = lambda: kernel_calls(jax.grad(lambda p: _own_layer(p, x).sum()), p)
+    assert launches() == {"causal_attention_fwd": 1, "causal_attention_bwd": 1}
+    got = _value_and_gradients(_own_layer, p, x, weigh)
+    if oracle == "dense":
+        monkeypatch.setattr(hybrid, "engages", lambda t: False)
+        assert launches() == {}
+        want = _value_and_gradients(_own_layer, p, x, weigh)
+    else:
+        want = _the_references(reference, p, x, weigh)
+    _assert_same(got, want)
+
+
+@pytest.mark.parametrize("t", [32, 640], ids=["under-512", "not-whole-blocks"])
+def test_where_the_kernels_do_not_engage_the_dense_spelling_answers(reference, monkeypatch,
+                                                                    kernel_calls, t):
+    p, x, weigh = _attention_layer(reference, t)
+    assert kernel_calls(jax.grad(lambda p: _own_layer(p, x).sum()), p) == {}
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 128)  # 640 is five bands of the reference's
+    _assert_same(_value_and_gradients(_own_layer, p, x, weigh), _the_references(reference, p, x, weigh))
+
+
+@pytest.mark.parametrize("kept,forwards", [(True, 1), (False, 2)],
+                         ids=["the-models-policy", "a-plain-checkpoint"])
+def test_the_lowered_training_step_holds_each_attention_kernel_once(monkeypatch, kept, forwards):
+    """The TPU-platform lowering (made on the CPU) of a ``MEM*E`` hybrid's training step at
+    512 positions in bfloat16: one forward and one backward kernel module for its one
+    attention layer, the layer's checkpoint keeping the forward's two named outputs; a
+    plain checkpoint launches the forward a second time."""
+    monkeypatch.setattr(attention, "auto_interpret", lambda interpret: False)  # as on the TPU
+    if not kept:
+        monkeypatch.setattr(hybrid, "KEEP_NAMED_OUTPUTS", None)
+    model = get_model("hybrid_lm", **{**SMALL, **ATTENTION, "seq_len": 512, "pattern": "MEM*E"})
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 512), jnp.int32)
+
+    def loss(params, tokens):
+        half = jax.tree.map(lambda leaf: leaf.astype(jnp.bfloat16), params)
+        return -model.apply(half, tokens)[:, 7].mean()
+
+    text = jax.jit(jax.value_and_grad(loss)).trace(params, tokens).lower(
+        lowering_platforms=("tpu",)).as_text()
+    modules = {kernel: len(re.findall(rf'kernel_name = "{kernel}"', text))
+               for kernel in ("causal_attention_fwd", "causal_attention_bwd")}
+    assert modules == {"causal_attention_fwd": forwards, "causal_attention_bwd": 1}
 
 
 def test_counters_count_the_picks_that_land_here(reference, seeded):

@@ -25,8 +25,8 @@ from nanofed_tpu.trainer import TrainingConfig
 #: ``(factory, its module, a tiny configuration)``; at 512 positions the kernels engage
 #: (Pallas's interpreter here), at 32 the dense spelling answers.  One full and three
 #: windowed layers with seven query heads a key/value head; one dense and two expert
-#: layers with 24-wide scores over 16-wide values; two mixers, one attention layer that
-#: never runs the kernels and two expert layers.
+#: layers with 24-wide scores over 16-wide values; two mixers, one attention layer with two
+#: query heads a key/value head and two expert layers.
 DECODERS = {
     "moe_decoder": ("moe_decoder_lm", moe_decoder, {
         "vocab": 64, "seq_len": 512, "width": 64, "rope_layout": [0, 1, 1, 1],
@@ -36,7 +36,7 @@ DECODERS = {
         "vocab": 64, "seq_len": 512, "width": 64, "heads": 4, "latent_rank": 32, "nope_dim": 16,
         "rope_dim": 8, "value_dim": 16, "dense_layers": 1, "dense_width": 160, "expert_layers": 2,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 48}),
-    "hybrid": ("hybrid_lm", hybrid, {"vocab": 64, "seq_len": 32, "pattern": "MEM*E"}),
+    "hybrid": ("hybrid_lm", hybrid, {"vocab": 64, "seq_len": 512, "pattern": "MEM*E", "chunk": 32}),
     # Two layers whose attention runs under a pick of 96 keys, eight query heads a
     # key/value head (at 32 positions: a pick of 8, densely).
     "indexed_moe": ("indexed_moe_lm", indexed_moe, {
@@ -55,7 +55,7 @@ LAUNCHES = {
     "moe_decoder": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
                     "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
     "latent_moe": {"causal_attention_fwd": 3, "causal_attention_bwd": 3},
-    "hybrid": {},
+    "hybrid": {"causal_attention_fwd": 1, "causal_attention_bwd": 1},
     "indexed_moe": {"causal_attention_fwd_keep": 2, "causal_attention_bwd_keep": 2},
     "gated_moe": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
                   "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
@@ -68,7 +68,7 @@ NEVER_MOVED = {"indexed_moe": 3}
 
 #: Fewer layers of each for the tests that run a step operation by operation.
 SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
-           "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "ME"},
+           "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "M*E"},
            "indexed_moe": {"layers": 1}, "gated_moe": {"sliding_layout": [1, 0]}}
 
 
