@@ -1,7 +1,8 @@
 """The held experts of a mixture-of-experts layer: dispatch and the grouped matmul, shared
 by every model of the zoo that routes (``models.hybrid``, ``models.moe_decoder``,
-``models.latent_moe``, ``models.indexed_moe``, ``models.gated_moe``), and the sigmoid
-router three of them share (:func:`sigmoid_route`).
+``models.latent_moe``, ``models.indexed_moe``, ``models.gated_moe``), the two routers
+they pick with (:func:`route` over logits, :func:`sigmoid_route`) and the one check of
+which experts a program holds (:func:`check_held`).
 
 A layer is TOLD which experts it holds (``first_expert``, ``held``) of the ``experts``
 its router scores.  The model routes — its own scores, its own normalisation — and hands
@@ -117,6 +118,21 @@ RELU2 = Activation(lambda pre: jnp.square(jax.nn.relu(pre)), _relu2_with_grad)
 REGLU = Activation(_reglu, _reglu_with_grad)
 #: ``silu(gate) * up`` of ``pre = [gate | up]``.
 SWIGLU = Activation(_swiglu, _swiglu_with_grad)
+
+
+def check_held(experts: int, first_expert: int, experts_held: int, top_k: int) -> None:
+    """Refuse a model whose held experts ``first_expert .. first_expert + experts_held``
+    do not lie among the ``experts`` its router scores, or that picks more than those."""
+    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
+        raise ValueError("the held experts must lie among the routed ones, top_k within them")
+
+
+def route(router: jax.Array, u: jax.Array, top_k: int):
+    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL the experts the
+    router scores: logits in float32, the ``top_k`` largest, softmax over those."""
+    logits = jnp.matmul(u.astype(_F32), router.astype(_F32), precision=lax.Precision.HIGHEST)
+    top, picks = lax.top_k(logits, top_k)
+    return picks, jax.nn.softmax(top, axis=-1)
 
 
 def sigmoid_route(router, x, top_k: int, scale: float, bias=None):

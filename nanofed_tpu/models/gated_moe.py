@@ -4,10 +4,10 @@ with a shared one, its first layers dense (the ``afmoe`` layout: ``layer_types``
 ``sliding_window``, ``num_dense_layers``, ``score_func`` ``sigmoid``, ``route_norm``,
 ``route_scale``, ``num_shared_experts``, ``mup_enabled``).
 
-The zoo's first decoder ASSEMBLED from the others' parts: ``moe_decoder.rotate`` and the
+The zoo's first decoder ASSEMBLED from shared parts: ``decoder.rotate`` and the
 window of ``ops.attention``'s kernels, ``experts.sigmoid_route`` with its selection bias,
-``experts.held_experts`` with :data:`SWIGLU`, ``latent_moe.gated_mlp`` for the dense
-layer and the shared expert, ``hybrid.rms_norm`` (per head on ``q`` and ``k``, as
+``experts.held_experts`` with :data:`SWIGLU`, ``decoder.gated_mlp`` for the dense
+layer and the shared expert, ``decoder.rms_norm`` (per head on ``q`` and ``k``, as
 ``indexed_moe`` uses it).  What it adds is the order they come in.  A layer, ``x`` [N, T,
 d] (no bias anywhere; every norm an RMSNorm with a weight of its own)::
 
@@ -58,15 +58,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
-from nanofed_tpu.models.experts import (
-    COUNTERS, KEEP_NAMED_OUTPUTS, SWIGLU, held_experts, sigmoid_route)
-from nanofed_tpu.models.hybrid import rms_norm
-from nanofed_tpu.models.latent_moe import gated_mlp
-from nanofed_tpu.models.moe_decoder import rotate
+from nanofed_tpu.models.decoder import gated_mlp, language_model, rms_norm, rotate, run_layers
+from nanofed_tpu.models.experts import COUNTERS, SWIGLU, check_held, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
@@ -187,16 +183,13 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     rows = embed_rows(params["embed"], tokens.astype(jnp.int32))
     with jax.named_scope("token_embed"):
         x = (rows.astype(_F32) * math.sqrt(cfg["width"])).astype(rows.dtype)
-    counters = jnp.zeros((len(COUNTERS),), _F32)
-    with jax.named_scope("layer_scan"):
-        for index, sliding in enumerate(cfg["sliding_layout"]):
-            dense = index < cfg["dense_layers"]
-            kind, at = ("dense", index) if dense else ("moe", index - cfg["dense_layers"])
-            layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=dense, sliding=bool(sliding)),
-                                   policy=KEEP_NAMED_OUTPUTS)
-            x, counted = layer(jax.tree.map(lambda leaf: leaf[at], params[kind]), x)
-            counters = counters + counted
-    return x, counters
+    plan = []
+    for index, sliding in enumerate(cfg["sliding_layout"]):
+        dense = index < cfg["dense_layers"]
+        kind, at = ("dense", index) if dense else ("moe", index - cfg["dense_layers"])
+        plan.append((partial(decoder_layer, cfg=cfg, dense=dense, sliding=bool(sliding)),
+                     params[kind], at))
+    return run_layers(x, plan, len(COUNTERS))
 
 
 @register_model("gated_moe_lm")
@@ -233,29 +226,6 @@ def gated_moe_lm(
         raise ValueError("sliding_layout: one flag a layer; dense_layers count the first of them")
     if attn_heads % kv_heads or head_dim % 2 or window < 1:
         raise ValueError("attn_heads must divide into kv_heads, head_dim in two, window >= 1")
-    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
-        raise ValueError("the held experts must lie among the routed ones, top_k within them")
-    expert_layers = len(sliding_layout) - dense_layers
-
-    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
-        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
-        del train, rng  # no dropout
-        hidden, counters = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
-        return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / max(expert_layers, 1)))
-
-    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
-        return with_counters(params, x, train=train, rng=rng)[0]
-
-    if expert_layers:
-        apply.with_counters = with_counters
-    return Model(
-        name="gated_moe_lm",
-        init=partial(init_gated_moe, **cfg),
-        apply=apply,
-        input_shape=(seq_len,),
-        num_classes=vocab,
-        token_stream=True,
-    )
+    check_held(experts, first_expert, experts_held, top_k)
+    return language_model("gated_moe_lm", cfg, init_gated_moe, hidden_states, COUNTERS,
+                          len(sliding_layout) - dense_layers)

@@ -58,8 +58,9 @@ from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
+from nanofed_tpu.models.decoder import language_model, rms_norm, run_layers
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
-from nanofed_tpu.models.experts import KEEP_NAMED_OUTPUTS, RELU2, held_experts, sigmoid_route
+from nanofed_tpu.models.experts import RELU2, check_held, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
@@ -129,13 +130,6 @@ def init_hybrid(rng: PRNGKey, *, vocab, width, pattern, mamba_heads, mamba_head_
             "shared_down": normal(k[16], n_e, shared_width, width, scale=resid),
         },
     }
-
-
-def rms_norm(weight: jax.Array, x: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm over the last axis, statistics in float32, result in ``x``'s dtype."""
-    x32 = x.astype(_F32)
-    scale = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale * weight.astype(_F32)).astype(x.dtype)
 
 
 def relu2(x: jax.Array) -> jax.Array:
@@ -238,21 +232,16 @@ def gqa_attention(p: Params, x: jax.Array, cfg: dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def route(router: jax.Array, x: jax.Array, cfg: dict):
-    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL ``experts``: the
-    zoo's sigmoid router (``experts.sigmoid_route``) with no selection bias.  (The
-    published router adds a balancing bias before picking; this model has no such leaf,
-    which is a bias of zero.  ``latent_moe`` carries one.)"""
-    return sigmoid_route(router, x, cfg["top_k"], cfg["routed_scale"])
-
-
 def routed_experts(p: Params, x: jax.Array, cfg: dict):
     """The held experts' part of the routed output for tokens ``x`` [n, d], and the two
     counters.  ``p["w_up"]``/``p["w_down"]`` hold experts ``first_expert ..
     first_expert + experts_held`` of the ``experts`` the router scores.  Dispatch and the
-    block loop are the zoo's shared ones (``models.experts``), with the squared ReLU."""
+    block loop are the zoo's shared ones (``models.experts``), with the squared ReLU; the
+    router is the zoo's sigmoid one with no selection bias (the published router adds a
+    balancing bias before picking; this model has no such leaf, which is a bias of zero.
+    ``latent_moe`` carries one)."""
     with jax.named_scope("moe_router"):
-        picks, weights = route(p["router"], x, cfg)
+        picks, weights = sigmoid_route(p["router"], x, cfg["top_k"], cfg["routed_scale"])
     out, counted = held_experts(x, picks, weights, p["w_up"], p["w_down"],
                                 first_expert=cfg["first_expert"],
                                 activation=RELU2)
@@ -273,35 +262,31 @@ def expert_layer(p: Params, x: jax.Array, cfg: dict):
 # The stack
 # ---------------------------------------------------------------------------
 
-def _counting_nothing(mixer):
-    """``mixer`` with an expert layer's return: ``(output, counters)``, the counters zero."""
-    return lambda p, x, cfg: (mixer(p, x, cfg), jnp.zeros((len(COUNTERS),), _F32))
-
-
 #: Pattern letter -> (the subtree of ``params`` its layers are stacked in, the mixer).
-_MIXERS = {"M": ("mamba", _counting_nothing(mamba_mixer)),
-           "*": ("attn", _counting_nothing(gqa_attention)), "E": ("moe", expert_layer)}
+_MIXERS = {"M": ("mamba", mamba_mixer), "*": ("attn", gqa_attention), "E": ("moe", expert_layer)}
+
+
+def residual_layer(p: Params, x: jax.Array, cfg: dict, *, mixer):
+    """``(x + mixer(RMSNorm(x)), the layer's counters)``: one pre-norm layer of the stack;
+    only an expert layer counts, the other mixers' counters are zero."""
+    u = rms_norm(p["norm"], x, cfg["eps"])
+    if mixer is expert_layer:
+        mixed, counted = expert_layer(p, u, cfg)
+    else:
+        mixed, counted = mixer(p, u, cfg), jnp.zeros((len(COUNTERS),), _F32)
+    return x + mixed, counted
 
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the ``E`` layers)."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     seen = dict.fromkeys(_MIXERS, 0)
-    counters = jnp.zeros((len(COUNTERS),), _F32)
-    with jax.named_scope("layer_scan"):
-        for letter in cfg["pattern"]:
-            kind, mixer = _MIXERS[letter]
-            index = seen[letter]
-            seen[letter] += 1
-
-            @partial(jax.checkpoint, policy=KEEP_NAMED_OUTPUTS)
-            def layer(p, x, mixer=mixer):
-                mixed, counted = mixer(p, rms_norm(p["norm"], x, cfg["eps"]), cfg)
-                return x + mixed, counted
-
-            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
-            counters = counters + counted
-    return x, counters
+    plan = []
+    for letter in cfg["pattern"]:
+        kind, mixer = _MIXERS[letter]
+        plan.append((partial(residual_layer, cfg=cfg, mixer=mixer), params[kind], seen[letter]))
+        seen[letter] += 1
+    return run_layers(x, plan, len(COUNTERS))
 
 
 @register_model("hybrid_lm")
@@ -338,32 +323,11 @@ def hybrid_lm(
         raise ValueError(f"seq_len {seq_len} must be a multiple of the scan's chunk {chunk}")
     if mamba_heads % ssm_groups or attn_heads % kv_heads:
         raise ValueError("mamba_heads must divide into ssm_groups, attn_heads into kv_heads")
-    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
-        raise ValueError("the held experts must lie among the routed ones, top_k within them")
-    n_e = pattern.count("E")
+    check_held(experts, first_expert, experts_held, top_k)
 
-    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
-        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
-        del train, rng  # no dropout
+    def whole_chunks(x):
         if x.shape[1] % chunk:
             raise ValueError(f"sequence length {x.shape[1]} is not a multiple of chunk {chunk}")
-        hidden, counters = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
-        counters = lax.stop_gradient(counters) / max(n_e, 1)
-        return logp, dict(zip(COUNTERS, counters))
 
-    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
-        return with_counters(params, x, train=train, rng=rng)[0]
-
-    if n_e:
-        apply.with_counters = with_counters
-    return Model(
-        name="hybrid_lm",
-        init=partial(init_hybrid, **cfg),
-        apply=apply,
-        input_shape=(seq_len,),
-        num_classes=vocab,
-        token_stream=True,
-    )
+    return language_model("hybrid_lm", cfg, init_hybrid, hidden_states, COUNTERS,
+                          pattern.count("E"), check=whole_chunks)

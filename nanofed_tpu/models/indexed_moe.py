@@ -50,7 +50,7 @@ bind and the layer is plain causal grouped-query attention, statically.
 **Experts**: the layer is TOLD which experts it holds (``first_expert``,
 ``experts_held``); dispatch and the block loop are ``models.experts``', with the
 SiLU-gated activation on a fused ``[d, 2 f]`` leaf; the router is
-``moe_decoder.route``.  The layer reports :data:`COUNTERS` through
+``experts.route``.  The layer reports :data:`COUNTERS` through
 ``apply.with_counters``: the experts' three and the pick's two.
 """
 
@@ -67,10 +67,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
+from nanofed_tpu.models.decoder import (
+    language_model, pair_frequencies, rms_norm, run_layers, turn_pairs)
 from nanofed_tpu.models.experts import COUNTERS as EXPERT_COUNTERS
-from nanofed_tpu.models.experts import INDEXER_KEPT, KEEP_NAMED_OUTPUTS, SWIGLU, held_experts
-from nanofed_tpu.models.hybrid import rms_norm
-from nanofed_tpu.models.moe_decoder import route
+from nanofed_tpu.models.experts import INDEXER_KEPT, SWIGLU, check_held, held_experts, route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
@@ -144,18 +144,13 @@ def text_positions(seq_len: int) -> jax.Array:
 
 def rotate(x: jax.Array, pos: jax.Array, theta: float, sections) -> jax.Array:
     """Sectioned rotary positions on ``x`` [N, T, heads, hd]: dimension ``i`` pairs with
-    ``i + hd/2`` (``moe_decoder.rotate``'s pairing) and pair ``i`` turns by ``pos[c(i), t] *
+    ``i + hd/2`` (``decoder.turn_pairs``) and pair ``i`` turns by ``pos[c(i), t] *
     theta^(-2i/hd)``, ``c(i)`` the component whose section of the ``hd/2`` pairs holds
     ``i`` (``sections`` = 16, 24, 24: pairs 0-15 component 0, 16-39 component 1, 40-63
-    component 2).  Float32 angles and arithmetic, the result in ``x``'s dtype; with
-    :func:`text_positions` this is ``moe_decoder.rotate``."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
+    component 2).  Float32 angles; with :func:`text_positions` this is ``decoder.rotate``."""
+    freq = pair_frequencies(x.shape[-1] // 2, theta)
     component = np.repeat(np.arange(len(sections)), sections)
-    angle = pos.astype(_F32)[component, :].T * freq[None, :]  # [T, half]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    a, b = x[..., :half].astype(_F32), x[..., half:].astype(_F32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    return turn_pairs(x, pos.astype(_F32)[component, :].T * freq[None, :])  # angle [T, half]
 
 
 def _count(flags) -> list[jax.Array]:
@@ -302,13 +297,9 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: dict, pos: jax.Array |
     ``pos`` [3, T] defaults to a text sequence's."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
     pos = text_positions(tokens.shape[1]) if pos is None else pos
-    counters = jnp.zeros((len(COUNTERS),), _F32)
-    with jax.named_scope("layer_scan"):
-        layer = jax.checkpoint(partial(decoder_layer, cfg=cfg), policy=KEEP_NAMED_OUTPUTS)
-        for index in range(cfg["layers"]):
-            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x, pos)
-            counters = counters + counted
-    return x, counters
+    layer = partial(decoder_layer, cfg=cfg)  # all layers alike: one trace
+    plan = [(layer, params["layers"], index) for index in range(cfg["layers"])]
+    return run_layers(x, plan, len(COUNTERS), pos)
 
 
 @register_model("indexed_moe_lm")
@@ -344,27 +335,6 @@ def indexed_moe_lm(
     if len(rope_sections) != 3 or sum(rope_sections) != head_dim // 2:
         raise ValueError(f"rope_sections {rope_sections}: three counts that add up to "
                          f"head_dim / 2 = {head_dim // 2}")
-    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
-        raise ValueError("the held experts must lie among the routed ones, top_k within them")
-
-    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
-        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
-        del train, rng  # no dropout
-        hidden, counters = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
-        return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / layers))
-
-    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
-        return with_counters(params, x, train=train, rng=rng)[0]
-
-    apply.with_counters = with_counters
-    return Model(
-        name="indexed_moe_lm",
-        init=partial(init_indexed_moe, **cfg),
-        apply=apply,
-        input_shape=(seq_len,),
-        num_classes=vocab,
-        token_stream=True,
-    )
+    check_held(experts, first_expert, experts_held, top_k)
+    return language_model("indexed_moe_lm", cfg, init_indexed_moe, hidden_states, COUNTERS,
+                          layers)

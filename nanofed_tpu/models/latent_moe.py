@@ -36,13 +36,13 @@ out again.
 is whole blocks of at least ``MIN_SEQ`` positions, with score heads of ``nope + rope``
 and value heads of ``value`` dimensions, and densely below that (tests).  The program
 hands them ``k = k_nope | broadcast(k_pe)`` written out a head; autodiff sums the rotary
-part of ``dK`` over the heads.  Rotary positions are ``moe_decoder.rotate``'s (the
+part of ``dK`` over the heads.  Rotary positions are ``decoder.rotate``'s (the
 rotate-half pairing, float32 angles) on the ``rope`` dimensions.
 
 **Experts**: the layer is TOLD which experts it holds (``first_expert``,
 ``experts_held``); dispatch and the block loop are ``models.experts``', here with the
 SiLU-gated activation on a fused ``[d, 2 f]`` leaf.  The shared experts and the dense
-layer's MLP are one function of a width (:func:`gated_mlp`).  The expert layers report
+layer's MLP are one function of a width (``decoder.gated_mlp``).  The expert layers report
 :data:`COUNTERS` through ``apply.with_counters``.
 """
 
@@ -53,14 +53,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
-from nanofed_tpu.models.experts import (
-    COUNTERS, KEEP_NAMED_OUTPUTS, SWIGLU, held_experts, sigmoid_route)
-from nanofed_tpu.models.hybrid import rms_norm
-from nanofed_tpu.models.moe_decoder import rotate
+from nanofed_tpu.models.decoder import gated_mlp, language_model, rms_norm, rotate, run_layers
+from nanofed_tpu.models.experts import COUNTERS, SWIGLU, check_held, held_experts, sigmoid_route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
@@ -115,12 +112,6 @@ def init_latent_moe(rng: PRNGKey, *, vocab, width, heads, latent_rank, nope_dim,
     }
 
 
-def gated_mlp(w_gate_up: jax.Array, w_down: jax.Array, h: jax.Array) -> jax.Array:
-    """``W_down (silu(W_gate h) * (W_up h))`` on a fused ``[d, 2 f]`` leaf: the dense
-    layer's MLP and the shared experts, each at its own width."""
-    return SWIGLU.apply(h @ w_gate_up) @ w_down
-
-
 def latent_attention(p: Params, u: jax.Array, cfg: dict) -> jax.Array:
     """Causal latent attention over the normed ``u`` [N, T, d], its output projection
     included: keys and values come up from one ``latent_rank``-wide normed latent, and
@@ -171,15 +162,11 @@ def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, dense: bool):
 def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the expert layers)."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
-    counters = jnp.zeros((len(COUNTERS),), _F32)
-    with jax.named_scope("layer_scan"):
-        for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
-            layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, dense=kind == "dense"),
-                                   policy=KEEP_NAMED_OUTPUTS)
-            for index in range(count):
-                x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params[kind]), x)
-                counters = counters + counted
-    return x, counters
+    plan = []
+    for kind, count in (("dense", cfg["dense_layers"]), ("moe", cfg["expert_layers"])):
+        layer = partial(decoder_layer, cfg=cfg, dense=kind == "dense")  # one trace a kind
+        plan += [(layer, params[kind], index) for index in range(count)]
+    return run_layers(x, plan, len(COUNTERS))
 
 
 @register_model("latent_moe_lm")
@@ -215,28 +202,6 @@ def latent_moe_lm(
     if rope_dim % 2 or min(heads, latent_rank, nope_dim + rope_dim, value_dim) < 1:
         raise ValueError("rope_dim must divide in two; heads, latent_rank, score and value "
                          "head sizes at least 1")
-    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
-        raise ValueError("the held experts must lie among the routed ones, top_k within them")
-
-    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
-        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
-        del train, rng  # no dropout
-        hidden, counters = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
-        return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / max(expert_layers, 1)))
-
-    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
-        return with_counters(params, x, train=train, rng=rng)[0]
-
-    if expert_layers:
-        apply.with_counters = with_counters
-    return Model(
-        name="latent_moe_lm",
-        init=partial(init_latent_moe, **cfg),
-        apply=apply,
-        input_shape=(seq_len,),
-        num_classes=vocab,
-        token_stream=True,
-    )
+    check_held(experts, first_expert, experts_held, top_k)
+    return language_model("latent_moe_lm", cfg, init_latent_moe, hidden_states, COUNTERS,
+                          expert_layers)

@@ -44,12 +44,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from nanofed_tpu.core.types import Params, PRNGKey
 from nanofed_tpu.models.base import Model, register_model
-from nanofed_tpu.models.experts import COUNTERS, KEEP_NAMED_OUTPUTS, REGLU, held_experts
-from nanofed_tpu.models.hybrid import rms_norm
+from nanofed_tpu.models.decoder import language_model, rms_norm, rotate, run_layers
+from nanofed_tpu.models.experts import COUNTERS, REGLU, check_held, held_experts, route
 from nanofed_tpu.nn import embed_rows
 from nanofed_tpu.ops.attention import causal_attention, dense_causal_attention, engages
 
@@ -84,26 +83,6 @@ def init_moe_decoder(rng: PRNGKey, *, vocab, width, rope_layout, attn_heads, kv_
             "w_down": normal(k[8], n, experts_held, expert_width, width, std=into_stream),
         },
     }
-
-
-def route(router: jax.Array, u: jax.Array, top_k: int):
-    """``(picks [n, top_k] int32, weights [n, top_k] float32)`` over ALL the experts the
-    router scores: logits in float32, the ``top_k`` largest, softmax over those."""
-    logits = jnp.matmul(u.astype(_F32), router.astype(_F32), precision=lax.Precision.HIGHEST)
-    top, picks = lax.top_k(logits, top_k)
-    return picks, jax.nn.softmax(top, axis=-1)
-
-
-def rotate(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions on ``x`` [N, T, heads, hd]: dimension ``i`` pairs with ``i + hd/2``
-    and the pair at position ``t`` turns by ``t * theta^(-2i/hd)``; float32 angles and
-    arithmetic, the result in ``x``'s dtype."""
-    t, half = x.shape[1], x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
-    angle = jnp.arange(t, dtype=_F32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    a, b = x[..., :half].astype(_F32), x[..., half:].astype(_F32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
 
 def attention(p: Params, u: jax.Array, cfg: dict, *, rope: bool, window: int | None) -> jax.Array:
@@ -143,15 +122,10 @@ def decoder_layer(p: Params, x: jax.Array, cfg: dict, *, rope: bool, window: int
 def hidden_states(params: Params, tokens: jax.Array, cfg: dict):
     """``([N, T, width]`` after the last layer, counters summed over the layers)."""
     x = embed_rows(params["embed"], tokens.astype(jnp.int32))
-    counters = jnp.zeros((len(COUNTERS),), _F32)
-    with jax.named_scope("layer_scan"):
-        for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"])):
-            layer = jax.checkpoint(partial(decoder_layer, cfg=cfg, rope=bool(rope),
-                                           window=cfg["window"] if windowed else None),
-                                   policy=KEEP_NAMED_OUTPUTS)
-            x, counted = layer(jax.tree.map(lambda leaf: leaf[index], params["layers"]), x)
-            counters = counters + counted
-    return x, counters
+    plan = [(partial(decoder_layer, cfg=cfg, rope=bool(rope),
+                     window=cfg["window"] if windowed else None), params["layers"], index)
+            for index, (rope, windowed) in enumerate(zip(cfg["rope_layout"], cfg["window_layout"]))]
+    return run_layers(x, plan, len(COUNTERS))
 
 
 @register_model("moe_decoder_lm")
@@ -182,28 +156,6 @@ def moe_decoder_lm(
         raise ValueError("rope_layout and window_layout: one flag a layer each, same length")
     if attn_heads % kv_heads or head_dim % 2 or window < 1:
         raise ValueError("attn_heads must divide into kv_heads, head_dim in two, window >= 1")
-    if not (0 <= first_expert and first_expert + experts_held <= experts and top_k <= experts):
-        raise ValueError("the held experts must lie among the routed ones, top_k within them")
-    depth = len(rope_layout)
-
-    def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
-        """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
-        del train, rng  # no dropout
-        hidden, counters = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], eps)
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
-        return logp, dict(zip(COUNTERS, lax.stop_gradient(counters) / depth))
-
-    def apply(params: Params, x: jax.Array, *, train: bool = False, rng=None) -> jax.Array:
-        return with_counters(params, x, train=train, rng=rng)[0]
-
-    apply.with_counters = with_counters
-    return Model(
-        name="moe_decoder_lm",
-        init=partial(init_moe_decoder, **cfg),
-        apply=apply,
-        input_shape=(seq_len,),
-        num_classes=vocab,
-        token_stream=True,
-    )
+    check_held(experts, first_expert, experts_held, top_k)
+    return language_model("moe_decoder_lm", cfg, init_moe_decoder, hidden_states, COUNTERS,
+                          len(rope_layout))

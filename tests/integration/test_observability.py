@@ -29,7 +29,10 @@ from nanofed_tpu.observability import MetricsRegistry, summarize_telemetry
 from nanofed_tpu.trainer import TrainingConfig
 from nanofed_tpu.trainer.local import make_local_fit
 
-PORT = 18732
+# A base of its own: under ``--dist loadfile`` each file is another worker's, so two files
+# on one base bind the same ports at the same time.  The bases in use under ``tests/``:
+# 8931, 18432, 18473, 18560, 18632, 18732, 18832 (here), 18950, 19050, 19100, offsets to 60.
+PORT = 18832
 
 
 def _client_data(seed):

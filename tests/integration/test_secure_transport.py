@@ -41,6 +41,7 @@ from nanofed_tpu.security.secure_agg import (
     mask_update,
 )
 from nanofed_tpu.security.validation import ValidationConfig
+from nanofed_tpu.utils.clock import Clock
 
 PORT = 18473
 
@@ -224,37 +225,61 @@ def test_nan_injecting_client_is_rejected():
                for leaf in jax.tree.leaves(coordinator.params))
 
 
+class _PollsOnceAllAreIn(Clock):
+    """The coordinator's clock for a round whose barrier opens BELOW the cohort's size: a
+    poll's sleep ends when every client has submitted (an event), not after 50 ms of wall
+    clock, so what the round drains does not depend on which client the machine ran last.
+    Once all are in it is the system clock."""
+
+    def __init__(self, cohort: int):
+        self._waiting_for, self._all_in = cohort, asyncio.Event()
+
+    def one_is_in(self) -> None:
+        self._waiting_for -= 1
+        if not self._waiting_for:
+            self._all_in.set()
+
+    async def sleep(self, seconds: float) -> None:
+        if self._all_in.is_set():
+            await asyncio.sleep(seconds)
+        else:
+            await self._all_in.wait()
+
+
 def test_nan_client_dropped_but_round_completes_with_completion_rate():
-    """With min_completion_rate < 1 the round still completes from the honest cohort."""
+    """With min_completion_rate < 1 the round still completes from the honest cohort.
+
+    The barrier opens at ``ceil(4 * 0.75) = 3`` buffered updates and the assertions need
+    the poisoned one among those drained.  On a 50 ms wall-clock poll that is a race
+    either side can win (the three honest drained alone: nothing rejected; the poisoned
+    and two honest: two valid of three required, FAILED), so the round's poll here waits
+    for all four submits instead."""
     model = get_model("linear", in_features=5, num_classes=2)
     init = _client_params(model, 0)
     honest = {f"h{i}": _client_params(model, i) for i in (1, 2, 3)}
+    poisoned = jax.tree.map(lambda x: jnp.full_like(x, jnp.inf), init)
 
-    async def run_honest(cid):
+    async def run_client(cid, params, clock):
         async with HTTPClient(f"http://127.0.0.1:{PORT + 3}", cid, timeout_s=10) as c:
             await _fetch_model_retry(c, init)
-            assert await c.submit_update(honest[cid], {"num_samples": 10.0})
-
-    async def run_malicious():
-        async with HTTPClient(f"http://127.0.0.1:{PORT + 3}", "evil", timeout_s=10) as c:
-            await _fetch_model_retry(c, init)
-            poisoned = jax.tree.map(lambda x: jnp.full_like(x, jnp.inf), init)
-            assert await c.submit_update(poisoned, {"num_samples": 10.0})
+            assert await c.submit_update(params, {"num_samples": 10.0})
+            clock.one_is_in()
 
     async def main():
         server = HTTPServer(port=PORT + 3)
         await server.start()
+        clock = _PollsOnceAllAreIn(cohort=4)
         try:
             coordinator = NetworkCoordinator(
                 server, init,
                 NetworkRoundConfig(num_rounds=1, min_clients=4,
                                    min_completion_rate=0.75, round_timeout_s=10),
-                validation=ValidationConfig(max_norm=100.0),
+                validation=ValidationConfig(max_norm=100.0), clock=clock,
             )
             await asyncio.gather(
                 coordinator.run(),
-                *(run_honest(c) for c in honest),
-                run_malicious(),
+                *(run_client(cid, params, clock)
+                  for cid, params in {**honest, "evil": poisoned}.items()),
             )
             return coordinator
         finally:

@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData, ClientMetrics
-from nanofed_tpu.models import get_model, hybrid
+from nanofed_tpu.models import decoder, get_model, hybrid
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import MODEL_AXIS, make_mesh, param_partition_spec
@@ -248,7 +248,7 @@ def test_the_lowered_training_step_holds_each_attention_kernel_once(monkeypatch,
     plain checkpoint launches the forward a second time."""
     monkeypatch.setattr(attention, "auto_interpret", lambda interpret: False)  # as on the TPU
     if not kept:
-        monkeypatch.setattr(hybrid, "KEEP_NAMED_OUTPUTS", None)
+        monkeypatch.setattr(decoder, "KEEP_NAMED_OUTPUTS", None)
     model = get_model("hybrid_lm", **{**SMALL, **ATTENTION, "seq_len": 512, "pattern": "MEM*E"})
     params = jax.eval_shape(model.init, jax.random.key(0))
     tokens = jax.ShapeDtypeStruct((2, 512), jnp.int32)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nanofed_tpu.models import experts, get_model, indexed_moe, moe_decoder
+from nanofed_tpu.models import decoder, experts, get_model, indexed_moe
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops import experts as ops_experts
 
@@ -291,7 +291,7 @@ def test_positions_with_three_components_match_the_reference(reference):
 def test_text_positions_are_the_plain_rotation(reference):
     x = jax.random.normal(jax.random.key(4), (2, 40, 3, 16)).astype(jnp.bfloat16)
     got = indexed_moe.rotate(x, indexed_moe.text_positions(40), 1e7, (2, 3, 3))
-    np.testing.assert_array_equal(got, moe_decoder.rotate(x, 1e7))
+    np.testing.assert_array_equal(got, decoder.rotate(x, 1e7))
     np.testing.assert_array_equal(indexed_moe.text_positions(5), reference.text_positions(5))
     params, tokens = _seeded(reference, SMALL)
     pos = jnp.stack([jnp.arange(32.0), jnp.arange(32.0) // 2, jnp.arange(32.0) % 5])

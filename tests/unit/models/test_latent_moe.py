@@ -328,13 +328,22 @@ def test_swiglu_backward_is_autodiffs():
     np.testing.assert_allclose(pull(d_hidden), vjp(d_hidden)[0], rtol=1e-5, atol=1e-6)
 
 
-def test_the_hybrid_routes_through_the_shared_router():
-    """One sigmoid router in the zoo: ``hybrid.route`` is ``experts.sigmoid_route`` with no
-    bias, and that is the arithmetic it had (written out here as it stood)."""
-    kw = {"top_k": 3, "routed_scale": 2.5}
+def test_the_hybrid_routes_through_the_shared_router(monkeypatch):
+    """One sigmoid router in the zoo: what ``hybrid.routed_experts`` hands the held experts
+    is ``experts.sigmoid_route`` with no bias, and that is the arithmetic the hybrid's own
+    router had (written out here as it stood)."""
+    kw = {"top_k": 3, "routed_scale": 2.5, "first_expert": 0}
     router = jax.random.normal(jax.random.key(8), (32, 16))
     x = jax.random.normal(jax.random.key(9), (40, 32))
-    picks, weights = hybrid.route(router, x, kw)
+    handed = {}
+
+    def held_experts(x, picks, weights, *_, **__):
+        handed.update(picks=picks, weights=weights)
+        return x, jnp.zeros((3,))
+
+    monkeypatch.setattr(hybrid, "held_experts", held_experts)
+    hybrid.routed_experts({"router": router, "w_up": None, "w_down": None}, x, kw)
+    picks, weights = handed["picks"], handed["weights"]
     scores = jax.nn.sigmoid(jnp.matmul(x, router, precision=jax.lax.Precision.HIGHEST))
     top, want = jax.lax.top_k(scores, 3)
     np.testing.assert_array_equal(picks, want)

@@ -2,7 +2,8 @@
 (``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``, ``models.indexed_moe``,
 whose pick of keys is a third kind of kept output, ``models.gated_moe``, whose gate comes
 after the kept output and is computed again): every layer under
-``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS``, so the attention kernel's
+``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS`` (``models.decoder.run_layers``,
+the one place a layer is rematerialized: ``stack`` here), so the attention kernel's
 output and log-sum-exp stay and the kernel is launched once a layer, and the expert
 dispatch's three integer outputs stay and it runs once a layer; a plain checkpoint
 (the policy taken away, as each test does for its other side) launches and dispatches
@@ -15,37 +16,37 @@ import pytest
 
 from nanofed_tpu.aggregation.base import fedavg_strategy
 from nanofed_tpu.core.types import ClientData
-from nanofed_tpu.models import (
-    experts, gated_moe, get_model, hybrid, indexed_moe, latent_moe, moe_decoder)
+from nanofed_tpu.models import decoder as stack
+from nanofed_tpu.models import experts, get_model
 from nanofed_tpu.ops import attention
 from nanofed_tpu.parallel.mesh import make_mesh
 from nanofed_tpu.parallel.round_step import build_round_step, init_server_state
 from nanofed_tpu.trainer import TrainingConfig
 
-#: ``(factory, its module, a tiny configuration)``; at 512 positions the kernels engage
+#: ``(factory, a tiny configuration)``; at 512 positions the kernels engage
 #: (Pallas's interpreter here), at 32 the dense spelling answers.  One full and three
 #: windowed layers with seven query heads a key/value head; one dense and two expert
 #: layers with 24-wide scores over 16-wide values; two mixers, one attention layer with two
 #: query heads a key/value head and two expert layers.
 DECODERS = {
-    "moe_decoder": ("moe_decoder_lm", moe_decoder, {
+    "moe_decoder": ("moe_decoder_lm", {
         "vocab": 64, "seq_len": 512, "width": 64, "rope_layout": [0, 1, 1, 1],
         "window_layout": [0, 1, 1, 1], "window": 200, "attn_heads": 7, "kv_heads": 1,
         "head_dim": 16, "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 48}),
-    "latent_moe": ("latent_moe_lm", latent_moe, {
+    "latent_moe": ("latent_moe_lm", {
         "vocab": 64, "seq_len": 512, "width": 64, "heads": 4, "latent_rank": 32, "nope_dim": 16,
         "rope_dim": 8, "value_dim": 16, "dense_layers": 1, "dense_width": 160, "expert_layers": 2,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 48}),
-    "hybrid": ("hybrid_lm", hybrid, {"vocab": 64, "seq_len": 512, "pattern": "MEM*E", "chunk": 32}),
+    "hybrid": ("hybrid_lm", {"vocab": 64, "seq_len": 512, "pattern": "MEM*E", "chunk": 32}),
     # Two layers whose attention runs under a pick of 96 keys, eight query heads a
     # key/value head (at 32 positions: a pick of 8, densely).
-    "indexed_moe": ("indexed_moe_lm", indexed_moe, {
+    "indexed_moe": ("indexed_moe_lm", {
         "vocab": 64, "seq_len": 512, "width": 64, "layers": 2, "attn_heads": 8, "kv_heads": 1,
         "head_dim": 16, "rope_sections": [2, 3, 3], "index_heads": 4, "index_dim": 8,
         "index_topk": 96, "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 48}),
     # A dense and three expert layers, three of them sliding under a window of 200, eight
     # query heads a key/value head, an output gate after the kernels' kept output.
-    "gated_moe": ("gated_moe_lm", gated_moe, {
+    "gated_moe": ("gated_moe_lm", {
         "vocab": 64, "seq_len": 512, "width": 64, "sliding_layout": [1, 1, 0, 1], "window": 200,
         "attn_heads": 8, "kv_heads": 1, "head_dim": 16, "dense_layers": 1, "dense_width": 160,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 24}),
@@ -75,15 +76,15 @@ SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
 @pytest.fixture(params=list(DECODERS))
 def decoder(request, monkeypatch):
     """``(name, build(**changes) -> (model, params, tokens [1, T]), plainly())``:
-    ``plainly()`` leaves the module a plain ``jax.checkpoint`` for the rest of the test."""
-    factory, module, kwargs = DECODERS[request.param]
+    ``plainly()`` leaves the stack a plain ``jax.checkpoint`` for the rest of the test."""
+    factory, kwargs = DECODERS[request.param]
 
     def build(**changes):
         model = get_model(factory, **{**kwargs, **changes})
         tokens = jax.random.randint(jax.random.key(1), (1, model.input_shape[0]), 0, kwargs["vocab"])
         return model, model.init(jax.random.key(0)), tokens
 
-    return request.param, build, lambda: monkeypatch.setattr(module, "KEEP_NAMED_OUTPUTS", None)
+    return request.param, build, lambda: monkeypatch.setattr(stack, "KEEP_NAMED_OUTPUTS", None)
 
 
 def _training_step(model, tokens, cast=lambda p: p):
@@ -167,7 +168,7 @@ def test_under_512_positions_the_checkpoint_keeps_the_dispatch_alone(decoder, mo
     named = {eqn.params["name"] for eqn in equations(step(), params) if eqn.primitive.name == "name"}
     assert named == set(experts.KEPT)  # (32 positions under a pick of 96 keys: none is made)
     assert dispatches(step(), params) == EXPERT_LAYERS[name]
-    monkeypatch.setattr(DECODERS[name][1], "KEEP_NAMED_OUTPUTS", attention.KEEP_KERNEL_OUTPUTS)
+    monkeypatch.setattr(stack, "KEEP_NAMED_OUTPUTS", attention.KEEP_KERNEL_OUTPUTS)
     kernels_alone = lowered()
     assert dispatches(step(), params) == 2 * EXPERT_LAYERS[name]
     plainly()

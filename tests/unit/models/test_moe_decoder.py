@@ -22,7 +22,7 @@ import pytest
 from nanofed_tpu import nn
 from nanofed_tpu.core.types import ClientData
 from nanofed_tpu.aggregation.base import fedavg_strategy
-from nanofed_tpu.models import experts, get_model, hybrid, moe_decoder
+from nanofed_tpu.models import decoder, experts, get_model, hybrid, moe_decoder
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops import experts as ops_experts
 from nanofed_tpu.parallel.mesh import make_mesh
@@ -222,7 +222,7 @@ def test_the_router_reads_the_layers_normed_input_not_the_post_attention_state(r
 def test_the_weights_are_a_softmax_over_the_picked_logits(reference):
     router = jax.random.normal(jax.random.key(2), (SMALL["width"], SMALL["experts"]))
     u = jax.random.normal(jax.random.key(3), (10, SMALL["width"]))
-    picks, weights = moe_decoder.route(router, u, SMALL["top_k"])
+    picks, weights = experts.route(router, u, SMALL["top_k"])
     logits = u @ router
     np.testing.assert_array_equal(picks, jnp.argsort(-logits, axis=-1)[:, :SMALL["top_k"]])
     picked = jnp.take_along_axis(logits, picks, axis=-1)
@@ -232,7 +232,7 @@ def test_the_weights_are_a_softmax_over_the_picked_logits(reference):
 
 def test_rotation_is_the_rotate_half_pairing():
     x = jax.random.normal(jax.random.key(4), (1, 6, 2, 8))
-    got = moe_decoder.rotate(x, 1.5e6)
+    got = decoder.rotate(x, 1.5e6)
     np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-7)  # position 0: no turn
     for i in range(4):  # dimension i pairs with i + 4, angle t * theta^(-i/4)
         angle = jnp.arange(6.0) * 1.5e6 ** (-i / 4)
@@ -242,7 +242,7 @@ def test_rotation_is_the_rotate_half_pairing():
     # A rotation: norms kept, and q.k depends on the positions' difference alone.
     np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1), jnp.linalg.norm(x, axis=-1), rtol=1e-5)
     same = jnp.broadcast_to(x[:, :1], x.shape)
-    turned = moe_decoder.rotate(same, 1.5e6)[0, :, 0]
+    turned = decoder.rotate(same, 1.5e6)[0, :, 0]
     np.testing.assert_allclose(turned[1] @ turned[3], turned[2] @ turned[4], rtol=1e-4)
 
 
@@ -391,7 +391,7 @@ def test_the_hybrid_runs_the_shared_loop():
          "w_down": 0.2 * jax.random.normal(k[2], (4, 24, 32))}
     x = jax.random.normal(k[3], (40, 32))
     out, counted = hybrid.routed_experts(p, x, kw)
-    picks, weights = hybrid.route(p["router"], x, kw)
+    picks, weights = experts.sigmoid_route(p["router"], x, kw["top_k"], kw["routed_scale"])
     want, all_counted = experts.held_experts(x, picks, weights, p["w_up"], p["w_down"], first_expert=4,
                                              activation=experts.RELU2)
     np.testing.assert_array_equal(out, want)

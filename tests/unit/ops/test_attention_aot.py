@@ -20,8 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from nanofed_tpu import nn
-from nanofed_tpu.models import (
-    experts, gated_moe, get_model, indexed_moe, latent_moe, moe_decoder, transformer)
+from nanofed_tpu.models import decoder, experts, get_model, transformer
 from nanofed_tpu.ops import attention
 from nanofed_tpu.ops import experts as expert_kernels
 from nanofed_tpu.ops.attention import causal_attention
@@ -215,27 +214,27 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
 
 
 #: The four cells' layers at their published widths and 8192 positions, a small vocabulary
-#: around them: ``(factory, module, kwargs, layers, bytes of the output and log-sum-exp a
+#: around them: ``(factory, kwargs, layers, bytes of the output and log-sum-exp a
 #: layer keeps)``.  An expert layer keeps its dispatch's layout too: under 0.3 MB.
 DECODERS = {
-    "smallthinker": ("moe_decoder_lm", moe_decoder, dict(
+    "smallthinker": ("moe_decoder_lm", dict(
         vocab=1024, seq_len=8192, width=2560, rope_layout=[1], window_layout=[1], window=4096,
         rope_theta=1500000, attn_heads=28, kv_heads=4, head_dim=128, experts=64, first_expert=0,
         experts_held=16, top_k=6, expert_width=768, eps=1e-6), 1, 28 * 8192 * (128 * 2 + 4)),
-    "moonlight": ("latent_moe_lm", latent_moe, dict(
+    "moonlight": ("latent_moe_lm", dict(
         vocab=1024, seq_len=8192, width=2048, heads=16, latent_rank=512, nope_dim=128,
         rope_dim=64, value_dim=128, rope_theta=50000, dense_layers=1, dense_width=11264,
         expert_layers=1, experts=64, first_expert=0, experts_held=8, top_k=6, expert_width=1408,
         shared_width=2816, routed_scale=2.446, eps=1e-5), 2, 16 * 8192 * (128 * 2 + 4)),
     # ... and the pick: one int8 [8192, 8192] mask a layer.
-    "keye": ("indexed_moe_lm", indexed_moe, dict(
+    "keye": ("indexed_moe_lm", dict(
         vocab=1024, seq_len=8192, width=2048, layers=1, attn_heads=32, kv_heads=4, head_dim=128,
         rope_theta=1e7, rope_sections=[16, 24, 24], index_heads=16, index_dim=64,
         index_topk=2048, experts=128, first_expert=0, experts_held=16, top_k=8, expert_width=768,
         eps=1e-6), 1, 32 * 8192 * (128 * 2 + 4) + 8192 * 8192),
     # A dense sliding layer and a full expert layer: the window's kernels with 8 query heads
     # a key/value head, and the gate between the kept output and ``W_o``.
-    "trinity": ("gated_moe_lm", gated_moe, dict(
+    "trinity": ("gated_moe_lm", dict(
         vocab=1024, seq_len=8192, width=2048, sliding_layout=[1, 0], window=2048, rope_theta=10000,
         attn_heads=32, kv_heads=4, head_dim=128, dense_layers=1, dense_width=6144, experts=128,
         first_expert=0, experts_held=8, top_k=8, expert_width=1024, shared_width=1024,
@@ -252,7 +251,7 @@ def decoder_steps(request, one_chip):
     bfloat16 compute) compiled twice: as the model rematerializes its layers, and under a
     plain ``jax.checkpoint``.  The kernels are compiled, not interpreted: this process
     sees the CPU, so the test says so in ``auto_interpret``'s place."""
-    factory, module, kwargs, layers, kept_bytes = DECODERS[request.param]
+    factory, kwargs, layers, kept_bytes = DECODERS[request.param]
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     batch = (shaped((1, 1, 8192), jnp.int32), shaped((1, 1), jnp.int32), shaped((1, 1), jnp.float32))
 
@@ -269,7 +268,7 @@ def decoder_steps(request, one_chip):
         patch.setattr(attention, "auto_interpret", lambda interpret: False)
         patch.setattr(experts, "auto_interpret", lambda interpret: False)
         kept = compile_step()
-        patch.setattr(module, "KEEP_NAMED_OUTPUTS", None)
+        patch.setattr(decoder, "KEEP_NAMED_OUTPUTS", None)
         plain = compile_step()
     return {"kept": kept, "plain": plain, "layers": layers, "kept_bytes": layers * kept_bytes,
             "cell": request.param}
