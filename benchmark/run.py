@@ -5,7 +5,9 @@
 Builds the cell's federation from the seed, drives its first rounds (they compile or
 read the cache, warm every shape, and are what ``correct`` compares), measures whole
 rounds for ``--seconds``, frees the system, runs the plain reference, and prints one
-JSON object as the last line.  ``--trace 0`` reports the cell's end-to-end metrics,
+JSON object as the last line; its last key, ``checks``, holds every number ``correct``
+was decided from beside its limit (``[value, limit]``), and the same pairs are the last
+lines on standard error.  ``--trace 0`` reports the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics with the profiler on for a few rounds of the window,
 and prints the traced rounds' device time by the program's named scopes and by pass.
 Needs the chips the cell asks for: there is no fallback to another backend.
@@ -143,6 +145,8 @@ def main(argv: list[str] | None = None) -> int:
     result = run_cell(ROOT, args.workload, args.seed, args.seconds, bool(args.trace),
                       found, peaks, keep_trace=args.keep_trace)
     say(json.dumps(result))
+    for name, (value, limit) in result["checks"].items():
+        print(f"{name} {value} limit {limit}", file=sys.stderr, flush=True)
     return 0
 
 
@@ -242,6 +246,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     failed = len(rounds) - len(good)
     correct = (all(r["ok"] for r in rows) and compiles_in_window == 0
                and failed == 0 and len(rounds) > 0)
+    # A number that is not finite has failed; as null it leaves the line JSON.
+    checks = {**{r["name"]: [r["value"] if math.isfinite(r["value"]) else None, r["limit"]]
+                 for r in rows},
+              "compiles_in_window": [compiles_in_window, 0], "failed_rounds": [failed, 0]}
 
     # --- the metrics.
     ctx = {
@@ -272,6 +280,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
                                "device_scopes": trace.longest_scopes(scopes)}
     say(f"# {len(run['samples'])} samples of {traffic['rounds_per_sample']} round(s) in "
         f"{run['window_s']:.3f} s; {len(rounds)} rounds, {failed} failed")
+    result["checks"] = checks  # last in the line: what a record of its end keeps
     return result
 
 

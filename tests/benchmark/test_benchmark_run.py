@@ -24,6 +24,19 @@ def _run(root, workload, traced=False, seed=3, seconds=0.3):
     return run.run_cell(root, workload, seed, seconds, traced, jax.devices(), CPU_PEAKS)
 
 
+CHECKS = ["loss_gap.0", "loss_gap.1", "loss_gap.2", "first_step_gap", "update_gap",
+          "compiles_in_window", "failed_rounds"]
+
+
+def _failing(result):
+    """The names in the line's ``checks`` whose number passes its limit: why a run was
+    not ``correct``, as a record of the line's end keeps it."""
+    assert list(result)[-1] == "checks" and list(result["checks"]) == CHECKS
+    assert all(len(pair) == 2 for pair in result["checks"].values())
+    return [name for name, (value, limit) in result["checks"].items()
+            if value is None or not value <= limit]
+
+
 def _cells(root, manifest_key, workload):
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     return {m["name"] for m in manifest[manifest_key] if run.applies(m, workload)}
@@ -34,7 +47,7 @@ def test_untraced_run_reports_the_end_to_end_metrics_and_is_correct(tiny_root, w
     said = []
     monkeypatch.setattr(run, "say", lambda *parts: said.append(" ".join(map(str, parts))))
     result = _run(tiny_root, workload)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "checks"]
     assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] >= 2
     assert set(result["metrics"]) == _cells(tiny_root, "end_to_end", workload)
@@ -42,14 +55,20 @@ def test_untraced_run_reports_the_end_to_end_metrics_and_is_correct(tiny_root, w
     out = "\n".join(said)
     # Every number compared is printed beside its limit, and the sample count too.
     assert out.count("# compared ") == 5 and "(limit " in out and " samples of " in out
-    json.dumps(result)
+    # ... and is in the line itself, under its last key, each number with its limit.
+    assert _failing(result) == []
+    limits = run.load_cell(tiny_root, workload)[2]["correct"]
+    for name, (value, limit) in result["checks"].items():
+        assert f"# compared {name}: {value:.6g} (limit {limit:g})" in out or limit == 0
+        assert limit == limits.get(name.split(".")[0], 0)
+    assert json.loads(json.dumps(result))["checks"] == {k: list(v) for k, v in result["checks"].items()}
 
 
 def test_traced_run_reports_per_layer_metrics_and_finds_added_files(tiny_root):
     """``tiny-cnn.pairs`` exists only as files ADDED to the copy: a configuration file,
     a traffic file and a ``layer_metrics`` file, with entries in BENCHMARK.json."""
     result = _run(tiny_root, "tiny-cnn.pairs", traced=True)
-    assert result["correct"] is True
+    assert result["correct"] is True and _failing(result) == []
     metrics = result["metrics"]
     assert metrics["rounds_seen"]["value"] == result["attempted"] >= 3
     assert {"host_gap_ms", "mfu_pct"} <= set(metrics)
@@ -97,6 +116,7 @@ def test_traced_run_hands_the_scope_table_to_its_readers_and_prints_it(tmp_path,
     assert len(scopes) == 10 and scopes == sorted(scopes, key=lambda e: -e[1])
     assert scopes[0][0] == "moe_experts.backward" and set(result["breakdown"]) == {
         "device_ops", "idle_gaps", "device_scopes"}
+    assert list(result)[-2:] == ["breakdown", "checks"] and _failing(result) == []
     out = "\n".join(said)
     assert "# device time by scope, ms a traced round:" in out and "#   unscoped " in out
     assert "the rows' sum" in out and "(+0.000%)" in out and "# trace read in " in out
@@ -153,6 +173,9 @@ def test_a_round_that_returns_its_state_unchanged_is_not_correct(tiny_root, monk
     monkeypatch.setattr(program, "build_round_step", broken_builder)
     result = _run(tiny_root, "tiny-lm.sync")
     assert result["correct"] is False and result["failed"] == 0
+    # The line says which numbers: the steps' norms, and nothing of the window.
+    assert {"first_step_gap", "update_gap"} <= set(_failing(result)) <= set(CHECKS[:5])
+    assert result["checks"]["update_gap"][0] == pytest.approx(1.0, abs=1e-3)
 
 
 def test_a_part_of_the_cohort_left_out_is_not_correct(tiny_root, monkeypatch):
@@ -164,7 +187,45 @@ def test_a_part_of_the_cohort_left_out_is_not_correct(tiny_root, monkeypatch):
         program, "compute_weights",
         lambda n, mask: real(n, mask) * (jax.numpy.arange(n.shape[0]) % 2),
     )
-    assert _run(tiny_root, "tiny-cnn.pairs")["correct"] is False
+    result = _run(tiny_root, "tiny-cnn.pairs")
+    assert result["correct"] is False
+    failing = _failing(result)
+    assert any(name.startswith("loss_gap.") for name in failing) and "first_step_gap" in failing
+    assert set(failing) <= set(CHECKS[:5])
+
+
+def test_a_compile_inside_the_window_is_not_correct_and_the_line_says_so(tiny_root, monkeypatch):
+    """Every compared number within its limit, and one program compiled while the window
+    ran: ``checks`` counts it against its limit of 0 and names nothing else."""
+    loop = federation.load_named(tiny_root, "loops", "closed_rounds")
+    real = loop.measure
+
+    def compiling(generator, traffic, seconds, trace_dir):
+        jax.jit(lambda x: x * 3 + len(traffic))(jax.numpy.arange(7.0)).block_until_ready()
+        return real(generator, traffic, seconds, trace_dir)
+
+    monkeypatch.setattr(loop, "measure", compiling)
+    result = _run(tiny_root, "tiny-lm.sync")
+    assert result["correct"] is False and result["failed"] == 0
+    assert _failing(result) == ["compiles_in_window"]
+    assert result["checks"]["compiles_in_window"][0] >= 1
+
+
+def test_main_ends_standard_output_with_the_line_and_standard_error_with_the_checks(
+        tiny_root, monkeypatch, capsys):
+    """``main`` with the look for a chip stepped over: the result is the last line of
+    standard output, ``checks`` its last key, and the same pairs end standard error."""
+    monkeypatch.setattr(run, "ROOT", tiny_root)
+    monkeypatch.setattr(run, "configure_cache", lambda root: None)
+    monkeypatch.setattr(run, "look_for_chips", lambda root, chips: (jax.devices(), CPU_PEAKS))
+    assert run.main(["--workload", "tiny-lm.sync", "--seed", str(2**31 + 7),
+                     "--seconds", "0.3", "--trace", "0"]) == 0
+    captured = capsys.readouterr()
+    result = json.loads(captured.out.strip().splitlines()[-1])
+    assert result["correct"] is True and _failing(result) == []
+    said = captured.err.strip().splitlines()[-len(CHECKS):]
+    assert said == [f"{name} {value} limit {limit}"
+                    for name, (value, limit) in result["checks"].items()]
 
 
 def _stdout_of(cmd, cwd):
