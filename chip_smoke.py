@@ -433,15 +433,21 @@ def phase_kernels(size: SmokeSize) -> dict[str, Any]:
         loss = lambda *a: (attend(*a).astype(jnp.float32) * w.astype(jnp.float32)).sum()
         return attend(*qkv), *jax.grad(loss, (0, 1, 2))(*qkv)
 
-    got = jax.jit(lambda *a: attend_and_grads(
-        lambda *b: ops.causal_attention(*b, interpret=interp), *a))(q, k, v)
-    want = jax.jit(lambda *a: attend_and_grads(dense_causal_attention, *a))(
-        *(a.astype(jnp.float32) for a in (q, k, v)))
-    errs = {name: _rel_err(g.astype(jnp.float32), r)
-            for name, g, r in zip(("out", "dq", "dk", "dv"), got, want)}
-    for name, err in errs.items():
-        _check(err < 1.5e-2, f"causal_attention {name}: off its dense form by {err:.3g}")
-    out["causal_attention"] = errs
+    # The causal rule, then the block-diffusion branch: the same operands as a stream of two
+    # halves in blocks of 4, the kernels' mask from positions and their walk against the
+    # dense mask.
+    halves = (size.attention_shape[2] // 2, 4)
+    for what, mask in (("causal_attention", {}), ("causal_attention_blocks", {"blocks": halves})):
+        got = jax.jit(lambda *a: attend_and_grads(
+            lambda *b: ops.causal_attention(*b, interpret=interp, **mask), *a))(q, k, v)
+        want = jax.jit(lambda *a: attend_and_grads(
+            lambda *b: dense_causal_attention(*b, **mask), *a))(
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+        errs = {name: _rel_err(g.astype(jnp.float32), r)
+                for name, g, r in zip(("out", "dq", "dk", "dv"), got, want)}
+        for name, err in errs.items():
+            _check(err < 1.5e-2, f"{what} {name}: off its dense form by {err:.3g}")
+        out[what] = errs
 
     # --- the held experts' grouped matmul, value and four gradients, against the loop it
     # stands in for, both on the same bfloat16 operands.

@@ -2,6 +2,7 @@
 the ResNets serve the BASELINE.json benchmark configs)."""
 
 from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
+    diffusion_moe,
     gated_moe,
     hybrid,
     indexed_moe,
@@ -13,6 +14,7 @@ from nanofed_tpu.models import (  # noqa: F401  (registry side effects)
     transformer,
 )
 from nanofed_tpu.models.base import Model, get_model, list_models, register_model
+from nanofed_tpu.models.diffusion_moe import diffusion_moe_lm
 from nanofed_tpu.models.gated_moe import gated_moe_lm
 from nanofed_tpu.models.hybrid import hybrid_lm
 from nanofed_tpu.models.indexed_moe import indexed_moe_lm
@@ -32,6 +34,7 @@ __all__ = [
     "get_model",
     "list_models",
     "register_model",
+    "diffusion_moe_lm",
     "gated_moe_lm",
     "hybrid_lm",
     "indexed_moe_lm",

@@ -1,9 +1,9 @@
 """What a decoder-only language model of this zoo IS: the loop over its layers, the head
-and the ``Model`` around them, and the parts more than one decoder uses.  The five
+and the ``Model`` around them, and the parts more than one decoder uses.  The six
 decoders (``models.hybrid``, ``models.moe_decoder``, ``models.latent_moe``,
-``models.indexed_moe``, ``models.gated_moe``) are modules of layer functions over this
-one and ``models.experts``; none imports another.  The arrows run one way: ``ops``,
-``nn`` -> ``models.experts`` -> here -> the five.
+``models.indexed_moe``, ``models.gated_moe``, ``models.diffusion_moe``) are modules of
+layer functions over this one and ``models.experts``; none imports another.  The arrows
+run one way: ``ops``, ``nn`` -> ``models.experts`` -> here -> the six.
 
 **A layer function** is ``layer_fn(p, x, *operands) -> (x, counted)``: ``p`` the leaves of
 ONE layer (a slice of the model's stacked leaves), ``x`` [N, T, width] the residual
@@ -22,7 +22,14 @@ axis and ``embed``, ``head`` and ``norm_f`` at the top; its layer functions;
 ``(layer_fn, stacked leaves, index)`` :func:`run_layers` walks; and a
 ``@register_model`` factory that checks its own arguments
 (``models.experts.check_held`` for the held experts) and returns
-:func:`language_model`.  A new decoder joins two test tables, ``DECODERS`` in
+:func:`language_model`.  **A decoder that carries its objective** (one whose training
+is not the last position's label: ``models.diffusion_moe``'s masked denoising over a
+doubled stream) also hands :func:`language_model` an ``objective(params, x, y, *, rng) ->
+(nll [N] float32, hits [N], {counter: scalar})``: each sample's own loss, the share of
+its predictions that were right and its counters, the noise drawn from the step's ``rng``,
+the head over the positions it chooses (:func:`log_probs_at`).  It becomes
+``apply.sample_nll`` and ``trainer.local.make_grad_fn`` trains on it; ``apply`` stays
+the ``[N, vocab]`` view that evaluation reads.  A new decoder joins two test tables, ``DECODERS`` in
 ``tests/unit/models/test_layer_checkpoints.py`` (what its checkpoints keep) and in
 ``tests/unit/ops/test_attention_aot.py`` (its step compiled for the chip at a cell's
 widths), and compares itself with a plain reference under ``benchmark/reference/``.
@@ -99,16 +106,28 @@ def run_layers(x: jax.Array, plan: Sequence[tuple[Callable, Params, int]], n_cou
     return x, counters
 
 
+def log_probs_at(params: Params, hidden: jax.Array, at, eps: float) -> jax.Array:
+    """The head over chosen positions: float32 log-probabilities ``[N, ..., vocab]`` from
+    ``hidden[:, at]`` (``hidden`` [N, T, width]; ``at`` an index or a slice of its
+    positions), the final norm and the untied head running on those positions alone.
+    The one place ``lm_head`` is opened."""
+    with jax.named_scope("lm_head"):
+        chosen = rms_norm(params["norm_f"], hidden[:, at, :], eps)
+        return jax.nn.log_softmax((chosen @ params["head"]).astype(_F32))
+
+
 def language_model(name: str, cfg: dict, init: Callable, hidden_states: Callable,
                    counters: Sequence[str], counted_layers: int,
-                   check: Callable | None = None) -> Model:
+                   check: Callable | None = None, objective: Callable | None = None) -> Model:
     """The zoo entry ``name`` around a decoder's ``hidden_states(params, tokens, cfg) ->
     (hidden [N, T, width], counters summed)``: ``apply`` returns next-token
     log-probabilities at the LAST position (``[N, vocab]``, float32), the final norm and
     the untied head running on that position's hidden state alone; ``apply.with_counters``
     returns them beside ``{counter: its mean over the counted_layers}`` and is set only
     where a layer counts.  ``init`` is the model's ``init_*`` (it takes ``cfg`` whole);
-    ``check(x)`` refuses a batch the layers cannot take, at every call."""
+    ``check(x)`` refuses a batch the layers cannot take, at every call.  ``objective``
+    is what a decoder that carries its own training loss hands over (the module's
+    docstring): it becomes ``apply.sample_nll``."""
 
     def with_counters(params: Params, x: jax.Array, *, train: bool = False, rng=None):
         """``(log-probs [N, vocab] at the last position, {counter: scalar})``."""
@@ -116,9 +135,7 @@ def language_model(name: str, cfg: dict, init: Callable, hidden_states: Callable
         if check is not None:
             check(x)
         hidden, counted = hidden_states(params, x, cfg)
-        with jax.named_scope("lm_head"):
-            last = rms_norm(params["norm_f"], hidden[:, -1, :], cfg["eps"])
-            logp = jax.nn.log_softmax((last @ params["head"]).astype(_F32))
+        logp = log_probs_at(params, hidden, -1, cfg["eps"])
         counted = lax.stop_gradient(counted) / max(counted_layers, 1)
         return logp, dict(zip(counters, counted))
 
@@ -127,6 +144,8 @@ def language_model(name: str, cfg: dict, init: Callable, hidden_states: Callable
 
     if counted_layers > 0:
         apply.with_counters = with_counters
+    if objective is not None:
+        apply.sample_nll = objective
     return Model(
         name=name,
         init=partial(init, **cfg),

@@ -52,9 +52,20 @@ of the mask (``[T, block]``, 4 MiB at 8192) is fetched once a key/value head and
 group's heads use it; the backward keeps its (head, key block) order, whose resident
 operands are a head's own (``q``, ``dO``, the float32 ``dQ``), and fetches the key
 block's strip (``[block, T]``) a step, under the step's products.  A row that keeps no
-key at all is the caller's error (its output is no softmax of anything).  All four
-are static Python branches: with full heads, no window, one head size and no mask the
-kernels trace to the program they were.
+key at all is the caller's error (its output is no softmax of anything).  A
+block-diffusion mask (``blocks=(half, size)``): the sequence is a stream of one or two
+halves of ``half`` positions, a clean text and then its noised copy, each cut into blocks
+of ``size``; a clean query sees the clean keys of its own block and of every block before
+it, a noised query the clean keys of the blocks before its own and the noised keys of
+its own block alone, and no clean query sees a noised key.  That is not sub-causal
+inside a block and it is computed in :func:`_scores` from the tile's own iotas, no mask
+array anywhere.  With the clean half first every seen pair still lies on or under the
+diagonal of the tile grid, and the kernels walk only the tiles that hold one
+(:func:`_block_walk`): of a noised query tile's row the clean tiles up to its own text and
+its own noised tile, never another noised tile, and of a clean query tile's row no
+noised tile at all (80 of the 136 causal tile pairs at 8192 positions in two halves and
+tiles of 512, 24 of them masked).  All five are static Python branches: with full heads,
+no window, one head size and no mask the kernels trace to the program they were.
 
 Precision: scores, softmax statistics and every accumulator are float32; the
 probabilities (and ``dS``) are cast to the inputs' dtype for the products that consume
@@ -124,10 +135,12 @@ def block_for(seq_len: int) -> int | None:
     return next((b for b in BLOCKS if seq_len % b == 0), None)
 
 
-def engages(seq_len: int) -> bool:
+def engages(seq_len: int, half: int | None = None) -> bool:
     """Whether ``causal_attention`` takes a sequence of this length: whole blocks, and
-    long enough that keeping the scores out of HBM pays, short enough for VMEM."""
-    return MIN_SEQ <= seq_len <= MAX_SEQ and block_for(seq_len) is not None
+    long enough that keeping the scores out of HBM pays, short enough for VMEM.  Under
+    ``blocks=(half, size)`` each half has to be whole blocks too."""
+    whole = block_for(seq_len if half is None else math.gcd(seq_len, half)) is not None
+    return MIN_SEQ <= seq_len <= MAX_SEQ and whole
 
 
 def _scale(hd: int) -> tuple[float, bool]:
@@ -149,16 +162,51 @@ def _window_steps(window: int, block: int) -> tuple[int, int]:
     return window // block, (window + block - 2) // block + 1
 
 
-def _scores(k, q, *, scale, fold, masked, behind=None, window=None, keep=None):
+def _block_walk(tile, half_tiles: int, n_tiles: int):
+    """``(noised, at)`` of a tile of a block-diffusion stream of ``n_tiles`` tiles whose
+    halves are ``half_tiles`` each: whether it lies in the noised half (0 or 1; the
+    integer 0 where the stream is one half) and the tile of the text it covers.  A QUERY
+    tile visits its own tile (masked to equal blocks) if it is noised, the clean tiles
+    ``0 .. at - 1`` whole, and the clean tile ``at`` masked (blocks up to its own; strictly
+    before its own if it is noised).  A KEY tile is visited by its own query tile alone
+    if it is noised; if it is clean, by the clean query tiles ``at`` (masked) and ``at + 1
+    .. half_tiles - 1`` (whole) and the noised ones ``half_tiles + at`` (masked) and
+    ``half_tiles + at + 1 ..`` (whole)."""
+    if n_tiles == half_tiles:
+        return 0, tile
+    noised = tile // half_tiles
+    return noised, tile - noised * half_tiles
+
+
+def _once(flag, fn, carry):
+    """``fn(carry)`` if the 0-or-1 ``flag`` (traced, or a plain integer) is 1, else
+    ``carry``."""
+    if isinstance(flag, int):
+        return fn(carry) if flag else carry
+    return lax.cond(flag == 1, fn, lambda c: c, carry)
+
+
+def _scores(k, q, *, scale, fold, masked, behind=None, window=None, keep=None, within=None):
     """A score block transposed, ``[keys, queries]`` float32, from a key block and a query
     block.  ``masked`` is for the diagonal block, whose first rows share a position:
     inside it a key past its query gets ``_MASKED``.  ``behind`` (with ``window``) is how
     many positions the key block starts behind the query block: a key the window's
     length or more behind its query gets ``_MASKED`` too.  ``keep`` is the block's tile of a
-    computed mask, ``[keys, queries]`` int8: a pair whose entry is 0 gets ``_MASKED``."""
+    computed mask, ``[keys, queries]`` int8: a pair whose entry is 0 gets ``_MASKED``.
+    ``within = (size, ahead)`` is for a tile of a block-diffusion stream whose keys and
+    queries cover the same text, in blocks of ``size`` (a power of two that divides the
+    tile, so a block's last index is ``index | (size - 1)``): a key is seen while its
+    block ends ``ahead`` positions or more before the query's does (0: its own block and
+    those before it; ``size``: those before it alone), or, ``ahead`` None, in the query's
+    own block alone."""
     s = lax.dot_general(k, q, _NT, preferred_element_type=_F32)
     if not fold:
         s = s * scale
+    if within is not None:
+        size, ahead = within
+        k_end = lax.broadcasted_iota(jnp.int32, s.shape, 0) | (size - 1)
+        q_end = lax.broadcasted_iota(jnp.int32, s.shape, 1) | (size - 1)
+        return jnp.where(k_end == q_end if ahead is None else k_end + ahead <= q_end, s, _MASKED)
     if keep is not None:
         kept = keep.astype(jnp.int32) != 0
         if masked:
@@ -178,7 +226,8 @@ def _scores(k, q, *, scale, fold, masked, behind=None, window=None, keep=None):
     return s
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, keep=False):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, keep=False,
+                blocks=None):
     keep_ref, (o_ref, lse_ref, vt_ref) = (rest[0], rest[1:]) if keep else (None, rest)
     i = pl.program_id(1)
 
@@ -193,13 +242,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, kee
         q = q * scale
     hd_v = vt_ref.shape[0]
 
-    def step(j, carry, masked, behind=None):
+    def step(j, carry, masked, behind=None, within=None):
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(j * block, block), block)
         # Key down the sublanes, query along the lanes: the softmax's reductions run
         # down the sublanes, vreg against vreg.
         s = _scores(k_ref[rows, :], q, scale=scale, fold=fold, masked=masked,
-                    behind=behind, window=window,
+                    behind=behind, window=window, within=within,
                     keep=None if keep_ref is None else keep_ref[rows, :])
         m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -211,7 +260,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, kee
 
     carry = (jnp.full((1, block), _MASKED, _F32), jnp.zeros((1, block), _F32),
              jnp.zeros((hd_v, block), _F32))
-    if window is None:
+    if blocks is not None:
+        # A noised query tile starts on its own tile, where every row sees its block;
+        # then the clean tiles before its text, then the clean tile of its text.
+        half_tiles, n_tiles, size = blocks
+        noised, at = _block_walk(i, half_tiles, n_tiles)
+        carry = _once(noised, lambda c: step(i, c, masked=True, within=(size, None)), carry)
+        carry = lax.fori_loop(0, at, lambda j, c: step(j, c, masked=False), carry)
+        m, l, acc = step(at, carry, masked=True, within=(size, noised * size))
+    elif window is None:
         carry = lax.fori_loop(0, i, lambda j, c: step(j, c, masked=False), carry)
         m, l, acc = step(i, carry, masked=True)
     else:
@@ -229,7 +286,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block, scale, fold, window=None, kee
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, scale, fold,
-                window=None, keep=False):
+                window=None, keep=False, blocks=None):
     keep_ref, rest = (rest[0], rest[1:]) if keep else (None, rest)
     dq_ref, dk_ref, dv_ref, dq_acc, kt_ref = rest
     j = pl.program_id(1)
@@ -243,7 +300,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, s
     v = v_ref[...]
     kt_ref[...] = k.T  # K's block with its rows along the lanes, for dQ^T = K^T dS^T
 
-    def pair(i, carry, masked, behind=None):
+    def pair(i, carry, masked, behind=None, within=None):
         dk, dv = carry
         rows = pl.ds(pl.multiple_of(i * block, block), block)
         q = q_ref[rows, :]
@@ -251,7 +308,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, s
             q = q * scale
         do = do_ref[rows, :]
         s = _scores(k, q, scale=scale, fold=fold, masked=masked, behind=behind, window=window,
-                    keep=None if keep_ref is None else keep_ref[:, rows])
+                    within=within, keep=None if keep_ref is None else keep_ref[:, rows])
         p = jnp.exp(s - lse_ref[i])
         dp = lax.dot_general(v, do, _NT, preferred_element_type=_F32)
         ds = (p * (dp - delta_ref[i])).astype(q.dtype)
@@ -262,7 +319,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, s
 
     zeros = jnp.zeros(k.shape, _F32)
     start = (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, _F32))
-    if window is None:
+    if blocks is not None:
+        # The forward's walk seen from the key tile: a noised one meets its own query
+        # tile alone; a clean one the clean query tiles from its own on and, in a stream
+        # of two halves, the noised ones from its text's on.  The loops of the kind of
+        # tile this one is not run from a start past their end.
+        half_tiles, n_tiles, size = blocks
+        noised, at = _block_walk(j, half_tiles, n_tiles)
+        clean = 1 - noised
+        whole = lambda i, c: pair(i, c, masked=False)
+        carry = _once(noised, lambda c: pair(j, c, masked=True, within=(size, None)), start)
+        carry = _once(clean, lambda c: pair(j, c, masked=True, within=(size, 0)), carry)
+        carry = lax.fori_loop(j + 1, clean * half_tiles, whole, carry)
+        if n_tiles > half_tiles:
+            partner = half_tiles + at
+            carry = _once(clean, lambda c: pair(partner, c, masked=True, within=(size, size)), carry)
+            carry = lax.fori_loop(partner + 1, clean * n_tiles, whole, carry)
+        dk, dv = carry
+    elif window is None:
         carry = pair(j, start, masked=True)
         dk, dv = lax.fori_loop(j + 1, n_blocks, lambda i, c: pair(i, c, masked=False), carry)
     else:
@@ -282,13 +356,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, block, s
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _kernel_options(q, k, window, keep=None):
+def _kernel_options(q, k, window, keep=None, blocks=None, block=None):
     """``(query heads a key/value head, the kernels' window or mask argument, what such a
     kernel's name ends in)``: static, and ``(1, {}, "")`` for full heads, no window and
     no computed mask, which leaves the calls as they were."""
     group = q.shape[0] // k.shape[0]
     if keep is not None:
         return group, {"keep": True}, "_keep"
+    if blocks is not None:  # (tiles a half, tiles of the stream, positions a block)
+        return group, {"blocks": (blocks[0] // block, q.shape[1] // block, blocks[1])}, "_blocks"
     return group, ({} if window is None else {"window": window}), "" if window is None else "_window"
 
 
@@ -303,7 +379,7 @@ def _vmem(hd: int, group: int, backward: bool, keep: bool = False) -> dict:
     return {"vmem_limit_bytes": GROUPED_BWD_VMEM} if backward and group != 1 else {}
 
 
-def _forward(q, k, v, block, interpret, window=None, keep=None):
+def _forward(q, k, v, block, interpret, window=None, keep=None, blocks=None):
     """``q`` [B, T, hd], ``k`` [B / group, T, hd], ``v`` [B / group, T, hd_v], ``keep``
     [N, T, T] int8 or None -> output *transposed* [B, hd_v, T], log-sum-exp
     [B, T/block, 1, block]."""
@@ -311,7 +387,7 @@ def _forward(q, k, v, block, interpret, window=None, keep=None):
     hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
-    group, masking, kind = _kernel_options(q, k, window, keep)
+    group, masking, kind = _kernel_options(q, k, window, keep, blocks, block)
     if keep is None:
         grid, semantics = (b, n_blocks), ("parallel", "arbitrary")
         head_of = lambda h, i: h  # the query head of a grid step
@@ -345,14 +421,14 @@ def _forward(q, k, v, block, interpret, window=None, keep=None):
     )(*operands)
 
 
-def _backward(q, k, v, do, lse, delta, block, interpret, window=None, keep=None):
+def _backward(q, k, v, do, lse, delta, block, interpret, window=None, keep=None, blocks=None):
     """Gradients: ``dq`` *transposed* [B, hd, T]; ``dk`` [B, T, hd], ``dv`` [B, T, hd_v] —
     one a QUERY head, in float32, where heads are grouped: the caller sums a group's."""
     b, t, hd = q.shape
     hd_v = v.shape[-1]
     n_blocks = t // block
     scale, fold = _scale(hd)
-    group, masking, kind = _kernel_options(q, k, window, keep)
+    group, masking, kind = _kernel_options(q, k, window, keep, blocks, block)
     head = lambda width: pl.BlockSpec((None, t, width), lambda h, j: (h, 0, 0))
     rows = lambda width: pl.BlockSpec((None, block, width), lambda h, j: (h, j, 0))
     kv_rows = rows if group == 1 else lambda width: pl.BlockSpec(
@@ -381,21 +457,21 @@ def _backward(q, k, v, do, lse, delta, block, interpret, window=None, keep=None)
     )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _attend(q, k, v, keep, block, interpret, window):
-    return _attend_fwd(q, k, v, keep, block, interpret, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _attend(q, k, v, keep, block, interpret, window, blocks):
+    return _attend_fwd(q, k, v, keep, block, interpret, window, blocks)[0]
 
 
-def _attend_fwd(q, k, v, keep, block, interpret, window):
-    o_t, lse = _forward(q, k, v, block, interpret, window, keep)
+def _attend_fwd(q, k, v, keep, block, interpret, window, blocks):
+    o_t, lse = _forward(q, k, v, block, interpret, window, keep, blocks)
     o, lse = map(checkpoint_name, (jnp.swapaxes(o_t, 1, 2), lse), KEPT)
     return o, (q, k, v, keep, o, lse)
 
 
-def _attend_bwd(block, interpret, window, saved, do):
+def _attend_bwd(block, interpret, window, blocks, saved, do):
     q, k, v, keep, o, lse = saved
     delta = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1).reshape(lse.shape)
-    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret, window, keep)
+    dq_t, dk, dv = _backward(q, k, v, do, lse, delta, block, interpret, window, keep, blocks)
     if k.shape != q.shape:  # a group's query heads each wrote their own share
         shared = lambda d, like: d.reshape(like.shape[0], -1, *like.shape[1:]).sum(1).astype(like.dtype)
         dk, dv = shared(dk, k), shared(dv, v)
@@ -405,22 +481,37 @@ def _attend_bwd(block, interpret, window, saved, do):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+def block_diffusion_mask(seq_len: int, half: int, size: int) -> jax.Array:
+    """``bool [T, T]``, queries down and keys along: the pairs a stream of ``T`` positions
+    sees under ``blocks=(half, size)``, from the four rules as they are stated.  Position
+    ``j`` is clean while ``j < half`` and noised from there on, covers text position ``j
+    % half``, and lies in block ``(j % half) // size``."""
+    at = jnp.arange(seq_len)
+    noised, of = at >= half, (at % half) // size
+    q_noised, k_noised, q_of, k_of = noised[:, None], noised[None, :], of[:, None], of[None, :]
+    return ((~q_noised & ~k_noised & (k_of <= q_of))  # clean reads clean: up to its own block
+            | (q_noised & ~k_noised & (k_of < q_of))  # noised reads clean: before its own
+            | (q_noised & k_noised & (k_of == q_of)))  # noised reads noised: its own alone
+
+
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            window: int | None = None,
-                           keep: jax.Array | None = None) -> jax.Array:
+                           keep: jax.Array | None = None,
+                           blocks: tuple[int, int] | None = None) -> jax.Array:
     """The same function spelled densely, in the inputs' dtype throughout: ``[N, H, T, T]``
     scores, mask, softmax.  What short sequences run, and what the kernels are tested
     against.  Grouped ``k``/``v`` (``[N, H_kv, T, hd]``) are repeated to the query heads;
     ``v``'s head size may differ from ``q``'s and ``k``'s (the output takes it); ``keep``
     ``[N, T, T]`` (keys down, queries along, as :func:`causal_attention` takes it) masks
-    every head of its sequence alike."""
+    every head of its sequence alike; ``blocks=(half, size)`` takes the causal rule's
+    place (:func:`block_diffusion_mask`), any ``size``."""
     t, hd = q.shape[-2:]
     if k.shape[1] != q.shape[1]:
         k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
     scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / math.sqrt(hd)
     # Causal mask: position q attends to keys <= q only.  Additive -inf keeps the
     # softmax exact for the allowed band.
-    causal = jnp.tril(jnp.ones((t, t), bool))
+    causal = jnp.tril(jnp.ones((t, t), bool)) if blocks is None else block_diffusion_mask(t, *blocks)
     if window is not None:  # ... and to keys less than ``window`` positions behind it
         causal &= ~jnp.tril(jnp.ones((t, t), bool), -window)
     seen = causal[None, None]
@@ -438,6 +529,7 @@ def causal_attention(
     *,
     window: int | None = None,
     keep: jax.Array | None = None,
+    blocks: tuple[int, int] | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -450,6 +542,10 @@ def causal_attention(
     ``keep`` ``[N, T, T]`` (``int8``; keys down, queries along: ``keep[n, s, t]``), position
     ``t`` of sequence ``n`` attends, in every head, to the keys ``s <= t`` with ``keep[n,
     s, t] != 0`` only; each ``t`` has to keep a key.  It is a constant of the backward pass.
+    With ``blocks=(half, size)`` the causal rule gives way to the block-diffusion one
+    (:func:`block_diffusion_mask`): ``T`` is ``half`` or twice it, ``size`` a power of two
+    that divides the kernels' block, and the default block is the largest that divides
+    ``half``.
 
     Off the TPU the kernels run in Pallas's interpreter, which cannot evaluate a kernel
     on values that vary over a ``shard_map`` axis under its varying-axes check (the
@@ -466,16 +562,24 @@ def causal_attention(
     if keep is not None and (window is not None or keep.shape != (n, t, t)):
         raise ValueError(f"keep is one [N, T, T] mask a sequence, {(n, t, t)} here, and takes "
                          f"the window's place: {keep.shape}, window={window}")
-    block = block_for(t) if block is None else block
-    if block is None or t % block or block % 128:
-        raise ValueError(f"T={t} is not whole blocks of {block or BLOCKS} (multiples of 128)")
+    if blocks is not None:
+        half, size = blocks
+        if window is not None or keep is not None or t not in (half, 2 * half):
+            raise ValueError(f"blocks={blocks} stands alone, over one or two halves: T={t}, "
+                             f"window={window}, keep given: {keep is not None}")
+        if size < 1 or size & (size - 1):
+            raise ValueError(f"blocks={blocks}: the kernels take blocks of a power of two")
+    block = block_for(t if blocks is None else blocks[0]) if block is None else block
+    if block is None or t % block or block % 128 or (blocks and (half % block or block % size)):
+        raise ValueError(f"T={t} is not whole blocks of {block or BLOCKS} (multiples of 128"
+                         + (f", which {blocks} cut into whole halves and blocks)" if blocks else ")"))
     if window is not None and window >= t:
         window = None  # no key is that far behind
     if keep is not None:
         keep = lax.stop_gradient(keep.astype(jnp.int8))
     interpret = auto_interpret(interpret)
     if interpret and any(jax.typeof(a).vma for a in (q, k, v)):
-        return dense_causal_attention(q, k, v, window=window, keep=keep)
+        return dense_causal_attention(q, k, v, window=window, keep=keep, blocks=blocks)
     flat = lambda a: a.reshape(-1, t, a.shape[-1])
-    out = _attend(flat(q), flat(k), flat(v), keep, block, interpret, window)
+    out = _attend(flat(q), flat(k), flat(v), keep, block, interpret, window, blocks)
     return out.reshape(n, h, t, hd_v)

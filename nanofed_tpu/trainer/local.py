@@ -69,9 +69,20 @@ def make_grad_fn(
     ``apply`` then carries ``with_counters``, the same call returning ``(log-probs,
     {name: scalar})``.  The scalars ride ``StepStats.counters`` weighted by the batch's
     real samples, and reach the round's metrics under their names.
+
+    A model whose training is not "one label a sample" (a loss at every position, at
+    masked positions, with noise of its own) brings its objective: its ``apply`` then
+    carries ``sample_nll(params, x, y, *, rng) -> (nll [N] float32, hits [N], {name:
+    scalar})``, each sample's own loss, the share of its predictions that were right (what
+    ``correct`` then sums) and the model's counters; ``rng`` is the step's key, so noise
+    drawn from it is the schedule's.  Where a model brings one it decides training
+    (``benchmark/reference/fedavg.py`` has the same rule); the cast, the masked mean over
+    the batch's real rows, the counters' weighting and ``StepStats`` stay this
+    function's, and ``y`` may be ignored.
     """
     cdt = jnp.dtype(compute_dtype) if compute_dtype is not None else None
     counted_apply = getattr(apply_fn, "with_counters", None)
+    objective = getattr(apply_fn, "sample_nll", None)
 
     def loss_fn(params, xb, yb, mb, rng):
         if cdt is not None:
@@ -82,16 +93,21 @@ def make_grad_fn(
                 # fedlint: disable=FED002 (branches on xb.dtype — static trace-time metadata, not a traced value; both arms compile into one program)
                 if jnp.issubdtype(xb.dtype, jnp.floating):
                     xb = xb.astype(cdt)
-        if counted_apply is None:
+        if objective is not None:
+            nll, hits, counters = objective(params, xb, yb, rng=rng)
+        elif counted_apply is None:
             logp, counters = apply_fn(params, xb, train=True, rng=rng), {}
         else:
             logp, counters = counted_apply(params, xb, train=True, rng=rng)
         with jax.named_scope("nll_loss"):
-            logp = logp.astype(jnp.float32)
-            nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+            if objective is None:
+                logp = logp.astype(jnp.float32)
+                nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
             count = mb.sum()
             loss = (nll * mb).sum() / jnp.maximum(count, 1.0)
-            correct = ((jnp.argmax(logp, -1) == yb) * mb).sum()
+            if objective is None:  # after the loss, where it stood: the same program
+                hits = jnp.argmax(logp, -1) == yb
+            correct = (hits * mb).sum()
         return loss, (correct, count, counters)
 
     def grad_fn(params, xb, yb, mb, rng):

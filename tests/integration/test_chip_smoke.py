@@ -50,7 +50,8 @@ def test_every_phase_passes_tiny_on_the_cpu_mesh(tmp_path, devices):
     assert wire["accepted"] == 12 and wire["aggregations_completed"] == 3
     assert wire["flat_size"] == 4810  # digits_mlp
     assert set(records["kernels"]) >= {
-        "u32", "C=8", "C=40", "weighted_mean_tree", "causal_attention", "expert_tiles"}
+        "u32", "C=8", "C=40", "weighted_mean_tree", "causal_attention", "causal_attention_blocks",
+        "expert_tiles"}
     multi = records["multichip"]
     assert multi["4"]["client_rows_per_device"] == 4
     assert multi["2x2"]["client_rows_per_device"] == 8

@@ -1,7 +1,9 @@
-"""What the five models with rematerialized layers keep for the backward pass
+"""What the six models with rematerialized layers keep for the backward pass
 (``models.moe_decoder``, ``models.latent_moe``, ``models.hybrid``, ``models.indexed_moe``,
 whose pick of keys is a third kind of kept output, ``models.gated_moe``, whose gate comes
-after the kept output and is computed again): every layer under
+after the kept output and is computed again, ``models.diffusion_moe``, whose round trains
+on the objective it carries, a doubled stream under the block-diffusion mask, where
+``apply`` reads one stream): every layer under
 ``jax.checkpoint`` with ``models.experts.KEEP_NAMED_OUTPUTS`` (``models.decoder.run_layers``,
 the one place a layer is rematerialized: ``stack`` here), so the attention kernel's
 output and log-sum-exp stay and the kernel is launched once a layer, and the expert
@@ -50,6 +52,12 @@ DECODERS = {
         "vocab": 64, "seq_len": 512, "width": 64, "sliding_layout": [1, 1, 0, 1], "window": 200,
         "attn_heads": 8, "kv_heads": 1, "head_dim": 16, "dense_layers": 1, "dense_width": 160,
         "experts": 16, "experts_held": 4, "top_k": 3, "expert_width": 24, "shared_width": 24}),
+    # One layer under the block-diffusion mask in blocks of 4, eight query heads a key/value
+    # head: ``apply`` reads one stream of 512, a round trains on the doubled one of 1024.
+    "diffusion_moe": ("diffusion_moe_lm", {
+        "vocab": 64, "seq_len": 512, "block": 4, "width": 64, "layers": 1, "attn_heads": 8,
+        "kv_heads": 1, "head_dim": 16, "experts": 16, "experts_held": 4, "top_k": 3,
+        "expert_width": 48}),
 }
 #: Launches a training step of each holds, forward kernels under the policy first.
 LAUNCHES = {
@@ -60,9 +68,11 @@ LAUNCHES = {
     "indexed_moe": {"causal_attention_fwd_keep": 2, "causal_attention_bwd_keep": 2},
     "gated_moe": {"causal_attention_fwd": 1, "causal_attention_fwd_window": 3,
                   "causal_attention_bwd": 1, "causal_attention_bwd_window": 3},
+    "diffusion_moe": {"causal_attention_fwd_blocks": 1, "causal_attention_bwd_blocks": 1},
 }
 #: Expert layers of each: a dispatch, and so one scatter of ``src``, apiece.
-EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2, "gated_moe": 3}
+EXPERT_LAYERS = {"moe_decoder": 4, "latent_moe": 2, "hybrid": 2, "indexed_moe": 2, "gated_moe": 3,
+                 "diffusion_moe": 1}
 #: Leaves the loss reads and no step moves: the indexer's three matrices (its pick is a
 #: constant of the backward pass).
 NEVER_MOVED = {"indexed_moe": 3}
@@ -70,7 +80,7 @@ NEVER_MOVED = {"indexed_moe": 3}
 #: Fewer layers of each for the tests that run a step operation by operation.
 SHALLOW = {"moe_decoder": {"rope_layout": [0, 1], "window_layout": [0, 1]},
            "latent_moe": {"expert_layers": 1}, "hybrid": {"pattern": "M*E"},
-           "indexed_moe": {"layers": 1}, "gated_moe": {"sliding_layout": [1, 0]}}
+           "indexed_moe": {"layers": 1}, "gated_moe": {"sliding_layout": [1, 0]}, "diffusion_moe": {}}
 
 
 @pytest.fixture(params=list(DECODERS))
@@ -184,29 +194,31 @@ def kernels_in_plain_jax(monkeypatch):
     ``custom_vjp`` is traced; with the stand-ins it is: the forward rule and its names,
     the residuals, the backward rule around them."""
 
-    def probabilities(q, k, lse, window, keep=None):
+    def probabilities(q, k, lse, window, keep=None, blocks=None):
         t, hd = q.shape[1:]
         s = jnp.einsum("bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32) / hd ** 0.5
         behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
         seen = (behind >= 0) & (behind < (t if window is None else window))
+        if blocks is not None:  # the block-diffusion rule in the causal one's place
+            seen = attention.block_diffusion_mask(t, *blocks)
         if keep is not None:  # [N, keys, queries], one mask for all a sequence's heads
             seen = seen & jnp.repeat(jnp.swapaxes(keep, 1, 2) != 0, q.shape[0] // keep.shape[0], 0)
         s = jnp.where(seen, s, -jnp.inf)
         lse = jax.nn.logsumexp(s, axis=-1) if lse is None else lse.reshape(q.shape[:2])
         return jnp.exp(s - lse[..., None]), lse
 
-    def causal_attention_fwd(q, k, v, block, window, keep=None):
+    def causal_attention_fwd(q, k, v, block, window, keep=None, blocks=None):
         group = q.shape[0] // k.shape[0]
-        p, lse = probabilities(q, jnp.repeat(k, group, 0), None, window, keep)
+        p, lse = probabilities(q, jnp.repeat(k, group, 0), None, window, keep, blocks)
         o = jnp.einsum("bqk,bkd->bqd", p, jnp.repeat(v, group, 0).astype(jnp.float32))
         return (jnp.swapaxes(o, 1, 2).astype(q.dtype),
                 lse.reshape(q.shape[0], q.shape[1] // block, 1, block))
 
-    def causal_attention_bwd(q, k, v, do, lse, delta, block, window, keep=None):
+    def causal_attention_bwd(q, k, v, do, lse, delta, block, window, keep=None, blocks=None):
         group = q.shape[0] // k.shape[0]
         k, v = jnp.repeat(k, group, 0), jnp.repeat(v, group, 0)
         f32 = lambda a: a.astype(jnp.float32)
-        p, _ = probabilities(q, k, lse, window, keep)
+        p, _ = probabilities(q, k, lse, window, keep, blocks)
         dp = jnp.einsum("bqd,bkd->bqk", f32(do), f32(v))
         ds = p * (dp - delta.reshape(q.shape[0], -1, 1)) / q.shape[-1] ** 0.5
         dq = jnp.einsum("bqk,bkd->bqd", ds, f32(k))
@@ -222,11 +234,27 @@ def kernels_in_plain_jax(monkeypatch):
     bwd_keep = jax.jit(lambda *a: causal_attention_bwd(*a), static_argnums=(6, 7))
     fwd_keep.__wrapped__.__name__ = "causal_attention_fwd_keep"
     bwd_keep.__wrapped__.__name__ = "causal_attention_bwd_keep"
-    monkeypatch.setattr(attention, "_forward", lambda q, k, v, block, interpret, window=None, keep=None:
-                        fwd(q, k, v, block, window) if keep is None else fwd_keep(q, k, v, block, window, keep))
-    monkeypatch.setattr(attention, "_backward", lambda q, k, v, do, lse, delta, block, interpret,
-                        window=None, keep=None: bwd(q, k, v, do, lse, delta, block, window)
-                        if keep is None else bwd_keep(q, k, v, do, lse, delta, block, window, keep))
+    # ... and one under the block-diffusion mask that branch's.
+    fwd_blocks = jax.jit(lambda q, k, v, block, blocks: causal_attention_fwd(
+        q, k, v, block, None, None, blocks), static_argnums=(3, 4))
+    bwd_blocks = jax.jit(lambda q, k, v, do, lse, delta, block, blocks: causal_attention_bwd(
+        q, k, v, do, lse, delta, block, None, None, blocks), static_argnums=(6, 7))
+    fwd_blocks.__wrapped__.__name__ = "causal_attention_fwd_blocks"
+    bwd_blocks.__wrapped__.__name__ = "causal_attention_bwd_blocks"
+
+    def forward(q, k, v, block, interpret, window=None, keep=None, blocks=None):
+        if blocks is not None:
+            return fwd_blocks(q, k, v, block, blocks)
+        return fwd(q, k, v, block, window) if keep is None else fwd_keep(q, k, v, block, window, keep)
+
+    def backward(q, k, v, do, lse, delta, block, interpret, window=None, keep=None, blocks=None):
+        if blocks is not None:
+            return bwd_blocks(q, k, v, do, lse, delta, block, blocks)
+        return (bwd(q, k, v, do, lse, delta, block, window) if keep is None
+                else bwd_keep(q, k, v, do, lse, delta, block, window, keep))
+
+    monkeypatch.setattr(attention, "_forward", forward)
+    monkeypatch.setattr(attention, "_backward", backward)
     monkeypatch.setattr(attention, "auto_interpret", lambda interpret: False)
 
 
@@ -241,7 +269,7 @@ def test_the_policy_inside_the_round_program_on_the_cpu_mesh(decoder, client_chu
     name, build, plainly = decoder
     model, params, _ = build()
     layers = sum(LAUNCHES[name].values()) // 2
-    suffix = "_keep" if name == "indexed_moe" else ""
+    suffix = {"indexed_moe": "_keep", "diffusion_moe": "_blocks"}.get(name, "")
     launches = lambda forward: {f"causal_attention_{kind}{suffix}": n for kind, n in (
         ("fwd", forward * layers), ("bwd", layers)) if n}
     mesh = make_mesh(devices=jax.devices()[:4])

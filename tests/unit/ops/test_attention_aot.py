@@ -6,10 +6,12 @@ TPU's library, inside the fixture): the cell's training step is here too, for wh
 compiler keeps of the MLP between its forward and its backward, the SmallThinker
 cell's embedding gradient, for where the compiler places its accumulators, the
 moonlight cell's kernels with score and value heads of different sizes, the keye cell's
-kernels under a computed mask, and a rematerialized layer of each of the four decoders,
+kernels under a computed mask, and a rematerialized layer of each of the five decoders,
 for what it launches twice and what it keeps (the trinity cell's: the windowed kernels with
-eight query heads a key/value head, a 2048 window at 8192 positions); in those layers the
-held experts' kernels (``ops.experts``) at the four decoders' widths, and alone at the
+eight query heads a key/value head, a 2048 window at 8192 positions; the sdar cell's: the
+step of the OBJECTIVE the model carries, a doubled stream of 8192 positions under the
+block-diffusion mask and the head over its 4096 noised positions); in those layers the
+held experts' kernels (``ops.experts``) at the five decoders' widths, and alone at the
 hybrid's, whose experts are 1856 wide: no whole lanes."""
 
 import re
@@ -213,7 +215,7 @@ def test_whole_table_accumulator_stays_in_hbm(one_chip, monkeypatch):
     assert results and all(r.startswith(whole) and "S(1)" not in r for r in results), results
 
 
-#: The four cells' layers at their published widths and 8192 positions, a small vocabulary
+#: The five cells' layers at their published widths and 8192 positions, a small vocabulary
 #: around them: ``(factory, kwargs, layers, bytes of the output and log-sum-exp a
 #: layer keeps)``.  An expert layer keeps its dispatch's layout too: under 0.3 MB.
 DECODERS = {
@@ -239,6 +241,12 @@ DECODERS = {
         attn_heads=32, kv_heads=4, head_dim=128, dense_layers=1, dense_width=6144, experts=128,
         first_expert=0, experts_held=8, top_k=8, expert_width=1024, shared_width=1024,
         routed_scale=2.826, eps=1e-5), 2, 32 * 8192 * (128 * 2 + 4)),
+    # The objective's step: 4096 tokens are a stream of 8192 positions in two halves, the
+    # kernels under the block-diffusion mask in blocks of 4, the head over the noised half.
+    "sdar": ("diffusion_moe_lm", dict(
+        vocab=1024, seq_len=4096, block=4, width=2048, layers=1, attn_heads=32, kv_heads=4,
+        head_dim=128, rope_theta=1e6, experts=128, first_expert=0, experts_held=16, top_k=8,
+        expert_width=768, eps=1e-6), 1, 32 * 8192 * (128 * 2 + 4)),
 }
 #: What buffer assignment may move for reasons of its own when the schedule changes (read
 #: here: +11 MB on 60 MB kept and +0.5 MB on 68 MB kept).
@@ -253,7 +261,8 @@ def decoder_steps(request, one_chip):
     sees the CPU, so the test says so in ``auto_interpret``'s place."""
     factory, kwargs, layers, kept_bytes = DECODERS[request.param]
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    batch = (shaped((1, 1, 8192), jnp.int32), shaped((1, 1), jnp.int32), shaped((1, 1), jnp.float32))
+    batch = (shaped((1, 1, kwargs["seq_len"]), jnp.int32), shaped((1, 1), jnp.int32),
+             shaped((1, 1), jnp.float32))
 
     def compile_step():
         m = get_model(factory, **kwargs)
